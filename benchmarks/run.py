@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.launch.compile_cache import configure_compile_cache
+
 from . import (bench_bank, bench_churn, bench_fig5, bench_filter,
                bench_kernels, bench_pause, bench_ragged, bench_serving,
                bench_table1, bench_table2)
@@ -18,6 +20,7 @@ def main() -> None:
     if unknown:        # a typo'd flag must not silently run the full suite
         sys.exit(f"usage: python -m benchmarks.run [--fast|--smoke] "
                  f"(unknown: {' '.join(unknown)})")
+    configure_compile_cache()
     smoke = "--smoke" in sys.argv
     fast = smoke or "--fast" in sys.argv
     csv = []

@@ -1,35 +1,30 @@
 """Distributed retrieval: the cuckoo filter sharded across a device mesh,
 with queries resolved by the shard_map lookup (pod-scale retrieval path).
 
-Spawns its own device count — run directly, not under the test process:
+Shards over every device JAX finds (one shard per device).  On a CPU,
+ask for several host devices to see the sharding:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python examples/distributed_lookup.py
 """
-import os
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-
-import jax                                    # noqa: E402
-import jax.numpy as jnp                       # noqa: E402
-import numpy as np                            # noqa: E402
-
-from repro.core import build_forest, build_index, lookup_batch  # noqa: E402
-from repro.core import hashing                # noqa: E402
-from repro.core.distributed import (shard_filter_tables,  # noqa: E402
-                                    sharded_lookup)
-from repro.data import hospital_corpus       # noqa: E402
+from repro.core import build_forest, build_index, lookup_batch
+from repro.core import hashing
+from repro.core.distributed import shard_filter_tables, sharded_lookup
+from repro.data import hospital_corpus
 
 
 def main():
     corpus = hospital_corpus(num_trees=200)
     forest = build_forest(corpus.trees)
+    d = len(jax.devices())          # a power of two divides the buckets
     index = build_index(forest, num_buckets=2048)
     t = index.filter.tables()
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((d,), ("model",))
     fps, heads = shard_filter_tables(mesh, "model",
                                      jnp.asarray(t.fingerprints),
                                      jnp.asarray(t.heads))

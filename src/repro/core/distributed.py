@@ -44,9 +44,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
 from ..obs import get_registry
 from . import hashing
 from .bank import FilterBank, ShardedBank, pad_csr
@@ -56,6 +55,21 @@ from .trag import (CFTDeviceState, DeviceRetrieval, finish_context,
                    gather_context)
 
 NULL = -1
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis of type ``Auto``.
+
+    ``jax.make_mesh`` hands out ``Explicit`` axes, under which every
+    array carries its sharding in its type and a slice or gather along a
+    sharded dimension must say where its result lives.  The router and
+    the model shardings here are written for the compiler's automatic
+    propagation, so each mesh that enters the program passes through
+    this one conversion."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 # ---------------------------------------------------------------- router
@@ -204,6 +218,7 @@ def stage_sharded_bank(sbank: ShardedBank, forest: EntityForest,
     routing/CSR/forest replicated).  ``arena_rows`` forces a larger
     per-shard block than the tight minimum — used to compare against a
     live state whose padding an in-place commit could not shrink."""
+    mesh = auto_axes(mesh)
     d = int(mesh.shape[axis])
     if d != sbank.num_shards:
         raise ValueError(f"bank has {sbank.num_shards} shards but mesh "
@@ -304,11 +319,11 @@ def sharded_apply_delta(fps: jax.Array, temp: jax.Array, heads: jax.Array,
                 h.at[r0].set(lh[0], mode="drop"))
 
     blk = P(axis, None)
-    fn = _shard_map(local, mesh=mesh,
-                    in_specs=(blk, blk, blk, blk, P(axis, None, None),
-                              P(axis, None, None), P(axis, None, None),
-                              P(axis, None, None), P(axis)),
-                    out_specs=(blk, blk, blk), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(blk, blk, blk, blk, P(axis, None, None),
+                                 P(axis, None, None), P(axis, None, None),
+                                 P(axis, None, None), P(axis)),
+                       out_specs=(blk, blk, blk), check_vma=False)
     return fn(fps, temp, heads, rows, vf, vt, vh, vkeep, shift)
 
 
@@ -337,9 +352,9 @@ def sharded_splice_segment(fps: jax.Array, temp: jax.Array,
         return jax.lax.cond(me == ow, splice, lambda _: (f, t, h), None)
 
     blk = P(axis, None)
-    fn = _shard_map(local, mesh=mesh,
-                    in_specs=(blk, blk, blk, P(), P(), P(), P(), P()),
-                    out_specs=(blk, blk, blk), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(blk, blk, blk, P(), P(), P(), P(), P()),
+                       out_specs=(blk, blk, blk), check_vma=False)
     return fn(fps, temp, heads, seg_f, seg_t, seg_h, owner, start)
 
 
@@ -400,10 +415,7 @@ def _bank_local_fused_fn(axis: str, num_shards: int, num_trees: int,
     locations)`` home.  The hierarchy walk stays on the source shard
     (``finish_context`` over the replicated forest), so the route-back
     payload grows only by ``max_locs`` ints per query."""
-    from ..kernels.cuckoo_lookup.ops import on_tpu
-    from ..kernels.fused_retrieve.ops import (context_resident_bytes,
-                                              fused_probe_locs,
-                                              fused_row_tile)
+    from ..kernels.fused_retrieve.ops import fused_probe_locs, launch_plan
 
     def local(fps_b, temp_b, heads_b, tree_shard, tree_off, tree_nb,
               csr_offsets, csr_nodes, tid, h):
@@ -421,15 +433,13 @@ def _bank_local_fused_fn(axis: str, num_shards: int, num_trees: int,
         qo = _exchange(bo, axis).reshape(-1)
         qm = _exchange(bm, axis).reshape(-1)
         qv = _exchange(bv, axis).reshape(-1)
-        interpret = not on_tpu()
         a, s = fps_b.shape
-        rt = 0 if interpret else fused_row_tile(
-            a, context_resident_bytes(a, s, csr_offsets.shape[0] - 1,
-                                      csr_nodes.shape[0], 0, 0, True))
+        interpret, mxu, rt, limit = launch_plan(
+            a, s, csr_offsets.shape[0] - 1, csr_nodes.shape[0], 0, 0)
         hit, locs, temp_b = fused_probe_locs(
             fps_b, temp_b, heads_b, qo, qm, qv, qh, csr_offsets,
             csr_nodes, max_locs=max_locs, interpret=interpret, row_tile=rt,
-            mxu=not interpret)
+            mxu=mxu, vmem_limit=limit)
         back = functools.partial(_route_back, dest=dest, rank=rank,
                                  axis=axis, num_shards=num_shards)
         locs_home = _route_back_wide(locs, dest, rank, axis, num_shards)
@@ -455,12 +465,12 @@ def _fused_lookup_core(state: ShardedBankState, tree_ids: jax.Array,
     hp = jnp.pad(h.astype(jnp.uint32), (0, pad))
     local = _bank_local_fused_fn(axis, d, state.num_trees, cap, max_locs)
     spec_b = P(axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_b, spec_b, spec_b, P(), P(), P(), P(), P(),
                   P(axis), P(axis)),
         out_specs=(P(axis), P(axis, None), spec_b),
-        check_rep=False)                   # pallas_call: no replication rule
+        check_vma=False)                   # pallas_call: no replication rule
     hit, locs, temp = fn(state.fingerprints, state.temperature,
                          state.heads, state.tree_shard, state.tree_offset,
                          state.tree_nb, state.csr_offsets, state.csr_nodes,
@@ -484,14 +494,14 @@ def _lookup_core(state: ShardedBankState, tree_ids: jax.Array,
     local = _bank_local_fn(axis, d, state.num_trees, state.slots, bump,
                            lookup_fn, cap)
     spec_b = P(axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_b, spec_b, spec_b, P(), P(), P(), P(axis), P(axis)),
         out_specs=(LookupResult(hit=P(axis), head=P(axis), bucket=P(axis),
                                 slot=P(axis)), spec_b),
         # pallas_call has no replication rule; rep-check only costs us the
         # kernel probe path, so switch it off just there
-        check_rep=lookup_fn is None)
+        check_vma=lookup_fn is None)
     res, temp = fn(state.fingerprints, state.temperature, state.heads,
                    state.tree_shard, state.tree_offset, state.tree_nb,
                    tid, hp)
@@ -519,8 +529,8 @@ def _routing_counts_jit(tree_shard: jax.Array, tid: jax.Array, mesh: Mesh,
         recv = _exchange(counts.reshape(num_shards, 1), axis)
         return recv.reshape(1, num_shards)
 
-    fn = _shard_map(local, mesh=mesh, in_specs=(P(), P(axis)),
-                    out_specs=P(axis, None), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axis)),
+                       out_specs=P(axis, None), check_vma=False)
     return fn(tree_shard, tid)
 
 
@@ -721,6 +731,7 @@ def sharded_lookup(mesh: Mesh, axis: str, fingerprints: jax.Array,
     ``lookup_batch``.
     """
     nb_global, slots = fingerprints.shape
+    mesh = auto_axes(mesh)
     d = int(mesh.shape[axis])
     if nb_global % d:
         raise ValueError(f"bucket count {nb_global} not divisible by "
@@ -729,7 +740,7 @@ def sharded_lookup(mesh: Mesh, axis: str, fingerprints: jax.Array,
     pad = (-b) % d
     hp = jnp.pad(h.astype(jnp.uint32), (0, pad))
     local = _filter_local_fn(axis, d, nb_global, nb_global // d, slots)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis)),
         out_specs=LookupResult(hit=P(axis), head=P(axis), bucket=P(axis),
@@ -742,5 +753,5 @@ def sharded_lookup(mesh: Mesh, axis: str, fingerprints: jax.Array,
 def shard_filter_tables(mesh: Mesh, axis: str, *tables: jax.Array
                         ) -> Tuple[jax.Array, ...]:
     """Place filter tables bucket-sharded on the mesh."""
-    sharding = NamedSharding(mesh, P(axis, None))
+    sharding = NamedSharding(auto_axes(mesh), P(axis, None))
     return tuple(jax.device_put(t, sharding) for t in tables)

@@ -45,7 +45,7 @@ from ..obs import get_registry
 from . import hashing
 from .bank import ColdTenant, FilterBank, ShardedBank
 from .cuckoo import NULL
-from .distributed import ShardedBankState
+from .distributed import ShardedBankState, auto_axes
 from .trag import CFTDeviceState
 
 _SNAP_FMT = "snap_%08d"
@@ -334,6 +334,7 @@ def restore_state(snap: RestoredSnapshot, mesh=None,
     axis = axis or snap.state_meta["axis"]
     if mesh is None:
         raise ValueError("restoring a sharded state needs a mesh")
+    mesh = auto_axes(mesh)
     d = int(mesh.shape[axis])
     if d != int(snap.state_meta["num_shards"]):
         raise ValueError(

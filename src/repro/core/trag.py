@@ -225,12 +225,8 @@ def retrieve_device(state: CFTDeviceState, query_hashes: jax.Array,
             raise ValueError("fused=True embeds the probe; lookup_fn "
                              "cannot be combined with it")
         from ..kernels.fused_retrieve import fused_retrieve_state_auto
-        out = fused_retrieve_state_auto(state, query_hashes, query_trees,
-                                        max_locs=max_locs, n=n)
-        if out is not None:
-            return out
-        # resident blocks overflow the VMEM budget (huge arena on TPU):
-        # fall through to the unfused oracle path
+        return fused_retrieve_state_auto(state, query_hashes, query_trees,
+                                         max_locs=max_locs, n=n)
     if lookup_fn is None:
         lookup_fn = lookup_arena
     if query_trees is None:

@@ -1,6 +1,4 @@
-from .kernel import (TILE, cuckoo_lookup_arena_pallas,
-                     cuckoo_lookup_bank_pallas, cuckoo_lookup_pallas,
-                     cuckoo_lookup_ragged_pallas)
+from .kernel import TILE, cuckoo_lookup_arena_pallas
 from .ops import (cuckoo_lookup, cuckoo_lookup_arena,
                   cuckoo_lookup_arena_auto, cuckoo_lookup_auto,
                   cuckoo_lookup_bank, cuckoo_lookup_bank_auto,
@@ -9,8 +7,7 @@ from .ops import (cuckoo_lookup, cuckoo_lookup_arena,
 from .ref import (cuckoo_lookup_arena_ref, cuckoo_lookup_bank_ref,
                   cuckoo_lookup_ragged_ref, cuckoo_lookup_ref)
 
-__all__ = ["TILE", "cuckoo_lookup_pallas", "cuckoo_lookup_bank_pallas",
-           "cuckoo_lookup_arena_pallas", "cuckoo_lookup_ragged_pallas",
+__all__ = ["TILE", "cuckoo_lookup_arena_pallas",
            "cuckoo_lookup", "cuckoo_lookup_auto", "cuckoo_lookup_bank",
            "cuckoo_lookup_bank_auto", "cuckoo_lookup_arena",
            "cuckoo_lookup_arena_auto", "cuckoo_lookup_ragged",

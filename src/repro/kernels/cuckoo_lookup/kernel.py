@@ -1,17 +1,24 @@
-"""Pallas TPU kernel: batched cuckoo-filter lookup (the paper's hot loop).
+"""Pallas TPU kernel: batched cuckoo-filter probe over the ragged bucket
+arena (the paper's hot loop).
 
-TPU-native design (DESIGN.md §3): the filter tables are small (NB x S x 4B —
-a few hundred KiB at most) and live as *whole VMEM blocks*; the query batch
-is tiled over the grid.  Bucket rows are gathered with one-hot matmuls on the
-MXU (exact in f32 for 12-bit fingerprints and <2^24 head pointers), replacing
-the CPU implementation's pointer dereference per probe.
+TPU layout: queries ride the 128 vector lanes.  Every per-query value is a
+``(1, TILE)`` row, and the arena is staged transposed as one f32 table
+``(2S, A)`` — fingerprint slots stacked over head slots — so a tile of
+``row_tile`` arena rows is a ``(2S, row_tile)`` block.  Bucket rows are
+gathered with one-hot matmuls on the MXU (exact in f32 for 12-bit
+fingerprints and < 2^24 payloads), replacing the CPU implementation's
+pointer dereference per probe:
 
-Per query tile (TILE=128 lanes):
-  1. integer hash pipeline (VPU):  fp, i1, i2 = candidates(h)
-  2. rows1 = one_hot(i1) @ [fp_table | head_table]   (MXU)
-     rows2 = one_hot(i2) @ [fp_table | head_table]
-  3. match = rows == fp; first-match slot via iota-min; outputs hit/head/
-     bucket/slot — identical semantics to repro.core.lookup.lookup_batch.
+  1. integer hash pipeline (VPU):  fp, i1, i2 = candidates(h, mask)
+  2. rows1 = tab @ one_hot(off + i1)       (2S, rt) @ (rt, TILE) on the MXU
+     rows2 = tab @ one_hot(off + i2)
+  3. first match over the [i1 slots | i2 slots] order via sublane iota-min;
+     outputs hit/head/bucket/slot — the semantics of
+     ``repro.core.lookup.lookup_arena``.
+
+Every probe entry (single filter, dense bank, ragged arena, fused
+retrieval) routes through :func:`_arena_probe`: a single filter is an
+arena of one segment, a dense bank an arena of equal segments.
 """
 from __future__ import annotations
 
@@ -20,434 +27,132 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:                      # TPU grid specs (scalar prefetch); optional on
-    from jax.experimental.pallas import tpu as pltpu   # CPU-only installs
-except ImportError:       # pragma: no cover - depends on the jax build
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core import hashing
 
 TILE = 128          # queries per grid step (one vector lane row)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(h_ref, fp_tab_ref, head_tab_ref, hit_ref, head_ref,
-            bucket_ref, slot_ref, *, num_buckets: int, slots: int):
-    h = h_ref[...].astype(jnp.uint32)                       # (TILE,)
-    fp, i1, i2 = hashing.candidate_buckets(h, num_buckets, jnp)
-
-    fp_tab = fp_tab_ref[...]                                # (NB, S) f32
-    head_tab = head_tab_ref[...]                            # (NB, S) f32
-    tab = jnp.concatenate([fp_tab, head_tab], axis=1)       # (NB, 2S)
-
-    nb_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, num_buckets), 1)
-    oh1 = (nb_iota == i1.astype(jnp.int32)[:, None]).astype(jnp.float32)
-    oh2 = (nb_iota == i2.astype(jnp.int32)[:, None]).astype(jnp.float32)
-    rows1 = jax.lax.dot(oh1, tab, precision=jax.lax.Precision.HIGHEST)
-    rows2 = jax.lax.dot(oh2, tab, precision=jax.lax.Precision.HIGHEST)
-
-    fps = jnp.concatenate([rows1[:, :slots], rows2[:, :slots]], axis=1)
-    heads = jnp.concatenate([rows1[:, slots:], rows2[:, slots:]], axis=1)
-
-    match = fps == fp.astype(jnp.float32)[:, None]          # (TILE, 2S)
-    pos_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, 2 * slots), 1)
-    first = jnp.min(jnp.where(match, pos_iota, 2 * slots), axis=1)
-    hit = first < 2 * slots
-    firstc = jnp.minimum(first, 2 * slots - 1)
-
-    sel = (pos_iota == firstc[:, None]).astype(jnp.float32)
-    head = jnp.sum(heads * sel, axis=1)                     # exact gather
-
-    hit_ref[...] = hit.astype(jnp.int32)
-    head_ref[...] = jnp.where(hit, head.astype(jnp.int32), -1)
-    bucket_ref[...] = jnp.where(first < slots, i1, i2).astype(jnp.int32)
-    slot_ref[...] = jnp.where(first < slots, firstc,
-                              firstc - slots).astype(jnp.int32)
+def compiler_params(vmem_limit: int):
+    """Mosaic parameters for a launch: ``vmem_limit`` > 0 raises the
+    kernel's scoped VMEM limit to what the tile budget was derived for
+    (0 keeps the compiler default)."""
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit or None)
 
 
-def cuckoo_lookup_pallas(h: jax.Array, fp_table_f32: jax.Array,
-                         head_table_f32: jax.Array,
-                         interpret: bool = True):
-    """h: (B,) uint32 (B % TILE == 0); tables: (NB, S) float32."""
-    num_buckets, slots = fp_table_f32.shape
-    b = h.shape[0]
-    grid = (b // TILE,)
-    out_shapes = [jax.ShapeDtypeStruct((b,), jnp.int32) for _ in range(4)]
-    qspec = pl.BlockSpec((TILE,), lambda i: (i,))
-    tabspec = pl.BlockSpec((num_buckets, slots), lambda i: (0, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel, num_buckets=num_buckets, slots=slots),
-        grid=grid,
-        in_specs=[qspec, tabspec, tabspec],
-        out_specs=[qspec] * 4,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(h, fp_table_f32, head_table_f32)
+def _fp_f32(fp):
+    """Query fingerprints as the f32 the tables are staged in.  Mosaic has
+    no uint32 -> f32 cast; fingerprints are FP_BITS wide, so the hop
+    through int32 is exact."""
+    return fp.astype(jnp.int32).astype(jnp.float32)
 
 
-def _bank_kernel(h_ref, tid_ref, fp_tab_ref, head_tab_ref, hit_ref,
-                 head_ref, bucket_ref, slot_ref, *, num_buckets: int,
-                 slots: int):
-    """Per-query tree routing: tables are the whole bank flattened to
-    (T * NB, S); each query's bucket rows are tid * NB + {i1, i2}.  The
-    hash pipeline stays tree-local (num_buckets = per-tree NB), so a bank
-    lookup is bit-identical to probing that tree's standalone filter."""
-    h = h_ref[...].astype(jnp.uint32)                       # (TILE,)
-    tid = tid_ref[...].astype(jnp.int32)
-    fp, i1, i2 = hashing.candidate_buckets(h, num_buckets, jnp)
-    r1 = tid * num_buckets + i1.astype(jnp.int32)
-    r2 = tid * num_buckets + i2.astype(jnp.int32)
-
-    fp_tab = fp_tab_ref[...]                                # (T*NB, S) f32
-    head_tab = head_tab_ref[...]
-    tab = jnp.concatenate([fp_tab, head_tab], axis=1)       # (T*NB, 2S)
-    rows_total = fp_tab.shape[0]
-
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, rows_total), 1)
-    oh1 = (row_iota == r1[:, None]).astype(jnp.float32)
-    oh2 = (row_iota == r2[:, None]).astype(jnp.float32)
-    rows1 = jax.lax.dot(oh1, tab, precision=jax.lax.Precision.HIGHEST)
-    rows2 = jax.lax.dot(oh2, tab, precision=jax.lax.Precision.HIGHEST)
-
-    fps = jnp.concatenate([rows1[:, :slots], rows2[:, :slots]], axis=1)
-    heads = jnp.concatenate([rows1[:, slots:], rows2[:, slots:]], axis=1)
-
-    match = fps == fp.astype(jnp.float32)[:, None]          # (TILE, 2S)
-    pos_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, 2 * slots), 1)
-    first = jnp.min(jnp.where(match, pos_iota, 2 * slots), axis=1)
-    hit = first < 2 * slots
-    firstc = jnp.minimum(first, 2 * slots - 1)
-
-    sel = (pos_iota == firstc[:, None]).astype(jnp.float32)
-    head = jnp.sum(heads * sel, axis=1)                     # exact gather
-
-    hit_ref[...] = hit.astype(jnp.int32)
-    head_ref[...] = jnp.where(hit, head.astype(jnp.int32), -1)
-    bucket_ref[...] = jnp.where(first < slots, i1, i2).astype(jnp.int32)
-    slot_ref[...] = jnp.where(first < slots, firstc,
-                              firstc - slots).astype(jnp.int32)
-
-
-def _bank_kernel_tiled(h_ref, tid_ref, fp_tab_ref, head_tab_ref, hit_ref,
-                       head_ref, bucket_ref, slot_ref, *, num_buckets: int,
-                       slots: int, tree_tile: int):
-    """Tree-tiled bank routing: grid axis 1 walks tiles of ``tree_tile``
-    trees, so VMEM only ever holds a ``(tree_tile * NB, S)`` slice of the
-    bank instead of the whole ``(T * NB, S)`` table.  The output block is
-    indexed by the query tile alone and revisited across tree steps
-    (accumulate pattern): step 0 writes the miss defaults — identical to
-    the single-block kernel's miss outputs (head -1, bucket i2, slot S-1)
-    — and each step overwrites the lanes whose tree id falls in its tile.
-    Every query belongs to exactly one tile, so the merge never races."""
-    ti = pl.program_id(1)
-    h = h_ref[...].astype(jnp.uint32)                       # (TILE,)
-    tid = tid_ref[...].astype(jnp.int32)
-    fp, i1, i2 = hashing.candidate_buckets(h, num_buckets, jnp)
-    i1 = i1.astype(jnp.int32)
-    i2 = i2.astype(jnp.int32)
-
-    @pl.when(ti == 0)
-    def _init():
-        hit_ref[...] = jnp.zeros((TILE,), jnp.int32)
-        head_ref[...] = jnp.full((TILE,), -1, jnp.int32)
-        bucket_ref[...] = i2
-        slot_ref[...] = jnp.full((TILE,), slots - 1, jnp.int32)
-
-    local_t = tid - ti * tree_tile
-    in_tile = (local_t >= 0) & (local_t < tree_tile)
-    r1 = local_t * num_buckets + i1
-    r2 = local_t * num_buckets + i2
-
-    fp_tab = fp_tab_ref[...]                          # (tree_tile*NB, S)
-    head_tab = head_tab_ref[...]
-    tab = jnp.concatenate([fp_tab, head_tab], axis=1)
-    rows_block = fp_tab.shape[0]
-
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, rows_block), 1)
-    # out-of-tile lanes produce all-zero one-hots -> zero rows -> no match
-    oh1 = ((row_iota == r1[:, None]) &
-           in_tile[:, None]).astype(jnp.float32)
-    oh2 = ((row_iota == r2[:, None]) &
-           in_tile[:, None]).astype(jnp.float32)
-    rows1 = jax.lax.dot(oh1, tab, precision=jax.lax.Precision.HIGHEST)
-    rows2 = jax.lax.dot(oh2, tab, precision=jax.lax.Precision.HIGHEST)
-
-    fps = jnp.concatenate([rows1[:, :slots], rows2[:, :slots]], axis=1)
-    heads = jnp.concatenate([rows1[:, slots:], rows2[:, slots:]], axis=1)
-
-    match = fps == fp.astype(jnp.float32)[:, None]          # (TILE, 2S)
-    pos_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, 2 * slots), 1)
-    first = jnp.min(jnp.where(match, pos_iota, 2 * slots), axis=1)
-    hit = first < 2 * slots
-    firstc = jnp.minimum(first, 2 * slots - 1)
-
-    sel = (pos_iota == firstc[:, None]).astype(jnp.float32)
-    head = jnp.sum(heads * sel, axis=1)                     # exact gather
-
-    hit_ref[...] = jnp.where(in_tile, hit.astype(jnp.int32), hit_ref[...])
-    head_ref[...] = jnp.where(in_tile & hit, head.astype(jnp.int32),
-                              jnp.where(in_tile, -1, head_ref[...]))
-    bucket_ref[...] = jnp.where(in_tile,
-                                jnp.where(first < slots, i1, i2),
-                                bucket_ref[...])
-    slot_ref[...] = jnp.where(in_tile,
-                              jnp.where(first < slots, firstc,
-                                        firstc - slots),
-                              slot_ref[...])
-
-
-def _arena_kernel(h_ref, off_ref, mask_ref, fp_tab_ref, head_tab_ref,
-                  hit_ref, head_ref, bucket_ref, slot_ref, prio_ref, *,
-                  slots: int, row_tile: int):
-    """Ragged-arena routing: the table is a flat ``(A, S)`` bucket arena
-    where each tree owns a contiguous segment of an independent power-of-
-    two length.  Each query arrives pre-routed as (hash, segment start,
-    bucket mask ``nb_t - 1``) — the offset/mask pair the wrapper gathers
-    from the per-tree SMEM-sized offsets table — and probes arena rows
-    ``off + (i1, i2)`` with ``i1 = mix(h) & mask``.
-
-    Grid axis 1 walks tiles of ``row_tile`` arena rows, so VMEM only ever
-    holds a slice of the arena.  Unlike the dense tree-tiled kernel, a
-    query's two candidate rows may fall in *different* tiles (segments are
-    not tile-aligned), so each tile contributes its local best match and a
-    running priority (position in the [i1 slots | i2 slots] concat) picks
-    the global first match — ``prio_ref`` is the cross-tile accumulator,
-    discarded by the wrapper.  Step 0 writes the same miss defaults as the
-    dense kernels (head -1, bucket i2, slot S-1); since every candidate
-    row lives in exactly one tile, the min-priority merge reproduces the
-    single-block match order exactly.
-    """
-    ti = pl.program_id(1)
-    h = h_ref[...].astype(jnp.uint32)                       # (TILE,)
-    qoff = off_ref[...].astype(jnp.int32)
-    qmask = mask_ref[...].astype(jnp.uint32)
-    _arena_probe(h, qoff, qmask, ti, fp_tab_ref, head_tab_ref, hit_ref,
-                 head_ref, bucket_ref, slot_ref, prio_ref, slots=slots,
-                 row_tile=row_tile)
-
-
-def _arena_probe(h, qoff, qmask, ti, fp_tab_ref, head_tab_ref, hit_ref,
-                 head_ref, bucket_ref, slot_ref, prio_ref, *, slots: int,
+def _arena_probe(h, qoff, qmask, ti, tab_ref, hit_ref, head_ref,
+                 bucket_ref, slot_ref, prio_ref, *, slots: int,
                  row_tile: int):
-    """Shared probe body of the arena kernels: candidates from a
-    per-query (segment start, bucket mask) pair, one-hot MXU row gather
-    within the resident tile, running slot-priority merge across tiles."""
+    """Probe body shared by the arena and fused kernels.
+
+    ``h``/``qoff``/``qmask``: (1, TILE) hash, segment start and bucket
+    mask ``nb_t - 1``.  Grid axis 1 walks tiles of ``row_tile`` arena
+    rows.  A query's two candidate rows may fall in different tiles
+    (segments are not tile-aligned), so each tile contributes its local
+    first match and ``prio_ref`` — the running position in the [i1 slots
+    | i2 slots] order — keeps the global first match.  Step 0 writes the
+    miss defaults (head -1, bucket i2, slot S-1); since every candidate
+    row lives in exactly one tile, the min-priority merge reproduces the
+    single-block match order exactly."""
     fp, i1u, i2u = hashing.candidate_buckets_masked(h, qmask, jnp)
     i1 = i1u.astype(jnp.int32)
     i2 = i2u.astype(jnp.int32)
-    r1 = qoff + i1
-    r2 = qoff + i2
 
     @pl.when(ti == 0)
     def _init():
-        hit_ref[...] = jnp.zeros((TILE,), jnp.int32)
-        head_ref[...] = jnp.full((TILE,), -1, jnp.int32)
+        hit_ref[...] = jnp.zeros((1, TILE), jnp.int32)
+        head_ref[...] = jnp.full((1, TILE), -1, jnp.int32)
         bucket_ref[...] = i2
-        slot_ref[...] = jnp.full((TILE,), slots - 1, jnp.int32)
-        prio_ref[...] = jnp.full((TILE,), 2 * slots, jnp.int32)
+        slot_ref[...] = jnp.full((1, TILE), slots - 1, jnp.int32)
+        prio_ref[...] = jnp.full((1, TILE), 2 * slots, jnp.int32)
 
     base = ti * row_tile
-    l1, l2 = r1 - base, r2 - base
+    l1 = qoff + i1 - base
+    l2 = qoff + i2 - base
     in1 = (l1 >= 0) & (l1 < row_tile)
     in2 = (l2 >= 0) & (l2 < row_tile)
 
-    fp_tab = fp_tab_ref[...]                          # (row_tile, S) f32
-    head_tab = head_tab_ref[...]
-    tab = jnp.concatenate([fp_tab, head_tab], axis=1)
-
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, row_tile), 1)
+    tab = tab_ref[...]                                  # (2S, row_tile) f32
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (row_tile, TILE), 0)
     # out-of-tile candidates produce all-zero one-hots -> zero rows -> no
     # match (query fingerprints are never the empty sentinel 0)
-    oh1 = ((row_iota == l1[:, None]) & in1[:, None]).astype(jnp.float32)
-    oh2 = ((row_iota == l2[:, None]) & in2[:, None]).astype(jnp.float32)
-    rows1 = jax.lax.dot(oh1, tab, precision=jax.lax.Precision.HIGHEST)
-    rows2 = jax.lax.dot(oh2, tab, precision=jax.lax.Precision.HIGHEST)
+    oh1 = ((row_iota == l1) & in1).astype(jnp.float32)
+    oh2 = ((row_iota == l2) & in2).astype(jnp.float32)
+    rows1 = jax.lax.dot(tab, oh1, precision=_HIGHEST)   # (2S, TILE)
+    rows2 = jax.lax.dot(tab, oh2, precision=_HIGHEST)
 
-    fps = jnp.concatenate([rows1[:, :slots], rows2[:, :slots]], axis=1)
-    heads = jnp.concatenate([rows1[:, slots:], rows2[:, slots:]], axis=1)
-
-    match = fps == fp.astype(jnp.float32)[:, None]          # (TILE, 2S)
-    pos_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, 2 * slots), 1)
-    first = jnp.min(jnp.where(match, pos_iota, 2 * slots), axis=1)
+    fpq = _fp_f32(fp)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (slots, TILE), 0)
+    none = 2 * slots
+    first = jnp.minimum(
+        jnp.min(jnp.where(rows1[:slots] == fpq, pos, none), axis=0,
+                keepdims=True),
+        jnp.min(jnp.where(rows2[:slots] == fpq, pos + slots, none), axis=0,
+                keepdims=True))                         # (1, TILE)
     better = first < prio_ref[...]
-    firstc = jnp.minimum(first, 2 * slots - 1)
-
-    sel = (pos_iota == firstc[:, None]).astype(jnp.float32)
-    head = jnp.sum(heads * sel, axis=1)                     # exact gather
+    # exact gather of the winning slot's head (one nonzero term)
+    head = (jnp.sum(jnp.where(pos == first, rows1[slots:], 0.0), axis=0,
+                    keepdims=True) +
+            jnp.sum(jnp.where(pos + slots == first, rows2[slots:], 0.0),
+                    axis=0, keepdims=True))
 
     hit_ref[...] = jnp.where(better, 1, hit_ref[...])
     head_ref[...] = jnp.where(better, head.astype(jnp.int32), head_ref[...])
-    bucket_ref[...] = jnp.where(better,
-                                jnp.where(first < slots, i1, i2),
+    bucket_ref[...] = jnp.where(better, jnp.where(first < slots, i1, i2),
                                 bucket_ref[...])
     slot_ref[...] = jnp.where(better,
-                              jnp.where(first < slots, firstc,
-                                        firstc - slots),
+                              jnp.where(first < slots, first, first - slots),
                               slot_ref[...])
     prio_ref[...] = jnp.where(better, first, prio_ref[...])
 
 
-def _arena_kernel_sp(off_ref, nb_ref, tid_ref, h_ref, fp_tab_ref,
-                     head_tab_ref, hit_ref, head_ref, bucket_ref, slot_ref,
-                     prio_ref, *, slots: int, row_tile: int,
-                     num_trees: int):
-    """Tree-routed arena kernel with the per-tree routing tables in SMEM.
-
-    ``bucket_offsets``/``tree_nb`` are **scalar-prefetch operands**
-    (``pltpu.PrefetchScalarGridSpec``): O(T) ints resident in SMEM for
-    the whole launch instead of per-query-expanded (B,) VMEM operands —
-    the wrapper no longer materializes a gathered offset/mask pair per
-    query.  The per-lane gather happens here: an iota-compare one-hot sum
-    over the SMEM tables (VPU work; T is small by construction — the
-    tables are the same O(T) arrays the sharded router replicates).
-    Everything downstream is the shared :func:`_arena_probe`, so results
-    stay bit-identical to the pre-routed kernel and the jnp reference.
-    """
-    ti = pl.program_id(1)
-    h = h_ref[...].astype(jnp.uint32)                       # (TILE,)
-    tid = tid_ref[...].astype(jnp.int32)                    # clamped valid
-    offs = off_ref[...].astype(jnp.int32)                   # (T + 1,) SMEM
-    nbs = nb_ref[...].astype(jnp.int32)                     # (T,) SMEM
-    t_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, num_trees), 1)
-    sel = t_iota == tid[:, None]
-    qoff = jnp.sum(jnp.where(sel, offs[None, :num_trees], 0), axis=1)
-    qnb = jnp.sum(jnp.where(sel, nbs[None, :], 0), axis=1)
-    qmask = (qnb - 1).astype(jnp.uint32)
-    _arena_probe(h, qoff, qmask, ti, fp_tab_ref, head_tab_ref, hit_ref,
-                 head_ref, bucket_ref, slot_ref, prio_ref, slots=slots,
-                 row_tile=row_tile)
+def _arena_kernel(h_ref, off_ref, mask_ref, tab_ref, hit_ref, head_ref,
+                  bucket_ref, slot_ref, prio_ref, *, slots: int,
+                  row_tile: int):
+    _arena_probe(h_ref[...], off_ref[...], mask_ref[...], pl.program_id(1),
+                 tab_ref, hit_ref, head_ref, bucket_ref, slot_ref, prio_ref,
+                 slots=slots, row_tile=row_tile)
 
 
 def cuckoo_lookup_arena_pallas(h: jax.Array, row_offsets: jax.Array,
-                               masks: jax.Array, fp_table_f32: jax.Array,
-                               head_table_f32: jax.Array,
-                               interpret: bool = True,
-                               row_tile: int = 0):
-    """h/row_offsets/masks: (B,) with B % TILE == 0; tables: (A, S) f32.
+                               masks: jax.Array, table_f32: jax.Array,
+                               interpret: bool = True, row_tile: int = 0,
+                               vmem_limit: int = 0):
+    """Pre-routed arena probe.
 
-    ``row_tile == 0`` keeps the whole arena as one VMEM block (right for
-    the many-small-trees regime); ``row_tile > 0`` tiles the arena rows
-    over a second grid dimension — the caller must pad A to a multiple of
-    ``row_tile`` (zero rows = empty fingerprints, so padding never
-    matches).  Arenas larger than a device should shard over the mesh
-    first (core.distributed) and route within each shard.
+    h (uint32) / row_offsets (int32) / masks (uint32): ``(1, B)`` with
+    ``B % TILE == 0``; ``table_f32``: ``(2S, A)`` from ``ops.stage_tables``
+    with ``A`` a multiple of TILE.  ``row_tile == 0`` keeps the whole
+    arena as one VMEM block; ``row_tile > 0`` (a TILE multiple dividing
+    A) streams arena tiles over a second grid dimension.  Returns (hit,
+    head, bucket, slot), each ``(1, B)`` int32.
     """
-    rows_total, slots = fp_table_f32.shape
-    b = h.shape[0]
+    two_s, rows_total = table_f32.shape
+    b = h.shape[1]
     rt = rows_total if row_tile <= 0 else row_tile
-    assert rows_total % rt == 0, \
-        "pad the arena to a multiple of row_tile before calling"
+    assert rows_total % rt == 0 and rt % TILE == 0, \
+        "pad the arena to a multiple of row_tile (and TILE) before calling"
     grid = (b // TILE, rows_total // rt)       # arena axis innermost
-    qspec = pl.BlockSpec((TILE,), lambda qi, ti: (qi,))
-    tabspec = pl.BlockSpec((rt, slots), lambda qi, ti: (ti, 0))
-    out_shapes = [jax.ShapeDtypeStruct((b,), jnp.int32) for _ in range(5)]
+    qspec = pl.BlockSpec((1, TILE), lambda qi, ti: (0, qi))
+    tabspec = pl.BlockSpec((two_s, rt), lambda qi, ti: (0, ti))
     outs = pl.pallas_call(
-        functools.partial(_arena_kernel, slots=slots, row_tile=rt),
+        functools.partial(_arena_kernel, slots=two_s // 2, row_tile=rt),
         grid=grid,
-        in_specs=[qspec, qspec, qspec, tabspec, tabspec],
+        in_specs=[qspec, qspec, qspec, tabspec],
         out_specs=[qspec] * 5,
-        out_shape=out_shapes,
+        out_shape=[jax.ShapeDtypeStruct((1, b), jnp.int32)
+                   for _ in range(5)],
+        compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
-    )(h, row_offsets, masks, fp_table_f32, head_table_f32)
+        name="cuckoo_probe",
+    )(h, row_offsets, masks, table_f32)
     return outs[:4]                            # drop the priority scratch
-
-
-def cuckoo_lookup_ragged_pallas(h: jax.Array, tree_ids: jax.Array,
-                                bucket_offsets: jax.Array,
-                                tree_nb: jax.Array,
-                                fp_table_f32: jax.Array,
-                                head_table_f32: jax.Array,
-                                interpret: bool = True,
-                                row_tile: int = 0):
-    """Tree-routed ragged lookup with SMEM scalar-prefetched routing.
-
-    h/tree_ids: (B,) with B % TILE == 0 (tree_ids pre-clamped to
-    [0, T-1]); bucket_offsets: (T + 1,); tree_nb: (T,); tables: (A, S)
-    f32.  The two per-tree tables ride as scalar-prefetch args (SMEM)
-    rather than per-query VMEM operands; ``row_tile`` tiles the arena
-    rows exactly as :func:`cuckoo_lookup_arena_pallas`.  Falls back to
-    the pre-gathered arena kernel when the jax build exposes no TPU
-    grid-spec module.
-    """
-    if pltpu is None:                      # pragma: no cover - build-dep
-        off = bucket_offsets[tree_ids]
-        mask = (tree_nb[tree_ids] - 1).astype(jnp.uint32)
-        return cuckoo_lookup_arena_pallas(
-            h, off, mask, fp_table_f32, head_table_f32,
-            interpret=interpret, row_tile=row_tile)
-    rows_total, slots = fp_table_f32.shape
-    b = h.shape[0]
-    rt = rows_total if row_tile <= 0 else row_tile
-    assert rows_total % rt == 0, \
-        "pad the arena to a multiple of row_tile before calling"
-    num_trees = tree_nb.shape[0]
-    grid = (b // TILE, rows_total // rt)       # arena axis innermost
-    # index maps receive the scalar-prefetch refs after the grid indices
-    qspec = pl.BlockSpec((TILE,), lambda qi, ti, off, nb: (qi,))
-    tabspec = pl.BlockSpec((rt, slots), lambda qi, ti, off, nb: (ti, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[qspec, qspec, tabspec, tabspec],
-        out_specs=[qspec] * 5,
-    )
-    out_shapes = [jax.ShapeDtypeStruct((b,), jnp.int32) for _ in range(5)]
-    outs = pl.pallas_call(
-        functools.partial(_arena_kernel_sp, slots=slots, row_tile=rt,
-                          num_trees=num_trees),
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(bucket_offsets.astype(jnp.int32), tree_nb.astype(jnp.int32),
-      tree_ids, h, fp_table_f32, head_table_f32)
-    return outs[:4]                            # drop the priority scratch
-
-
-def cuckoo_lookup_bank_pallas(h: jax.Array, tree_ids: jax.Array,
-                              fp_table_f32: jax.Array,
-                              head_table_f32: jax.Array, num_buckets: int,
-                              interpret: bool = True,
-                              tree_tile: int = 0):
-    """h/tree_ids: (B,) with B % TILE == 0; tables: (T * NB, S) float32.
-
-    ``tree_tile == 0`` is the single-block path: the whole bank lives as
-    one VMEM block — right for the many-small-trees regime (a few MiB at
-    most).  ``tree_tile > 0`` tiles the tree axis over a second grid
-    dimension so only ``tree_tile * NB`` bucket rows are resident per
-    step; the caller must pad T to a multiple of ``tree_tile`` (zero rows
-    = empty fingerprints, so padded trees can never match).  Banks larger
-    than a device should shard over the mesh first (core.distributed) and
-    route within each shard.
-    """
-    rows_total, slots = fp_table_f32.shape
-    b = h.shape[0]
-    out_shapes = [jax.ShapeDtypeStruct((b,), jnp.int32) for _ in range(4)]
-    if tree_tile <= 0:
-        grid = (b // TILE,)
-        qspec = pl.BlockSpec((TILE,), lambda i: (i,))
-        tabspec = pl.BlockSpec((rows_total, slots), lambda i: (0, 0))
-        return pl.pallas_call(
-            functools.partial(_bank_kernel, num_buckets=num_buckets,
-                              slots=slots),
-            grid=grid,
-            in_specs=[qspec, qspec, tabspec, tabspec],
-            out_specs=[qspec] * 4,
-            out_shape=out_shapes,
-            interpret=interpret,
-        )(h, tree_ids, fp_table_f32, head_table_f32)
-
-    block_rows = tree_tile * num_buckets
-    assert rows_total % block_rows == 0, \
-        "pad T to a multiple of tree_tile before calling"
-    grid = (b // TILE, rows_total // block_rows)   # tree axis innermost
-    qspec = pl.BlockSpec((TILE,), lambda qi, ti: (qi,))
-    tabspec = pl.BlockSpec((block_rows, slots), lambda qi, ti: (ti, 0))
-    return pl.pallas_call(
-        functools.partial(_bank_kernel_tiled, num_buckets=num_buckets,
-                          slots=slots, tree_tile=tree_tile),
-        grid=grid,
-        in_specs=[qspec, qspec, tabspec, tabspec],
-        out_specs=[qspec] * 4,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(h, tree_ids, fp_table_f32, head_table_f32)

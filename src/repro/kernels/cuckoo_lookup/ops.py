@@ -1,129 +1,129 @@
-"""Public jit'd wrapper for the cuckoo-lookup Pallas kernel.
+"""Public jit'd wrappers for the cuckoo-lookup Pallas kernel.
 
-Handles: query padding to the TILE multiple, int->f32 table staging (done
-once per table version, not per query), interpret-mode selection off the
-backend, and repackaging into core.lookup.LookupResult.
+Handles: query padding to the TILE multiple and the kernel's lane layout,
+int -> f32 table staging, the row tile and VMEM limit of a launch on the
+chip, interpret-mode selection off the backend, and repackaging into
+core.lookup.LookupResult.  Every entry probes through the one arena kernel:
+a single filter is an arena of one segment, a dense ``(T, NB, S)`` bank an
+arena of T equal segments.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ...core.lookup import LookupResult
 from .. import vmem
-from .kernel import (TILE, cuckoo_lookup_arena_pallas,
-                     cuckoo_lookup_bank_pallas, cuckoo_lookup_pallas,
-                     cuckoo_lookup_ragged_pallas)
+from .kernel import TILE, cuckoo_lookup_arena_pallas
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def stage_tables(fingerprints: jax.Array, heads: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """One-time conversion of int tables to the kernel's f32 layout."""
-    return (fingerprints.astype(jnp.float32), heads.astype(jnp.float32))
+def stage_tables(fingerprints: jax.Array, heads: jax.Array,
+                 rows: int) -> jax.Array:
+    """``(A, S)`` int tables -> the kernel's ``(2S, rows)`` f32 layout:
+    fingerprint slots over head slots, zero-padded to ``rows`` arena rows
+    (an empty fingerprint never matches)."""
+    a = fingerprints.shape[0]
+    tab = jnp.concatenate([fingerprints, heads], axis=1).astype(jnp.float32)
+    return jnp.pad(tab, ((0, rows - a), (0, 0))).T
+
+
+def padded_rows(arena_rows: int, row_tile: int) -> int:
+    """Arena rows after padding to a whole number of grid tiles."""
+    unit = row_tile if row_tile > 0 else TILE
+    return -(-arena_rows // unit) * unit
+
+
+def lane_queries(b: int, *arrs):
+    """Pad ``(B,)`` query arrays to a TILE multiple, as ``(1, Bp)`` rows."""
+    pad = (-b) % TILE
+    return [jnp.pad(a, (0, pad))[None, :] for a in arrs]
+
+
+def pick_row_tile(arena_rows: int, interpret: bool,
+                  resident_bytes: int = 0) -> int:
+    """Row tile of a launch: one block in interpret mode (no VMEM to fit),
+    else the attached TPU's tile budget."""
+    if interpret:
+        return 0
+    cap = vmem.max_rows_for_vmem(vmem.device_budget(slots=4, tile=TILE),
+                                 TILE, resident_bytes)
+    return vmem.row_tile_for(arena_rows, cap)
+
+
+def launch_vmem_limit(interpret: bool) -> int:
+    """Scoped VMEM limit of a launch (0 = compiler default)."""
+    return 0 if interpret else vmem.device_budget(slots=4,
+                                                  tile=TILE).limit_bytes
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "row_tile",
+                                             "vmem_limit"))
+def cuckoo_lookup_arena(fingerprints: jax.Array, heads: jax.Array,
+                        row_offsets: jax.Array, masks: jax.Array,
+                        h: jax.Array, interpret: bool = True,
+                        row_tile: int = -1,
+                        vmem_limit: int = 0) -> LookupResult:
+    """Ragged-arena lookup with pre-routed queries — same signature and
+    semantics as ``core.lookup.lookup_arena``.  Tables: flat ``(A, S)``;
+    ``row_offsets``/``masks``: per-query segment start and ``nb_t - 1``.
+
+    ``row_tile``: -1 auto-selects (:func:`pick_row_tile`); 0 forces the
+    single-block path; > 0 (a TILE multiple) forces that many arena rows
+    per grid step.  ``vmem_limit`` 0 launches under the scoped limit the
+    tile budget assumes (:func:`launch_vmem_limit`).  The arena is padded
+    here with empty-fingerprint rows (which can never match), so callers
+    never pre-pad.
+    """
+    a, _ = fingerprints.shape
+    if row_tile < 0:
+        row_tile = pick_row_tile(a, interpret)
+    vmem_limit = vmem_limit or launch_vmem_limit(interpret)
+    b = h.shape[0]
+    hp, op, mp = lane_queries(b, h.astype(jnp.uint32),
+                              row_offsets.astype(jnp.int32),
+                              masks.astype(jnp.uint32))
+    tab = stage_tables(fingerprints, heads, padded_rows(a, row_tile))
+    hit, head, bucket, slot = cuckoo_lookup_arena_pallas(
+        hp, op, mp, tab, interpret=interpret, row_tile=row_tile,
+        vmem_limit=vmem_limit)
+    return LookupResult(hit=hit[0, :b].astype(jnp.bool_), head=head[0, :b],
+                        bucket=bucket[0, :b], slot=slot[0, :b])
+
+
+def cuckoo_lookup_arena_auto(fingerprints, heads, row_offsets, masks, h
+                             ) -> LookupResult:
+    """Kernel on TPU, interpret elsewhere — serving's ragged-arena entry
+    (the ``lookup_fn`` shape ``retrieve_device`` and the sharded probe
+    consume)."""
+    return cuckoo_lookup_arena(fingerprints, heads, row_offsets, masks, h,
+                               interpret=not on_tpu())
+
+
+def _uniform_masks(h: jax.Array, num_buckets: int) -> jax.Array:
+    return jnp.full(h.shape, num_buckets - 1, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def cuckoo_lookup(fingerprints: jax.Array, heads: jax.Array, h: jax.Array,
                   interpret: bool = True) -> LookupResult:
-    """Same signature/semantics as core.lookup.lookup_batch."""
-    b = h.shape[0]
-    pad = (-b) % TILE
-    hp = jnp.pad(h, (0, pad))
-    fp32, hd32 = stage_tables(fingerprints, heads)
-    hit, head, bucket, slot = cuckoo_lookup_pallas(
-        hp.astype(jnp.uint32), fp32, hd32, interpret=interpret)
-    return LookupResult(hit=hit[:b].astype(jnp.bool_), head=head[:b],
-                        bucket=bucket[:b], slot=slot[:b])
+    """Same signature/semantics as core.lookup.lookup_batch: one filter
+    ``(NB, S)`` is an arena of one segment."""
+    nb = fingerprints.shape[0]
+    return cuckoo_lookup_arena(fingerprints, heads,
+                               jnp.zeros(h.shape, jnp.int32),
+                               _uniform_masks(h, nb), h,
+                               interpret=interpret)
 
 
 def cuckoo_lookup_auto(fingerprints, heads, h) -> LookupResult:
-    """Kernel on TPU, interpret elsewhere — the serving engine's entry."""
+    """Kernel on TPU, interpret elsewhere."""
     return cuckoo_lookup(fingerprints, heads, h, interpret=not on_tpu())
-
-
-# Past SINGLE_BLOCK_MAX_ROWS flat bucket rows the bank/arena kernels tile
-# the row axis so the VMEM-resident working set stays bounded instead of
-# growing with the bank.  The budget derivation lives in
-# ``repro.kernels.vmem`` (shared with the fused retrieval kernel): half of
-# a 16 MiB core for the streamed tiles, per-row cost from the documented
-# closed form — 4 * (4*S + 2*TILE) bytes: fp+head blocks, their (rows, 2S)
-# concat, and two (TILE, rows) one-hot gather operands.
-#
-# SINGLE_BLOCK_MAX_ROWS is the *closed-form* cap and is resolved at
-# import (the tiling threshold must not compile kernels, and the jitted
-# wrappers auto-pick tiles at trace time where lowering a second kernel is
-# off limits).  The non-traced ``*_auto`` serving entries refine the tile
-# *size* with the measured derivation — ``memory_analysis()`` on the
-# compiled probe, lazily, once — which typically roughly doubles the tile
-# (XLA fuses the concat and one-hots, so the true slope is about half the
-# closed form).
-def max_rows_for_vmem(slots: int = 4, tile: int = TILE,
-                      budget: int = 0) -> int:
-    """Largest per-step row-tile (a TILE multiple) fitting the VMEM budget
-    for the one-hot-matmul lookup working set (closed form; pass a budget
-    to override the shared default)."""
-    bd = vmem.VmemBudget(
-        budget or int(vmem.DEFAULT_VMEM_BYTES * vmem.BUDGET_FRACTION),
-        vmem.closed_form_row_bytes(slots, tile), "closed_form")
-    return vmem.max_rows_for_vmem(bd, tile)
-
-
-SINGLE_BLOCK_MAX_ROWS = max_rows_for_vmem()
-
-
-def _probe_lower(rows: int):
-    """Lower the single-block arena probe at ``rows`` arena rows — the
-    measurement target for the shared VMEM derivation."""
-    s = 4
-    h = jnp.zeros((TILE,), jnp.uint32)
-    off = jnp.zeros((TILE,), jnp.int32)
-    mask = jnp.zeros((TILE,), jnp.uint32)
-    fp = jnp.zeros((rows, s), jnp.float32)
-    hd = jnp.zeros((rows, s), jnp.float32)
-    fn = jax.jit(functools.partial(cuckoo_lookup_arena_pallas,
-                                   interpret=not on_tpu(), row_tile=0))
-    return fn.lower(h, off, mask, fp, hd)
-
-
-def lookup_vmem_budget() -> "vmem.VmemBudget":
-    """The arena kernels' VMEM budget: measured per-row slope where the
-    backend exposes compiled memory stats, documented closed form else.
-    Cached after the first call (one probe compile)."""
-    return vmem.derive_budget(slots=4, tile=TILE, measure=_probe_lower)
-
-
-_measured_max_rows: int = 0
-
-
-def _max_rows() -> int:
-    """Measured-budget row cap for the auto entries, derived lazily."""
-    global _measured_max_rows
-    if not _measured_max_rows:
-        _measured_max_rows = vmem.max_rows_for_vmem(lookup_vmem_budget(),
-                                                    TILE)
-    return _measured_max_rows
-
-
-def _auto_row_tile(a: int) -> int:
-    """Row tile for the non-traced auto entries: single block below the
-    closed-form threshold, measured-budget tiles above it."""
-    if a <= SINGLE_BLOCK_MAX_ROWS:
-        return 0
-    return min(_max_rows(), (a + TILE - 1) // TILE * TILE)
-
-
-def _pick_tree_tile(t: int, nb: int) -> int:
-    """0 = single-block; else trees per grid step (>= 1)."""
-    if t * nb <= SINGLE_BLOCK_MAX_ROWS:
-        return 0
-    return max(1, SINGLE_BLOCK_MAX_ROWS // nb)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tree_tile"))
@@ -132,32 +132,22 @@ def cuckoo_lookup_bank(fingerprints: jax.Array, heads: jax.Array,
                        interpret: bool = True,
                        tree_tile: int = -1) -> LookupResult:
     """Bank lookup with per-query tree routing — same signature/semantics
-    as core.lookup.lookup_batch_bank.  Tables: (T, NB, S).
+    as core.lookup.lookup_batch_bank.  Tables: (T, NB, S), probed as an
+    arena of T segments of NB rows.
 
-    ``tree_tile``: -1 auto-selects (single VMEM block for small banks,
-    tree-axis grid tiling past ``SINGLE_BLOCK_MAX_ROWS`` flat rows);
-    0 forces the single-block path; > 0 forces that many trees per grid
-    step.  T is padded here to a tile multiple with empty-fingerprint rows
-    (which can never match), so callers never pre-pad.
+    ``tree_tile``: -1 auto-selects; 0 forces the single-block path; > 0
+    streams about that many trees per grid step (rounded up to a TILE
+    multiple of rows).  Out-of-range tree ids route outside the arena and
+    miss.
     """
     t, nb, s = fingerprints.shape
-    if tree_tile < 0:
-        tree_tile = _pick_tree_tile(t, nb)
-    b = h.shape[0]
-    pad = (-b) % TILE
-    hp = jnp.pad(h, (0, pad))
-    tp = jnp.pad(tree_ids.astype(jnp.int32), (0, pad))
-    fps2, hds2 = fingerprints.reshape(t * nb, s), heads.reshape(t * nb, s)
-    if tree_tile > 0:
-        row_pad = ((-t) % tree_tile) * nb
-        fps2 = jnp.pad(fps2, ((0, row_pad), (0, 0)))
-        hds2 = jnp.pad(hds2, ((0, row_pad), (0, 0)))
-    fp32, hd32 = stage_tables(fps2, hds2)
-    hit, head, bucket, slot = cuckoo_lookup_bank_pallas(
-        hp.astype(jnp.uint32), tp, fp32, hd32, num_buckets=nb,
-        interpret=interpret, tree_tile=tree_tile)
-    return LookupResult(hit=hit[:b].astype(jnp.bool_), head=head[:b],
-                        bucket=bucket[:b], slot=slot[:b])
+    row_tile = (-1 if tree_tile < 0 else
+                0 if tree_tile == 0 else padded_rows(tree_tile * nb, 0))
+    return cuckoo_lookup_arena(fingerprints.reshape(t * nb, s),
+                               heads.reshape(t * nb, s),
+                               tree_ids.astype(jnp.int32) * nb,
+                               _uniform_masks(h, nb), h,
+                               interpret=interpret, row_tile=row_tile)
 
 
 def cuckoo_lookup_bank_auto(fingerprints, heads, tree_ids, h
@@ -167,110 +157,42 @@ def cuckoo_lookup_bank_auto(fingerprints, heads, tree_ids, h
                               interpret=not on_tpu())
 
 
-def _pick_row_tile(a: int) -> int:
-    """0 = single-block; else arena rows per grid step."""
-    return 0 if a <= SINGLE_BLOCK_MAX_ROWS else SINGLE_BLOCK_MAX_ROWS
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "row_tile"))
-def cuckoo_lookup_arena(fingerprints: jax.Array, heads: jax.Array,
-                        row_offsets: jax.Array, masks: jax.Array,
-                        h: jax.Array, interpret: bool = True,
-                        row_tile: int = -1) -> LookupResult:
-    """Ragged-arena lookup with pre-routed queries — same signature and
-    semantics as ``core.lookup.lookup_arena``.  Tables: flat ``(A, S)``;
-    ``row_offsets``/``masks``: per-query segment start and ``nb_t - 1``.
-
-    ``row_tile``: -1 auto-selects (single VMEM block for small arenas,
-    arena-row grid tiling past ``SINGLE_BLOCK_MAX_ROWS``); 0 forces the
-    single-block path; > 0 forces that many arena rows per grid step.  The
-    arena is padded here to a tile multiple with empty-fingerprint rows
-    (which can never match), so callers never pre-pad.
-    """
-    a, s = fingerprints.shape
-    if row_tile < 0:
-        row_tile = _pick_row_tile(a)
-    b = h.shape[0]
-    pad = (-b) % TILE
-    hp = jnp.pad(h.astype(jnp.uint32), (0, pad))
-    op = jnp.pad(row_offsets.astype(jnp.int32), (0, pad))
-    mp = jnp.pad(masks.astype(jnp.uint32), (0, pad))
-    fps2, hds2 = fingerprints, heads
-    if row_tile > 0:
-        row_pad = (-a) % row_tile
-        fps2 = jnp.pad(fps2, ((0, row_pad), (0, 0)))
-        hds2 = jnp.pad(hds2, ((0, row_pad), (0, 0)))
-    fp32, hd32 = stage_tables(fps2, hds2)
-    hit, head, bucket, slot = cuckoo_lookup_arena_pallas(
-        hp, op, mp, fp32, hd32, interpret=interpret, row_tile=row_tile)
-    return LookupResult(hit=hit[:b].astype(jnp.bool_), head=head[:b],
-                        bucket=bucket[:b], slot=slot[:b])
-
-
-def cuckoo_lookup_arena_auto(fingerprints, heads, row_offsets, masks, h
-                             ) -> LookupResult:
-    """Kernel on TPU, interpret elsewhere — serving's ragged-arena entry
-    (the ``lookup_fn`` shape ``retrieve_device`` and the sharded probe
-    consume).  Tile size refined by the measured VMEM budget."""
-    return cuckoo_lookup_arena(fingerprints, heads, row_offsets, masks, h,
-                               interpret=not on_tpu(),
-                               row_tile=_auto_row_tile(
-                                   fingerprints.shape[0]))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "row_tile"))
+@functools.partial(jax.jit, static_argnames=("interpret", "row_tile",
+                                             "vmem_limit"))
 def cuckoo_lookup_ragged(fingerprints: jax.Array, heads: jax.Array,
                          bucket_offsets: jax.Array, tree_nb: jax.Array,
                          tree_ids: jax.Array, h: jax.Array,
-                         interpret: bool = True,
-                         row_tile: int = -1) -> LookupResult:
+                         interpret: bool = True, row_tile: int = -1,
+                         vmem_limit: int = 0) -> LookupResult:
     """Tree-routed ragged lookup — same signature/semantics as
-    ``core.lookup.lookup_batch_ragged``.  The per-tree offsets/nb tables
-    are O(T) and SMEM-sized: they ride into the kernel as scalar-prefetch
-    operands (``PrefetchScalarGridSpec``) and the per-query routing
-    gather happens in-kernel from SMEM — no (B,)-expanded offset/mask
-    VMEM operands.  Out-of-range tree ids are clamped (matching the jnp
-    reference's clipped gather); the pre-routed
-    :func:`cuckoo_lookup_arena` remains the sharded router's contract.
+    ``core.lookup.lookup_batch_ragged``.  Each query's (segment start,
+    bucket mask) pair is gathered here from the O(T) per-tree tables and
+    the pre-routed :func:`cuckoo_lookup_arena` probes; out-of-range tree
+    ids are clamped (matching the jnp reference's clipped gather).
     """
-    a, s = fingerprints.shape
-    if row_tile < 0:
-        row_tile = _pick_row_tile(a)
-    b = h.shape[0]
-    pad = (-b) % TILE
-    hp = jnp.pad(h.astype(jnp.uint32), (0, pad))
-    tp = jnp.clip(jnp.pad(tree_ids.astype(jnp.int32), (0, pad)),
-                  0, tree_nb.shape[0] - 1)
-    fps2, hds2 = fingerprints, heads
-    if row_tile > 0:
-        row_pad = (-a) % row_tile
-        fps2 = jnp.pad(fps2, ((0, row_pad), (0, 0)))
-        hds2 = jnp.pad(hds2, ((0, row_pad), (0, 0)))
-    fp32, hd32 = stage_tables(fps2, hds2)
-    hit, head, bucket, slot = cuckoo_lookup_ragged_pallas(
-        hp, tp, bucket_offsets, tree_nb, fp32, hd32, interpret=interpret,
-        row_tile=row_tile)
-    return LookupResult(hit=hit[:b].astype(jnp.bool_), head=head[:b],
-                        bucket=bucket[:b], slot=slot[:b])
+    t = jnp.clip(tree_ids.astype(jnp.int32), 0, tree_nb.shape[0] - 1)
+    return cuckoo_lookup_arena(fingerprints, heads, bucket_offsets[t],
+                               (tree_nb[t] - 1).astype(jnp.uint32), h,
+                               interpret=interpret, row_tile=row_tile,
+                               vmem_limit=vmem_limit)
 
 
 def cuckoo_lookup_ragged_auto(fingerprints, heads, bucket_offsets, tree_nb,
                               tree_ids, h) -> LookupResult:
-    """Kernel on TPU, interpret elsewhere — tree-routed ragged entry.
-    Tile size refined by the measured VMEM budget."""
+    """Kernel on TPU, interpret elsewhere — tree-routed ragged entry."""
     return cuckoo_lookup_ragged(fingerprints, heads, bucket_offsets,
                                 tree_nb, tree_ids, h,
-                                interpret=not on_tpu(),
-                                row_tile=_auto_row_tile(
-                                    fingerprints.shape[0]))
+                                interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def cuckoo_lookup_trees(fingerprints: jax.Array, heads: jax.Array,
                         h: jax.Array, interpret: bool = True
                         ) -> LookupResult:
-    """Vmapped-over-trees kernel entry: tables (T, NB, S), h (T, B) —
-    one dense query batch per tree, result fields shaped (T, B)."""
-    return jax.vmap(
-        lambda f, d, q: cuckoo_lookup(f, d, q, interpret=interpret)
-    )(fingerprints, heads, h)
+    """Per-tree entry: tables (T, NB, S), h (T, B) — one dense query batch
+    per tree, result fields shaped (T, B)."""
+    t, b = h.shape
+    tid = jnp.repeat(jnp.arange(t, dtype=jnp.int32), b)
+    res = cuckoo_lookup_bank(fingerprints, heads, tid, h.reshape(-1),
+                             interpret=interpret)
+    return LookupResult(*(x.reshape(t, b) for x in res))

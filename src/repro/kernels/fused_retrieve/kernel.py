@@ -15,7 +15,15 @@ tail consumes them in-register on the *last* arena tile, when the
 cross-tile priority merge has settled.  The CSR/forest tables and the
 temperature table ride as whole VMEM blocks with constant index maps
 (resident for the launch, consecutively revisited — the budget in
-``ops.fused_row_tile`` accounts for them).
+``ops.context_resident_bytes`` accounts for them).
+
+Layout, as in the probe kernel: queries ride the lanes, so per-query
+values are ``(1, TILE)`` rows and every table is staged transposed —
+``(columns, rows)`` — so a gather returns ``(columns, TILE)`` and a column
+is a sublane row.  The context tables are further cut into fixed-width
+chunks along their rows (``ops.chunk_columns``) that a gather loops over.
+The temperature table is ``(S, A)``; the location and hierarchy outputs
+are ``(max_locs, 1 | n, B)``.
 
 Two static gather strategies (``mxu``):
   * ``mxu=True``  — one-hot matmul gathers on the MXU (TPU; exact in f32
@@ -33,80 +41,90 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:                      # TPU grid specs (scalar prefetch); optional on
-    from jax.experimental.pallas import tpu as pltpu   # CPU-only installs
-except ImportError:       # pragma: no cover - depends on the jax build
-    pltpu = None
-
-from ..cuckoo_lookup.kernel import TILE, _arena_probe
+from ..cuckoo_lookup.kernel import TILE, _arena_probe, compiler_params
 
 NULL = -1
 _HIGHEST = jax.lax.Precision.HIGHEST
+#: Context-table columns one MXU gather step covers; its one-hot operand
+#: is ``(GATHER_CHUNK, TILE)`` f32 (512 KiB).
+GATHER_CHUNK = 1024
 
 
-def _gather_rows(tab, idx, gate, mxu):
-    """Gather rows of ``tab`` (R, C) f32 at ``idx`` (TILE,) int32; lanes
-    with ``gate`` False yield zero rows (callers re-mask with their own
-    sentinel).  mxu: one-hot matmul; else clipped direct indexing."""
-    rows = tab.shape[0]
-    if mxu:
-        it = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], rows), 1)
-        oh = ((it == idx[:, None]) & gate[:, None]).astype(jnp.float32)
-        return jax.lax.dot(oh, tab, precision=_HIGHEST)
-    safe = jnp.clip(idx, 0, rows - 1)
-    return jnp.where(gate[:, None], tab[safe], jnp.float32(0))
+def _gather_rows(tab_ref, idx, gate, mxu):
+    """Gather columns ``idx`` (1, TILE) int32 of a context table staged as
+    ``(K, C, W)`` chunks (column ``r`` at ``[r // W, :, r % W]``, see
+    ``ops.chunk_columns``) -> (C, TILE) f32; lanes with ``gate`` False, or
+    an index past the table, yield zeros (callers re-mask with their own
+    sentinel).  mxu: one-hot matmul per chunk, accumulated over a loop so
+    the one-hot operand stays ``(W, TILE)`` whatever the table length (one
+    chunk holds a lane's column, the others add exact zeros); else clipped
+    indexing."""
+    nch, c, w = tab_ref.shape
+    if not mxu:
+        safe = jnp.clip(idx[0], 0, nch * w - 1)
+        cols = tab_ref[...][safe // w, :, safe % w].T           # (C, TILE)
+        return jnp.where(gate, cols, jnp.float32(0))
+    it = jax.lax.broadcasted_iota(jnp.int32, (w, TILE), 0)
+
+    def step(k, acc):
+        oh = ((it + k * w == idx) & gate).astype(jnp.float32)
+        return acc + jax.lax.dot(tab_ref[k], oh, precision=_HIGHEST)
+
+    return jax.lax.fori_loop(0, nch, step,
+                             jnp.zeros((c, TILE), jnp.float32))
 
 
-def _up_walk(nodes, pe_tab, n, mxu):
-    """Ancestor window (TILE, n) — mirrors ``gather_hierarchy_unrolled``
-    on the packed (N, 2) [parent | entity_id] table."""
+def _as_int(row):
+    return row.astype(jnp.int32)
+
+
+def _up_walk(nodes, pe_ref, n, mxu):
+    """Ancestor window: ``n`` (1, TILE) rows — mirrors
+    ``gather_hierarchy_unrolled`` on the packed [parent; entity_id]
+    table."""
     cur = nodes
     outs = []
     for _ in range(n):
         g = cur != NULL
-        prow = _gather_rows(pe_tab, jnp.maximum(cur, 0), g, mxu)
-        p = jnp.where(g, prow[:, 0].astype(jnp.int32), NULL)
+        prow = _gather_rows(pe_ref, jnp.maximum(cur, 0), g, mxu)
+        p = jnp.where(g, _as_int(prow[0:1]), NULL)
         g2 = p != NULL
-        erow = _gather_rows(pe_tab, jnp.maximum(p, 0), g2, mxu)
-        outs.append(jnp.where(g2, erow[:, 1].astype(jnp.int32), NULL))
+        erow = _gather_rows(pe_ref, jnp.maximum(p, 0), g2, mxu)
+        outs.append(jnp.where(g2, _as_int(erow[1:2]), NULL))
         cur = p
-    return jnp.stack(outs, axis=1)
+    return outs
 
 
-def _down_walk(nodes, child_lc_tab, child_index_tab, pe_tab, n, mxu):
-    """Descendant window (TILE, n) — mirrors
-    ``gather_descendants_unrolled`` on packed tables: child_lc (N, 2)
-    [child_lo | child_count], child_index (C, 1), entity ids from the
-    (N, 2) parent/entity table's second column."""
-    ci = child_index_tab.shape[0]
-    buf = jnp.full((TILE, n), NULL, jnp.int32)
-    w = jnp.zeros((TILE,), jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (TILE, n), 1)
+def _down_walk(nodes, child_lc_ref, child_index_ref, pe_ref, n, mxu):
+    """Descendant window: ``n`` (1, TILE) rows — mirrors
+    ``gather_descendants_unrolled`` on packed tables: child_lc
+    [child_lo; child_count], child_index, entity ids from the
+    parent/entity table's second row."""
+    null_row = jnp.full((1, TILE), NULL, jnp.int32)
+    buf = [null_row] * n                       # BFS frontier ring, cap n
+    w = jnp.zeros((1, TILE), jnp.int32)        # frontier write cursor
 
     def push(buf, w, src):
         g = src != NULL
-        lc = _gather_rows(child_lc_tab, jnp.maximum(src, 0), g, mxu)
-        lo = lc[:, 0].astype(jnp.int32)
-        hi = lo + lc[:, 1].astype(jnp.int32)
+        lc = _gather_rows(child_lc_ref, jnp.maximum(src, 0), g, mxu)
+        lo = _as_int(lc[0:1])
+        hi = lo + _as_int(lc[1:2])
         for k in range(n):
             idx = lo + k
             valid = g & (idx < hi) & (w < n)
-            crow = _gather_rows(child_index_tab, jnp.minimum(idx, ci - 1),
-                                valid, mxu)
-            c = jnp.where(valid, crow[:, 0].astype(jnp.int32), NULL)
-            oh = (lane == jnp.minimum(w, n - 1)[:, None]) & valid[:, None]
-            buf = jnp.where(oh, c[:, None], buf)
+            crow = _gather_rows(child_index_ref, idx, valid, mxu)
+            c = jnp.where(valid, _as_int(crow), NULL)
+            buf = [jnp.where(valid & (w == j), c, buf[j]) for j in range(n)]
             w = jnp.where(valid, w + 1, w)
         return buf, w
 
     buf, w = push(buf, w, nodes)
-    out = jnp.full((TILE, n), NULL, jnp.int32)
+    out = [null_row] * n
     for i in range(n):
-        cur = buf[:, i]
+        cur = buf[i]
         valid = (i < w) & (cur != NULL)
-        erow = _gather_rows(pe_tab, jnp.maximum(cur, 0), valid, mxu)
-        out = out.at[:, i].set(
-            jnp.where(valid, erow[:, 1].astype(jnp.int32), out[:, i]))
+        erow = _gather_rows(pe_ref, jnp.maximum(cur, 0), valid, mxu)
+        out[i] = jnp.where(valid, _as_int(erow[1:2]), out[i])
         buf, w = push(buf, w, jnp.where(valid, cur, NULL))
     return out
 
@@ -117,7 +135,8 @@ def _context_tail(qoff, valid, csr_lc_ref, csr_nodes_ref, parent_eid_ref,
                   temp_in_ref, temp_ref, qi, *, slots, max_locs, n, mxu,
                   locs_only):
     """Consume the settled probe accumulators: bump temperature, gather the
-    CSR window, walk the hierarchy — all from VMEM-resident tables."""
+    CSR window, walk the hierarchy — all from VMEM-resident tables.  One
+    loop step per location slot keeps the walks' code emitted once."""
     vhit = (hit_ref[...] > 0) & valid               # = unfused hit&in_range
     hit_ref[...] = vhit.astype(jnp.int32)           # the emitted hit
     bucket = bucket_ref[...]
@@ -127,55 +146,44 @@ def _context_tail(qoff, valid, csr_lc_ref, csr_nodes_ref, parent_eid_ref,
     def _init_temp():
         temp_ref[...] = temp_in_ref[...]
 
-    arena_rows = temp_ref.shape[0]
+    arena_rows = temp_ref.shape[1]
     rows = qoff + bucket                            # always < arena_rows
     if mxu:
-        it = jax.lax.broadcasted_iota(jnp.int32, (TILE, arena_rows), 1)
-        rows_oh = ((it == rows[:, None]) &
-                   vhit[:, None]).astype(jnp.float32)
-        st = jax.lax.broadcasted_iota(jnp.int32, (TILE, slots), 1)
-        slot_oh = (st == slot[:, None]).astype(jnp.float32)
-        contrib = jax.lax.dot_general(                     # (A, S) counts
-            rows_oh, slot_oh, (((0,), (0,)), ((), ())), precision=_HIGHEST)
+        it = jax.lax.broadcasted_iota(jnp.int32, (arena_rows, TILE), 0)
+        rows_oh = ((it == rows) & vhit).astype(jnp.float32)
+        st = jax.lax.broadcasted_iota(jnp.int32, (slots, TILE), 0)
+        slot_oh = (st == slot).astype(jnp.float32)
+        contrib = jax.lax.dot_general(                     # (S, A) counts
+            slot_oh, rows_oh, (((1,), (1,)), ((), ())), precision=_HIGHEST)
         temp_ref[...] += contrib.astype(temp_ref.dtype)
     else:
         t = temp_ref[...]
-        temp_ref[...] = t.at[jnp.clip(rows, 0, arena_rows - 1),
-                             slot].add(vhit.astype(t.dtype))
+        temp_ref[...] = t.at[slot[0], jnp.clip(rows[0], 0, arena_rows - 1)
+                             ].add(vhit[0].astype(t.dtype))
 
-    # CSR location window; misses route to the empty sentinel row R
-    r_sent = csr_lc_ref.shape[0] - 1
-    eid = jnp.where(vhit, head_ref[...], r_sent)
-    lc = _gather_rows(csr_lc_ref[...], eid, vhit, mxu)
-    lo = lc[:, 0].astype(jnp.int32)
-    count = lc[:, 1].astype(jnp.int32)
-    csr_nodes = csr_nodes_ref[...]
-    node_cols = []
-    for k in range(max_locs):
-        idx = lo + k
+    # CSR location window; misses gather zero rows (start 0, count 0)
+    lc = _gather_rows(csr_lc_ref, jnp.where(vhit, head_ref[...], 0), vhit,
+                      mxu)
+    lo = _as_int(lc[0:1])
+    count = _as_int(lc[1:2])
+
+    def location(k, carry):
         validk = (k < count) & vhit
-        nrow = _gather_rows(csr_nodes, jnp.clip(idx, 0,
-                                                csr_nodes.shape[0] - 1),
-                            validk, mxu)
-        node_cols.append(jnp.where(validk, nrow[:, 0].astype(jnp.int32),
-                                   NULL))
-    loc_ref[...] = jnp.stack(node_cols, axis=1)
-    if locs_only:
-        return
+        nrow = _gather_rows(csr_nodes_ref, lo + k, validk, mxu)
+        node_k = jnp.where(validk, _as_int(nrow), NULL)
+        loc_ref[k] = node_k
+        if not locs_only:
+            src = jnp.maximum(node_k, 0)
+            miss = node_k == NULL
+            ups = _up_walk(src, parent_eid_ref, n, mxu)
+            downs = _down_walk(src, child_lc_ref, child_index_ref,
+                               parent_eid_ref, n, mxu)
+            for j in range(n):
+                up_ref[k, j:j + 1, :] = jnp.where(miss, NULL, ups[j])
+                down_ref[k, j:j + 1, :] = jnp.where(miss, NULL, downs[j])
+        return carry
 
-    pe_tab = parent_eid_ref[...]
-    child_lc = child_lc_ref[...]
-    child_index = child_index_ref[...]
-    up_cols, down_cols = [], []
-    for k in range(max_locs):
-        node_k = node_cols[k]
-        src = jnp.maximum(node_k, 0)
-        upk = _up_walk(src, pe_tab, n, mxu)
-        up_cols.append(jnp.where(node_k[:, None] == NULL, NULL, upk))
-        downk = _down_walk(src, child_lc, child_index, pe_tab, n, mxu)
-        down_cols.append(jnp.where(node_k[:, None] == NULL, NULL, downk))
-    up_ref[...] = jnp.concatenate(up_cols, axis=1)
-    down_ref[...] = jnp.concatenate(down_cols, axis=1)
+    jax.lax.fori_loop(0, max_locs, location, 0)
 
 
 def _split_out_refs(refs, locs_only):
@@ -187,21 +195,18 @@ def _split_out_refs(refs, locs_only):
     return refs
 
 
-def _fused_kernel(h_ref, off_ref, mask_ref, valid_ref, fp_tab_ref,
-                  head_tab_ref, temp_in_ref, csr_lc_ref, csr_nodes_ref,
-                  parent_eid_ref, child_lc_ref, child_index_ref,
-                  *out_refs, slots, row_tile, num_tiles, max_locs, n, mxu,
-                  locs_only):
+def _fused_kernel(h_ref, off_ref, mask_ref, valid_ref, tab_ref, temp_in_ref,
+                  csr_lc_ref, csr_nodes_ref, parent_eid_ref, child_lc_ref,
+                  child_index_ref, *out_refs, slots, row_tile, num_tiles,
+                  max_locs, n, mxu, locs_only):
     """Pre-routed fused kernel: probe every arena tile, run the context
     tail once the last tile's priority merge has settled."""
     (hit_ref, head_ref, bucket_ref, slot_ref, prio_ref, loc_ref, up_ref,
      down_ref, temp_ref) = _split_out_refs(out_refs, locs_only)
     qi = pl.program_id(0)
     ti = pl.program_id(1)
-    h = h_ref[...].astype(jnp.uint32)
-    qoff = off_ref[...].astype(jnp.int32)
-    qmask = mask_ref[...].astype(jnp.uint32)
-    _arena_probe(h, qoff, qmask, ti, fp_tab_ref, head_tab_ref, hit_ref,
+    qoff = off_ref[...]
+    _arena_probe(h_ref[...], qoff, mask_ref[...], ti, tab_ref, hit_ref,
                  head_ref, bucket_ref, slot_ref, prio_ref, slots=slots,
                  row_tile=row_tile)
 
@@ -215,163 +220,56 @@ def _fused_kernel(h_ref, off_ref, mask_ref, valid_ref, fp_tab_ref,
                       locs_only=locs_only)
 
 
-def _fused_kernel_sp(off_ref, nb_ref, tid_ref, h_ref, valid_ref,
-                     fp_tab_ref, head_tab_ref, temp_in_ref, csr_lc_ref,
-                     csr_nodes_ref, parent_eid_ref, child_lc_ref,
-                     child_index_ref, *out_refs, slots, row_tile,
-                     num_tiles, num_trees, max_locs, n, mxu, locs_only):
-    """Tree-routed fused kernel: ``bucket_offsets``/``tree_nb`` ride as
-    SMEM scalar-prefetch operands (PR 5's routing tables) and the
-    per-lane (offset, mask) gather happens in-kernel — then the shared
-    probe + context tail."""
-    (hit_ref, head_ref, bucket_ref, slot_ref, prio_ref, loc_ref, up_ref,
-     down_ref, temp_ref) = _split_out_refs(out_refs, locs_only)
-    qi = pl.program_id(0)
-    ti = pl.program_id(1)
-    h = h_ref[...].astype(jnp.uint32)
-    tid = tid_ref[...].astype(jnp.int32)                    # clamped valid
-    offs = off_ref[...].astype(jnp.int32)                   # (T + 1,) SMEM
-    nbs = nb_ref[...].astype(jnp.int32)                     # (T,) SMEM
-    t_iota = jax.lax.broadcasted_iota(jnp.int32, (TILE, num_trees), 1)
-    sel = t_iota == tid[:, None]
-    qoff = jnp.sum(jnp.where(sel, offs[None, :num_trees], 0), axis=1)
-    qnb = jnp.sum(jnp.where(sel, nbs[None, :], 0), axis=1)
-    qmask = (qnb - 1).astype(jnp.uint32)
-    _arena_probe(h, qoff, qmask, ti, fp_tab_ref, head_tab_ref, hit_ref,
-                 head_ref, bucket_ref, slot_ref, prio_ref, slots=slots,
-                 row_tile=row_tile)
-
-    @pl.when(ti == num_tiles - 1)
-    def _tail():
-        _context_tail(qoff, valid_ref[...] > 0, csr_lc_ref, csr_nodes_ref,
-                      parent_eid_ref, child_lc_ref, child_index_ref,
-                      hit_ref, head_ref, bucket_ref, slot_ref, loc_ref,
-                      up_ref, down_ref, temp_in_ref, temp_ref, qi,
-                      slots=slots, max_locs=max_locs, n=n, mxu=mxu,
-                      locs_only=locs_only)
-
-
-def _out_shapes(b, arena_rows, slots, temp_dtype, max_locs, n, locs_only):
-    shapes = [jax.ShapeDtypeStruct((b,), jnp.int32) for _ in range(5)]
-    shapes.append(jax.ShapeDtypeStruct((b, max_locs), jnp.int32))
-    if not locs_only:
-        shapes.append(jax.ShapeDtypeStruct((b, max_locs * n), jnp.int32))
-        shapes.append(jax.ShapeDtypeStruct((b, max_locs * n), jnp.int32))
-    shapes.append(jax.ShapeDtypeStruct((arena_rows, slots), temp_dtype))
-    return shapes
-
-
-def _out_specs(qspec, wide, tempspec, max_locs, n, locs_only):
-    specs = [qspec] * 5 + [wide(max_locs)]
-    if not locs_only:
-        specs += [wide(max_locs * n), wide(max_locs * n)]
-    return specs + [tempspec]
-
-
-def fused_retrieve_pallas(h, row_offsets, masks, valid, fp_table_f32,
-                          head_table_f32, temperature, csr_lc, csr_nodes,
-                          parent_eid, child_lc, child_index,
-                          max_locs: int = 4, n: int = 3,
-                          interpret: bool = True, row_tile: int = 0,
-                          mxu: bool = False, locs_only: bool = False):
-    """Pre-routed fused retrieval.  h/row_offsets/masks/valid: (B,) with
-    B % TILE == 0; fp/head tables (A, S) f32 (A a multiple of row_tile
-    when tiling); temperature (A, S); context tables packed by
-    ``ops.stage_context_tables``.  Returns (hit, head, bucket, slot, prio,
-    locations[, up, down], temperature) — the wrapper drops the probe
-    internals."""
-    rows_total, slots = fp_table_f32.shape
-    b = h.shape[0]
+def fused_retrieve_pallas(h, row_offsets, masks, valid, table_f32,
+                          temperature, csr_lc, csr_nodes, parent_eid,
+                          child_lc, child_index, max_locs: int = 4,
+                          n: int = 3, interpret: bool = True,
+                          row_tile: int = 0, mxu: bool = False,
+                          locs_only: bool = False, vmem_limit: int = 0):
+    """Pre-routed fused retrieval.  h/row_offsets/masks/valid: ``(1, B)``
+    with B % TILE == 0; ``table_f32`` ``(2S, A)`` (A a multiple of TILE and
+    of row_tile when tiling); temperature ``(S, A)``; context tables packed
+    by ``ops.stage_context_tables``.  Returns (hit, head, bucket, slot,
+    prio, locations ``(max_locs, 1, B)``[, up, down ``(max_locs, n, B)``],
+    temperature) — the wrapper drops the probe internals."""
+    two_s, rows_total = table_f32.shape
+    slots = two_s // 2
+    b = h.shape[1]
     rt = rows_total if row_tile <= 0 else row_tile
-    assert rows_total % rt == 0, \
-        "pad the arena to a multiple of row_tile before calling"
+    assert rows_total % rt == 0 and rt % TILE == 0, \
+        "pad the arena to a multiple of row_tile (and TILE) before calling"
     nt = rows_total // rt
     grid = (b // TILE, nt)                     # arena axis innermost
-    qspec = pl.BlockSpec((TILE,), lambda qi, ti: (qi,))
-    tabspec = pl.BlockSpec((rt, slots), lambda qi, ti: (ti, 0))
+    qspec = pl.BlockSpec((1, TILE), lambda qi, ti: (0, qi))
+    tabspec = pl.BlockSpec((two_s, rt), lambda qi, ti: (0, ti))
 
     def wide(w):
-        return pl.BlockSpec((TILE, w), lambda qi, ti: (qi, 0))
+        return pl.BlockSpec((max_locs, w, TILE), lambda qi, ti: (0, 0, qi))
 
     def const(arr):
-        return pl.BlockSpec(arr.shape, lambda qi, ti: (0,) * arr.ndim)
+        return pl.BlockSpec(arr.shape,
+                            lambda qi, ti: (0,) * arr.ndim)
 
-    outs = pl.pallas_call(
+    out_shape = [jax.ShapeDtypeStruct((1, b), jnp.int32) for _ in range(5)]
+    out_specs = [qspec] * 5
+    for w in [1] if locs_only else [1, n, n]:
+        out_shape.append(jax.ShapeDtypeStruct((max_locs, w, b), jnp.int32))
+        out_specs.append(wide(w))
+    out_shape.append(jax.ShapeDtypeStruct(temperature.shape,
+                                          temperature.dtype))
+    out_specs.append(const(temperature))
+    return pl.pallas_call(
         functools.partial(_fused_kernel, slots=slots, row_tile=rt,
                           num_tiles=nt, max_locs=max_locs, n=n, mxu=mxu,
                           locs_only=locs_only),
         grid=grid,
-        in_specs=[qspec, qspec, qspec, qspec, tabspec, tabspec,
-                  const(temperature), const(csr_lc), const(csr_nodes),
-                  const(parent_eid), const(child_lc), const(child_index)],
-        out_specs=_out_specs(qspec, wide, const(temperature), max_locs, n,
-                             locs_only),
-        out_shape=_out_shapes(b, rows_total, slots, temperature.dtype,
-                              max_locs, n, locs_only),
+        in_specs=[qspec, qspec, qspec, qspec, tabspec, const(temperature),
+                  const(csr_lc), const(csr_nodes), const(parent_eid),
+                  const(child_lc), const(child_index)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=compiler_params(vmem_limit),
         interpret=interpret,
-    )(h, row_offsets, masks, valid, fp_table_f32, head_table_f32,
-      temperature, csr_lc, csr_nodes, parent_eid, child_lc, child_index)
-    return outs
-
-
-def fused_retrieve_ragged_pallas(h, tree_ids, valid, bucket_offsets,
-                                 tree_nb, fp_table_f32, head_table_f32,
-                                 temperature, csr_lc, csr_nodes,
-                                 parent_eid, child_lc, child_index,
-                                 max_locs: int = 4, n: int = 3,
-                                 interpret: bool = True, row_tile: int = 0,
-                                 mxu: bool = False,
-                                 locs_only: bool = False):
-    """Tree-routed fused retrieval with SMEM scalar-prefetched routing
-    tables (tree_ids pre-clamped to [0, T-1], ``valid`` carrying the
-    in-range mask).  Falls back to the pre-routed kernel when the jax
-    build exposes no TPU grid-spec module."""
-    if pltpu is None:                      # pragma: no cover - build-dep
-        off = bucket_offsets[tree_ids]
-        mask = (tree_nb[tree_ids] - 1).astype(jnp.uint32)
-        return fused_retrieve_pallas(
-            h, off, mask, valid, fp_table_f32, head_table_f32, temperature,
-            csr_lc, csr_nodes, parent_eid, child_lc, child_index,
-            max_locs=max_locs, n=n, interpret=interpret, row_tile=row_tile,
-            mxu=mxu, locs_only=locs_only)
-    rows_total, slots = fp_table_f32.shape
-    b = h.shape[0]
-    rt = rows_total if row_tile <= 0 else row_tile
-    assert rows_total % rt == 0, \
-        "pad the arena to a multiple of row_tile before calling"
-    nt = rows_total // rt
-    num_trees = tree_nb.shape[0]
-    grid = (b // TILE, nt)                     # arena axis innermost
-    # index maps receive the scalar-prefetch refs after the grid indices
-    qspec = pl.BlockSpec((TILE,), lambda qi, ti, off, nb: (qi,))
-    tabspec = pl.BlockSpec((rt, slots), lambda qi, ti, off, nb: (ti, 0))
-
-    def wide(w):
-        return pl.BlockSpec((TILE, w), lambda qi, ti, off, nb: (qi, 0))
-
-    def const(arr):
-        return pl.BlockSpec(arr.shape,
-                            lambda qi, ti, off, nb: (0,) * arr.ndim)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[qspec, qspec, qspec, tabspec, tabspec,
-                  const(temperature), const(csr_lc), const(csr_nodes),
-                  const(parent_eid), const(child_lc), const(child_index)],
-        out_specs=_out_specs(qspec, wide, const(temperature), max_locs, n,
-                             locs_only),
-    )
-    outs = pl.pallas_call(
-        functools.partial(_fused_kernel_sp, slots=slots, row_tile=rt,
-                          num_tiles=nt, num_trees=num_trees,
-                          max_locs=max_locs, n=n, mxu=mxu,
-                          locs_only=locs_only),
-        grid_spec=grid_spec,
-        out_shape=_out_shapes(b, rows_total, slots, temperature.dtype,
-                              max_locs, n, locs_only),
-        interpret=interpret,
-    )(bucket_offsets.astype(jnp.int32), tree_nb.astype(jnp.int32),
-      tree_ids, h, valid, fp_table_f32, head_table_f32, temperature,
-      csr_lc, csr_nodes, parent_eid, child_lc, child_index)
-    return outs
+        name="fused_retrieve",
+    )(h, row_offsets, masks, valid, table_f32, temperature, csr_lc,
+      csr_nodes, parent_eid, child_lc, child_index)

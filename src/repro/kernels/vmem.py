@@ -1,105 +1,80 @@
-"""Shared VMEM budget derivation for the retrieval kernels.
+"""Shared VMEM budget for the retrieval kernels.
 
 The arena kernels (``cuckoo_lookup`` and ``fused_retrieve``) stream arena
-tiles through VMEM and must cap the rows-per-tile so the tile working set
-fits on chip.  Historically the cap came from a hand-written closed form
-baked into ``LOOKUP_VMEM_BUDGET``; this module replaces that constant with a
-derivation that *measures* the per-row cost from the compiled executable
-(``memory_analysis()``, where the backend exposes it) and keeps the closed
-form as the documented fallback.
+tiles through VMEM and must cap the rows per tile so that the tile working
+set fits on chip.  The capacity comes from the attached TPU (Pallas' TPU
+hardware table, ``pltpu.get_tpu_info``, keyed by ``device_kind``; a kind
+the table does not know is an error).  Only a TPU has VMEM: off the chip
+the kernels run in interpret mode and nothing here is consulted.
 
-Closed form (per arena row streamed through a probe tile, f32 staging):
+Per arena row streamed through a probe tile (closed form, f32 staging):
 
-    fp tile + head tile      2 * slots * 4 bytes
-    concat(fp, head)             2 * slots * 4 bytes
-    two one-hot operands     2 * TILE  * 4 bytes   (query-tile x rows)
-    -------------------------------------------------
+    (2S, rows) table tile, double-buffered   2 * 2 * slots * 4 bytes
+    two (rows, TILE) one-hot operands        2 * TILE * 4 bytes
+    -------------------------------------------------------------
     per_row = 4 * (4 * slots + 2 * TILE)
 
-Budget = half of a 16 MiB VMEM core so the Pallas pipeline can double-buffer
-the streamed tiles (two tile generations resident at once).
+Budget = half of the core's VMEM for the streamed tiles; the other half is
+headroom for the residents and Mosaic's own scratch.  The kernels are
+launched with a scoped VMEM limit of the whole capacity, so the compiler
+holds them to the physical memory the budget was derived from.
 
-Measurement: lower the single-block arena kernel at two row counts and take
-the difference quotient of ``temp_size_in_bytes`` — the slope is the true
-bytes/row after XLA fusion (on this container's CPU backend it comes out at
-roughly half the closed form, because the concat and one-hots fuse).  The
-measured slope only ever *raises* the row cap, never past the closed-form
-floor of correctness: both derivations feed the same ``max_rows_for_vmem``
-rounding to TILE multiples.
-
-Derivations are cached and lazy — nothing compiles at import time.
+No measurement refines the closed form: ``memory_analysis()`` of a
+program that calls a kernel counts the program's HBM temporaries on the
+TPU (and interpret-mode buffers on the CPU), never the kernel's VMEM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple, Optional
+from typing import NamedTuple
+
+import jax
 
 from ..obs import get_registry
 
-#: Per-core VMEM capacity assumed for budgeting (TPU v4/v5e class).
-DEFAULT_VMEM_BYTES = 16 * 1024 * 1024
-
-#: Fraction of VMEM the streamed tiles may occupy; the other half is
-#: headroom for the Pallas pipeline's double-buffering and residents.
+#: Fraction of VMEM the streamed tiles may occupy.
 BUDGET_FRACTION = 0.5
 
 
 class VmemBudget(NamedTuple):
     budget_bytes: int     # bytes available to the streamed tile working set
     per_row_bytes: int    # bytes of VMEM one arena row costs inside a tile
-    source: str           # "measured" | "closed_form"
+    limit_bytes: int      # scoped VMEM limit the kernels are launched with
+
+
+def vmem_capacity_bytes() -> int:
+    """Per-core VMEM of the attached TPU.  Raises on any other backend
+    (nothing off the chip has VMEM) and on a TPU kind the hardware table
+    does not list."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(f"no VMEM on a {dev.platform} device: the "
+                           "retrieval kernels run interpreted there")
+    from jax.experimental.pallas import tpu as pltpu
+    return int(pltpu.get_tpu_info().vmem_capacity_bytes)
 
 
 def closed_form_row_bytes(slots: int, tile: int) -> int:
-    """The documented closed form: staged f32 tables + matmul operands."""
+    """Staged f32 table tile (double-buffered) + the two one-hot operands."""
     return 4 * (4 * slots + 2 * tile)
 
 
-def measured_row_bytes(lower_fn: Callable[[int], object],
-                       rows_lo: int = 256,
-                       rows_hi: int = 512) -> Optional[int]:
-    """Measure bytes/row from compiled memory stats, or None if the backend
-    does not expose ``memory_analysis()``.
-
-    ``lower_fn(rows)`` must return a ``jax.stages.Lowered`` for the kernel
-    at the given arena row count with everything else held fixed; the
-    difference quotient of temp (scratch) bytes is the per-row slope.
-    """
-    try:
-        lo = lower_fn(rows_lo).compile().memory_analysis()
-        hi = lower_fn(rows_hi).compile().memory_analysis()
-        if lo is None or hi is None:
-            return None
-        slope = (int(hi.temp_size_in_bytes) - int(lo.temp_size_in_bytes)) \
-            // (rows_hi - rows_lo)
-    except Exception:          # backend without memory_analysis, or lowering
-        return None            # quirk — the closed form is always available
-    return slope if slope > 0 else None
+def budget_for(capacity_bytes: int, slots: int = 4,
+               tile: int = 128) -> VmemBudget:
+    """The tile budget on a core with ``capacity_bytes`` of VMEM."""
+    return VmemBudget(budget_bytes=int(capacity_bytes * BUDGET_FRACTION),
+                      per_row_bytes=closed_form_row_bytes(slots, tile),
+                      limit_bytes=int(capacity_bytes))
 
 
 @functools.lru_cache(maxsize=None)
-def derive_budget(slots: int = 4, tile: int = 128,
-                  measure: Optional[Callable[[int], object]] = None,
-                  vmem_bytes: int = DEFAULT_VMEM_BYTES) -> VmemBudget:
-    """Derive the tile budget for an arena kernel.
-
-    ``measure`` is an optional hashable lower_fn (pass a module-level
-    function, not a lambda, so the cache key is stable); when provided and
-    the backend cooperates, the measured slope wins, else the closed form.
-    """
-    budget = int(vmem_bytes * BUDGET_FRACTION)
-    per_row = closed_form_row_bytes(slots, tile)
-    source = "closed_form"
-    if measure is not None:
-        got = measured_row_bytes(measure)
-        if got is not None:
-            per_row, source = got, "measured"
+def device_budget(slots: int = 4, tile: int = 128) -> VmemBudget:
+    """The tile budget of the attached TPU (cached per process)."""
+    b = budget_for(vmem_capacity_bytes(), slots, tile)
     get_registry().gauge(
         "kernel.vmem_budget_bytes",
-        "VMEM bytes budgeted for streamed arena tiles").set(
-            budget, source=source)
-    return VmemBudget(budget_bytes=budget, per_row_bytes=per_row,
-                      source=source)
+        "VMEM bytes budgeted for streamed arena tiles").set(b.budget_bytes)
+    return b
 
 
 def max_rows_for_vmem(budget: VmemBudget, tile: int = 128,
@@ -110,3 +85,9 @@ def max_rows_for_vmem(budget: VmemBudget, tile: int = 128,
     avail = max(budget.budget_bytes - resident_bytes, 0)
     rows = avail // budget.per_row_bytes
     return max(tile, rows // tile * tile)
+
+
+def row_tile_for(arena_rows: int, max_rows: int) -> int:
+    """0 (the whole arena as one block) when it fits ``max_rows``; else
+    ``max_rows`` arena rows per grid step."""
+    return 0 if arena_rows <= max_rows else max_rows

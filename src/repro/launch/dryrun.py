@@ -61,7 +61,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     params_abs = specs.params_specs(cfg)
     params_sh = sh.params_shardings(mesh, params_abs)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_abs = jax.eval_shape(adamw_init, params_abs)
             opt_sh = sh.opt_shardings(mesh, opt_abs, params_sh)

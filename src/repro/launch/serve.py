@@ -15,6 +15,7 @@ from ..configs import get_arch
 from ..data import HashTokenizer, hospital_corpus
 from ..models import init_params
 from ..serving import RAGPipeline, ServeEngine
+from .compile_cache import configure_compile_cache
 
 
 def main() -> None:
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--cache", type=int, default=256)
     ap.add_argument("--device-lookup", action="store_true")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -16,7 +16,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map as _shard_map
 from ..configs.base import ModelConfig
 from .layers import Params, dense_init, swiglu
 
@@ -214,7 +213,7 @@ def moe_apply_sharded(cfg: ModelConfig, p: Params, x: jax.Array, mesh,
         y = jax.lax.psum(y.astype(jnp.float32), tp_axis)
         return y.astype(x.dtype).reshape(b_l, l_l, d)
 
-    fn = _shard_map(inner, mesh=mesh, in_specs=tuple(in_specs),
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=P(dp_axes, None, None))
     return fn(*args)
 
@@ -307,7 +306,7 @@ def _moe_small_batch(cfg: ModelConfig, p: Params, x: jax.Array, mesh,
             y_full, dp_idx * t_loc, t_loc, axis=0)
         return mine.astype(x.dtype).reshape(b_l, l_l, d)
 
-    fn = _shard_map(inner, mesh=mesh, in_specs=tuple(in_specs),
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=P(dp_axes, None, None))
     return fn(*args)
 

@@ -17,7 +17,8 @@ _TP_AXIS: str = "model"
 def set_mesh(mesh, dp_axes: Tuple[str, ...] = ("data",),
              tp_axis: str = "model") -> None:
     global _MESH, _DP_AXES, _TP_AXIS
-    _MESH = mesh
+    from ..core.distributed import auto_axes
+    _MESH = auto_axes(mesh)
     _DP_AXES = tuple(dp_axes)
     _TP_AXIS = tp_axis
 

@@ -24,8 +24,7 @@ was diagnosed by hand; this module makes the diagnosis permanent:
   hot-path recompile raises :class:`HotPathRecompileError` instead of
   silently eating the tail.
 
-A process-wide ``jax.monitoring`` listener (via
-``compat.register_compile_listener``) additionally counts *every*
+A process-wide ``jax.monitoring`` listener additionally counts *every*
 backend compile in the process (``xla.compiles`` /
 ``xla.compile_s``) — warmup, maintenance warm-compiles, everything —
 giving snapshots the denominator against which zero hot-path
@@ -191,10 +190,12 @@ class RecompileSentinel:
 # one process-wide jax.monitoring listener, shared by every sentinel;
 # jax offers no targeted unregister, so this never unhooks
 _listener_lock = threading.Lock()
-_listener_installed: Optional[bool] = None
+_listener_installed = False
 
 
-def _on_backend_compile(event: str, duration: float) -> None:
+def _on_backend_compile(event: str, duration: float, **kw) -> None:
+    if "backend_compile" not in event:
+        return
     reg = get_registry()
     reg.counter("xla.compiles",
                 "process-wide backend compilations (any cause)").inc()
@@ -205,8 +206,9 @@ def _on_backend_compile(event: str, duration: float) -> None:
 def _ensure_process_listener(registry: MetricsRegistry) -> bool:
     global _listener_installed
     with _listener_lock:
-        if _listener_installed is None:
-            from ..compat import register_compile_listener
-            _listener_installed = register_compile_listener(
+        if not _listener_installed:
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(
                 _on_backend_compile)
+            _listener_installed = True
         return _listener_installed

@@ -110,20 +110,12 @@ class RetrievalSession:
             from ..core.distributed import _sharded_retrieve_jit
             self._watched_step = _sharded_retrieve_jit
         elif self.fused:
-            # the fused entry picks row tiling / VMEM fit outside any
-            # trace, so the jit boundary is the kernel ops wrapper — keep
-            # a jitted unfused step around for the VMEM-overflow fallback
+            # the fused entry picks its launch plan outside any trace, so
+            # the jit boundary is the kernel ops wrapper
             from ..kernels.fused_retrieve import (fused_retrieve_state_auto,
                                                   ops as _fops)
-            unfused = jax.jit(functools.partial(
-                retrieve_device, max_locs=max_locs, n=n))
-
-            def step(state, hh, tid):
-                out = fused_retrieve_state_auto(state, hh, tid,
-                                                max_locs=max_locs, n=n)
-                return out if out is not None else unfused(state, hh, tid)
-
-            self._step = step
+            self._step = functools.partial(fused_retrieve_state_auto,
+                                           max_locs=max_locs, n=n)
             self._watched_step = _fops.fused_retrieve_ragged
         else:
             self._step = jax.jit(functools.partial(

@@ -188,13 +188,17 @@ def test_pallas_bank_kernel_tree_tiled_matches_single_block():
 
 
 def test_bank_auto_tiling_threshold():
-    """Auto selection keeps small banks single-block and tiles big ones;
-    both answer identically to the jnp reference."""
-    from repro.kernels.cuckoo_lookup.ops import (SINGLE_BLOCK_MAX_ROWS,
-                                                 _pick_tree_tile)
-    assert _pick_tree_tile(4, 64) == 0
-    assert _pick_tree_tile(SINGLE_BLOCK_MAX_ROWS, 16) >= 1
-    assert _pick_tree_tile(64, 2 * SINGLE_BLOCK_MAX_ROWS) == 1
+    """Auto selection keeps small banks single-block and tiles big ones,
+    on a core of either VMEM size; interpret mode never tiles."""
+    from repro.kernels import vmem
+    from repro.kernels.cuckoo_lookup.ops import pick_row_tile
+    for capacity in (16 << 20, 128 << 20):
+        cap = vmem.max_rows_for_vmem(vmem.budget_for(capacity), 128)
+        assert cap % 128 == 0 and cap >= 128
+        assert vmem.row_tile_for(4 * 64, cap) == 0
+        assert vmem.row_tile_for(cap, cap) == 0
+        assert vmem.row_tile_for(2 * cap, cap) == cap
+    assert pick_row_tile(1 << 20, interpret=True) == 0
 
 
 def test_absorb_temperature_replaces_handrolled_writeback():

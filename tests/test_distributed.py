@@ -21,6 +21,7 @@ def _run(code: str, devices: int = 8) -> str:
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"       # host devices only, never the chip
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=520)
@@ -522,6 +523,7 @@ def test_small_mesh_train_step_sharded():
     from repro.models import init_params, runtime
     from repro.training import AdamWConfig, adamw_init, make_train_step
     from repro.launch import sharding as sh
+    from repro.launch.mesh import make_test_mesh
 
     # capacity_factor high enough that no tokens drop: per-shard capacity
     # (sharded path) and global capacity (local path) then agree exactly
@@ -536,7 +538,7 @@ def test_small_mesh_train_step_sharded():
 
     p1, _, m1 = make_train_step(cfg, ocfg)(params, adamw_init(params), batch)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh(2, 4)
     runtime.set_mesh(mesh, ("data",))
     params_sh = sh.params_shardings(mesh, jax.eval_shape(lambda: params))
     opt_abs = jax.eval_shape(adamw_init, params)
@@ -545,7 +547,7 @@ def test_small_mesh_train_step_sharded():
         mesh, P("data", *(None,) * (t.ndim - 1))), batch)
     step = make_train_step(cfg, ocfg, param_shardings=params_sh,
                            data_axes=("data",))
-    with mesh:
+    with jax.set_mesh(mesh):
         fn = jax.jit(step, in_shardings=(params_sh, opt_sh, bs),
                      out_shardings=(params_sh, opt_sh, None))
         p2, _, m2 = fn(jax.device_put(params, params_sh),
@@ -602,7 +604,7 @@ def test_moe_small_batch_token_routing():
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, 64), jnp.float32)
     y_local = M._moe_apply_local(cfg, p, x)
     mesh = jax.make_mesh((2, 4), ("data", "model"))
-    with mesh:
+    with jax.set_mesh(mesh):
         y_small = M._moe_small_batch(cfg, p, x, mesh, ("data",), "model", 2)
     np.testing.assert_allclose(np.asarray(y_local), np.asarray(y_small),
                                atol=2e-5, rtol=2e-5)
@@ -617,19 +619,20 @@ def test_mini_dryrun_multi_pod_mesh():
     import jax, jax.numpy as jnp
     from repro.configs import get_arch, SHAPES
     from repro.launch import sharding as sh, specs
+    from repro.launch.mesh import make_test_mesh
     from repro.models import lm, runtime
     from repro.training.grad import make_train_step
     from repro.training.optimizer import AdamWConfig, adamw_init
     import dataclasses
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_test_mesh(2, 2, pod=2)
     runtime.set_mesh(mesh, ("pod", "data"))
     cfg = get_arch("qwen2-0.5b").smoke()
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64,
                                 global_batch=8)
     params_abs = specs.params_specs(cfg)
     params_sh = sh.params_shardings(mesh, params_abs)
-    with mesh:
+    with jax.set_mesh(mesh):
         opt_abs = jax.eval_shape(adamw_init, params_abs)
         opt_sh = sh.opt_shardings(mesh, opt_abs, params_sh)
         batch_abs = specs.train_batch_specs(cfg, shape)

@@ -19,7 +19,7 @@ from repro.kernels import vmem
 from repro.kernels.fused_retrieve import (fused_retrieve_arena,
                                           fused_retrieve_ref,
                                           fused_retrieve_state_auto,
-                                          fused_vmem_budget)
+                                          launch_plan)
 from repro.obs import get_registry
 
 RNG = np.random.default_rng(7)
@@ -210,27 +210,32 @@ def test_ref_matches_oracle():
 # --------------------------------------------------------- VMEM derivation
 
 def test_vmem_budget_derivation():
-    b = fused_vmem_budget()
-    assert b.source in ("measured", "closed_form")
-    assert b.per_row_bytes > 0
-    assert b.budget_bytes == vmem.DEFAULT_VMEM_BYTES * vmem.BUDGET_FRACTION
-    # the closed form upper-bounds the true footprint: a measured
-    # per-row cost must never exceed it
-    assert b.per_row_bytes <= vmem.closed_form_row_bytes(4, 128)
+    """The tile budget of a core is half its VMEM at the closed-form
+    per-row cost, launched under a scoped limit of that capacity."""
+    for capacity in (16 << 20, 128 << 20):
+        b = vmem.budget_for(capacity)
+        assert b.budget_bytes == capacity * vmem.BUDGET_FRACTION
+        assert b.per_row_bytes == vmem.closed_form_row_bytes(4, 128) > 0
+        assert b.limit_bytes == capacity
 
 
 def test_vmem_budget_measured_on_cpu():
-    """The CPU backend exposes memory_analysis(), so the derivation here
-    must come from the compiled measurement, not the fallback."""
-    assert fused_vmem_budget().source == "measured"
+    """The CPU has no VMEM: the capacity lookup raises rather than assume
+    a size, and the fused launch plan runs interpreted, untiled."""
+    with pytest.raises(RuntimeError, match="no VMEM"):
+        vmem.vmem_capacity_bytes()
+    assert launch_plan(4608, 4, 16384, 16384, 10523, 9923) == \
+        (True, False, 0, 0)
 
 
 def test_max_rows_monotone():
-    b = fused_vmem_budget()
+    b = vmem.budget_for(16 << 20)
     free = vmem.max_rows_for_vmem(b, 128, 0)
     assert free % 128 == 0 and free >= 128
     # resident context blocks shrink the probe-tile allowance
     assert vmem.max_rows_for_vmem(b, 128, b.budget_bytes // 2) <= free
+    # a larger core never holds fewer rows
+    assert vmem.max_rows_for_vmem(vmem.budget_for(128 << 20), 128) >= free
 
 
 # ----------------------------------------------------------- observability
@@ -246,10 +251,6 @@ def test_fused_obs_surface():
     snap = reg.snapshot()
     assert snap["counters"]["serve.fused_batches"] == before + 1
     assert snap["gauges"]["kernel.tile_rows"] == 0      # resident on CPU
-    b = fused_vmem_budget()
-    snap = reg.snapshot()["gauges"]
-    assert snap[f"kernel.vmem_budget_bytes{{source={b.source}}}"] == \
-        b.budget_bytes
 
 
 def test_session_fused_flip_forgiven():
