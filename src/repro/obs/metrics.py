@@ -19,8 +19,9 @@ Every mutation takes the registry lock — the fix for the torn
 before the lock, so instrumented hot paths pay one attribute load and
 one branch.  ``snapshot()`` returns a plain-Python dict (every leaf
 survives ``json.dumps`` untouched) and ``to_prometheus()`` renders the
-v0 text exposition format; ``PeriodicLogger`` ships snapshots to a sink
-on a timer for long-running servers.
+v0 text exposition format.  Beside the metrics the registry keeps the
+finished trace spans (``spans``, a :class:`SpanLog`), so they outlive
+the objects that produced them.
 
 One process-wide default registry (``get_registry``) keeps
 instrumentation call sites decoupled from construction; tests that need
@@ -28,11 +29,11 @@ isolation construct a private ``MetricsRegistry`` and pass it down.
 """
 from __future__ import annotations
 
-import json
 import math
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from collections import deque
+from typing import Dict, List, Tuple
 
 # log2 histogram geometry: bucket i spans [2^(B0+i), 2^(B0+i+1)) seconds
 # (or whatever unit the caller observes); 2^-20 s ≈ 1 µs up to 2^19 s.
@@ -205,6 +206,37 @@ class _HistTimer:
         self._hist.observe(time.perf_counter() - self._t0)
 
 
+class SpanLog:
+    """Finished spans, kept per span name in rings of ``capacity``: a
+    flood of one name never evicts another's.  Records are opaque here
+    (``repro.obs.tracing`` writes and reads them)."""
+
+    def __init__(self, capacity: int = 16384):
+        self.capacity = capacity
+        self._rings: Dict[str, deque] = {}
+        self._lock = threading.Lock()
+
+    def append(self, name: str, record) -> None:
+        with self._lock:
+            ring = self._rings.get(name)
+            if ring is None:
+                ring = self._rings[name] = deque(maxlen=self.capacity)
+            ring.append(record)
+
+    def newest(self, name: str, n: int) -> List:
+        """The newest ``n`` records of ``name``, oldest first (fewer when
+        the ring holds fewer)."""
+        if n <= 0:
+            return []
+        with self._lock:
+            ring = self._rings.get(name, ())
+            return list(ring)[-n:]
+
+    def clear(self) -> None:
+        with self._lock:
+            self._rings.clear()
+
+
 class MetricsRegistry:
     """Thread-safe named-metric store with JSON / Prometheus exporters.
 
@@ -217,6 +249,7 @@ class MetricsRegistry:
         self.enabled = enabled
         self.lock = threading.RLock()
         self._metrics: Dict[str, object] = {}
+        self.spans = SpanLog()
 
     def _get(self, cls, name: str, help: str):
         with self.lock:
@@ -249,9 +282,11 @@ class MetricsRegistry:
         self.enabled = False
 
     def reset(self) -> None:
-        """Drop every registered metric (tests; not for serving use)."""
+        """Drop every registered metric and finished span (tests; not
+        for serving use)."""
         with self.lock:
             self._metrics.clear()
+        self.spans.clear()
 
     # -------------------------------------------------------- exporters
     def snapshot(self) -> Dict[str, Dict]:
@@ -322,50 +357,6 @@ def _prom_sample(cell: str, suffix: str) -> str:
 
 def _fmt(v: float) -> str:
     return repr(int(v)) if float(v).is_integer() else repr(float(v))
-
-
-class PeriodicLogger:
-    """Ship a compact snapshot line to ``sink`` every ``interval``
-    seconds on a daemon thread (default sink: ``print``).  ``stop()``
-    flushes one final line so short runs still log."""
-
-    def __init__(self, registry: MetricsRegistry, interval: float = 30.0,
-                 sink: Optional[Callable[[str], None]] = None):
-        self.registry = registry
-        self.interval = interval
-        self.sink = sink if sink is not None else print
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-
-    def _emit(self) -> None:
-        snap = self.registry.snapshot()
-        self.sink(json.dumps(snap, separators=(",", ":"), sort_keys=True))
-
-    def _loop(self) -> None:
-        while not self._stop.wait(self.interval):
-            self._emit()
-
-    def start(self) -> "PeriodicLogger":
-        if self._thread is not None:
-            raise RuntimeError("already started")
-        self._stop.clear()
-        self._thread = threading.Thread(target=self._loop,
-                                        name="cft-metrics-log", daemon=True)
-        self._thread.start()
-        return self
-
-    def stop(self) -> None:
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-        self._emit()
-
-    def __enter__(self) -> "PeriodicLogger":
-        return self.start()
-
-    def __exit__(self, *exc) -> None:
-        self.stop()
 
 
 _default_registry = MetricsRegistry()

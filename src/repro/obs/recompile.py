@@ -28,7 +28,11 @@ A process-wide ``jax.monitoring`` listener additionally counts *every*
 backend compile in the process (``xla.compiles`` /
 ``xla.compile_s``) — warmup, maintenance warm-compiles, everything —
 giving snapshots the denominator against which zero hot-path
-recompiles is meaningful.
+recompiles is meaningful.  It also sums JAX's jaxpr tracing
+(``xla.trace_s``) and MLIR lowering (``xla.lower_s``), and adds each of
+the three durations to the innermost open trace span of the compiling
+thread (``trace_s``, ``lower_s``, ``compile_s`` attributes), so a call
+that compiles says how much of it was compiling.
 """
 from __future__ import annotations
 
@@ -36,6 +40,7 @@ import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
 from .metrics import MetricsRegistry, get_registry
+from .tracing import current_span
 
 # plan kinds whose commits legitimately change committed array shapes
 # (resized arena segment, full repack / restage)
@@ -82,7 +87,7 @@ class RecompileSentinel:
         self._shape_changes = self.metrics.counter(
             "maint.commit_shape_changes",
             "maintenance commits that changed committed array shapes")
-        _ensure_process_listener(self.metrics)
+        ensure_compile_listener()
 
     # ------------------------------------------------------ cache sizes
     def watch(self, label: str, fn) -> bool:
@@ -187,28 +192,59 @@ class RecompileSentinel:
         return self._armed
 
 
-# one process-wide jax.monitoring listener, shared by every sentinel;
-# jax offers no targeted unregister, so this never unhooks
+# one process-wide jax.monitoring listener, shared by every sentinel and
+# tracer; installed once and never unhooked
 _listener_lock = threading.Lock()
 _listener_installed = False
 
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+# event -> (process histogram, its help, span attribute)
+_PHASES = {
+    _TRACE: ("xla.trace_s", "jaxpr tracing, nested traces counted once",
+             "trace_s"),
+    _LOWER: ("xla.lower_s", "jaxpr to MLIR lowering durations", "lower_s"),
+    _COMPILE: ("xla.compile_s", "backend compile durations", "compile_s")}
+# per thread: how many events of each phase are open.  JAX traces an
+# inner jitted function inside its caller's trace, so only the
+# outermost event of a phase counts: nested time is counted once.
+_depth = threading.local()
 
-def _on_backend_compile(event: str, duration: float, **kw) -> None:
-    if "backend_compile" not in event:
+
+def _on_compile_start(event: str, value: float, **kw) -> None:
+    if event in _PHASES:
+        d = _depth.__dict__
+        d[event] = d.get(event, 0) + 1
+
+
+def _on_compile_duration(event: str, duration: float, **kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
         return
+    d = _depth.__dict__
+    open_ = d.get(event, 0) - 1
+    d[event] = max(open_, 0)
     reg = get_registry()
-    reg.counter("xla.compiles",
-                "process-wide backend compilations (any cause)").inc()
-    reg.histogram("xla.compile_s", "backend compile durations") \
-       .observe(duration)
+    if event == _COMPILE:
+        reg.counter("xla.compiles",
+                    "process-wide backend compilations (any cause)").inc()
+    elif open_ > 0:
+        return                       # inside an outer event of its phase
+    hist, help_, attr = phase
+    reg.histogram(hist, help_).observe(duration)
+    span = current_span()
+    if span is not None:
+        span.attrs[attr] = span.attrs.get(attr, 0.0) + duration
 
 
-def _ensure_process_listener(registry: MetricsRegistry) -> bool:
+def ensure_compile_listener() -> None:
+    """Install the process-wide compile listener once."""
     global _listener_installed
     with _listener_lock:
         if not _listener_installed:
             from jax import monitoring
+            monitoring.register_scalar_listener(_on_compile_start)
             monitoring.register_event_duration_secs_listener(
-                _on_backend_compile)
+                _on_compile_duration)
             _listener_installed = True
-        return _listener_installed

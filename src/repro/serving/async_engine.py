@@ -41,7 +41,8 @@ emits a ``serve.batch`` trace span (coalesce → pad → dispatch →
 prepare → device_lookup → route_back) and ticks the session's
 recompile sentinel, so a commit that leaks an unstable shape into the
 hot path is counted (and, armed, fatal) rather than a silent ~650 ms
-tail spike.
+tail spike.  The scheduler's wait for work is the profiler annotation
+``repro/serve.wait``.
 
 Failure model (see README "Failure model" for the full contract):
 
@@ -513,8 +514,9 @@ class AsyncServeEngine:
                 up = np.asarray(out.up)
                 down = np.asarray(out.down)
                 self.session.harvest()
-        except HotPathRecompileError:
+        except HotPathRecompileError as exc:
             # armed sentinel at dispatch: fail loudly, don't contain
+            sp.set(error=type(exc).__name__).end()
             raise
         except Exception as exc:
             # contain the blast radius to this batch: fail its futures,
@@ -663,7 +665,10 @@ class AsyncServeEngine:
                         # wake for the commit deadline even when idle
                         t2 = max(0.0, self.policy.deadline / 4)
                         timeout = t2 if timeout is None else min(timeout, t2)
-                    self._work.wait(timeout=timeout)
+                    # trace-only: with the batches' stages it covers this
+                    # thread's track, so what neither covers is a stall
+                    with self.session.tracer.annotate("serve.wait"):
+                        self._work.wait(timeout=timeout)
                     if self._stop:
                         return
                     now = self.clock()

@@ -445,6 +445,7 @@ class ServeEngine:
         self._decode = jax.jit(
             functools.partial(lm.decode_step, cfg), donate_argnums=(2,))
         self.retrieval = RetrievalSession()
+        self.tracer = Tracer()
 
     # engine-internal views of the session (kept for callers that poke
     # the state directly, e.g. the benches' equivalence gates)
@@ -510,13 +511,16 @@ class ServeEngine:
     def generate(self, batch: Dict[str, jax.Array], max_new_tokens: int
                  ) -> np.ndarray:
         """Greedy generation. batch['tokens']: (B, S) prompt ids."""
-        logits, state = self._prefill(self.params, batch)
-        tok = lm.greedy_token(logits)
-        out = [np.asarray(tok)]
-        for _ in range(max_new_tokens - 1):
-            logits, state = self._decode(self.params, tok, state)
-            tok = lm.greedy_token(logits)
-            out.append(np.asarray(tok))
+        with self.tracer.span("serve.generate", steps=max_new_tokens) as sp:
+            with sp.stage("prefill"):          # to the first token on host
+                logits, state = self._prefill(self.params, batch)
+                tok = lm.greedy_token(logits)
+                out = [np.asarray(tok)]
+            with sp.stage("decode"):           # each step syncs its token
+                for _ in range(max_new_tokens - 1):
+                    logits, state = self._decode(self.params, tok, state)
+                    tok = lm.greedy_token(logits)
+                    out.append(np.asarray(tok))
         return np.concatenate(out, axis=1)            # (B, new)
 
     # ---------------------------------------------------------- scheduler

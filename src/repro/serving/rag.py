@@ -199,34 +199,52 @@ class RAGPipeline:
         filter bank (multi-tenant shape); ``None`` retrieves globally —
         on a bank state that fans each entity out to every tree.
         """
-        ents = recognize_entities(query, self.gazetteer)
-        if self.use_device_lookup:
-            trees_np, hashes_np, b = self._device_query_batch(ents,
-                                                              tree_scope)
-            hashes = jnp.asarray(hashes_np)
-            trees = jnp.asarray(trees_np)
-            if isinstance(self._dev_state, ShardedBankState):
-                # the Pallas arena probe routes per query (segment start +
-                # bucket mask), so it works unchanged after tree-local
-                # expansions diverge per-tree bucket counts
-                out = sharded_retrieve_device(
-                    self._dev_state, hashes, trees,
-                    lookup_fn=cuckoo_lookup_arena_auto)
+        # the device path stays inline: JAX records the Python stack of
+        # every operation the eager step traces, and a helper frame would
+        # lengthen each
+        with self.session.tracer.span("rag.retrieve") as sp:
+            with sp.stage("recognise"):
+                ents = recognize_entities(query, self.gazetteer)
+                if self.use_device_lookup:
+                    trees_np, hashes_np, b = self._device_query_batch(
+                        ents, tree_scope)
+            if self.use_device_lookup:
+                # the device stage holds the per-call trace, lowering and
+                # compile work of the eager step; fetch waits for its
+                # results
+                with sp.stage("device"):
+                    hashes = jnp.asarray(hashes_np)
+                    trees = jnp.asarray(trees_np)
+                    if isinstance(self._dev_state, ShardedBankState):
+                        # the Pallas arena probe routes per query (segment
+                        # start + bucket mask), so it works unchanged after
+                        # tree-local expansions diverge per-tree bucket
+                        # counts
+                        out = sharded_retrieve_device(
+                            self._dev_state, hashes, trees,
+                            lookup_fn=cuckoo_lookup_arena_auto)
+                    else:
+                        out = retrieve_device(
+                            self._dev_state, hashes, trees,
+                            lookup_fn=cuckoo_lookup_arena_auto)
+                    self._dev_state = self._dev_state.with_temperature(
+                        out.temperature)
+                with sp.stage("harvest"):
+                    # harvest defers while a restage is staged-but-
+                    # uncommitted (the bank may already carry the next
+                    # geometry)
+                    self.session.harvest()
+                with sp.stage("fetch"):
+                    up, down = np.asarray(out.up), np.asarray(out.down)
+                with sp.stage("render"):
+                    up, down = self._merge_bank_updown(up, down, b,
+                                                       tree_scope)
+                    ctxs = self._render_device(ents, up, down)
             else:
-                out = retrieve_device(self._dev_state, hashes, trees,
-                                      lookup_fn=cuckoo_lookup_arena_auto)
-            self._dev_state = self._dev_state.with_temperature(
-                out.temperature)
-            # harvest defers while a restage is staged-but-uncommitted
-            # (the bank may already carry the next geometry)
-            self.session.harvest()
-            up, down = self._merge_bank_updown(np.asarray(out.up),
-                                               np.asarray(out.down),
-                                               b, tree_scope)
-            ctxs = self._render_device(ents, up, down)
-        else:
-            ctxs = self.retriever.render(self.retriever.retrieve(ents))
-        prompt = f"{SYSTEM_PROMPT}\n{ctxs}\nQuestion: {query}\nAnswer:"
+                with sp.stage("lookup"):
+                    ctxs = self.retriever.render(
+                        self.retriever.retrieve(ents))
+            prompt = f"{SYSTEM_PROMPT}\n{ctxs}\nQuestion: {query}\nAnswer:"
         return RAGAnswer(query=query, entities=ents, context=ctxs,
                          prompt=prompt)
 
@@ -323,15 +341,18 @@ class RAGPipeline:
 
     # ----------------------------------------------------------- generate
     def answer(self, query: str, max_new_tokens: int = 16) -> RAGAnswer:
-        ans = self.retrieve(query)
-        if self.engine is None:
-            return ans
-        ids = self.tokenizer.encode(ans.prompt, bos=True)
-        req = Request(prompt_ids=ids, max_new_tokens=max_new_tokens)
-        self.engine.serve([req])
-        ans.output_ids = req.out_ids
-        ans.text = self.tokenizer.decode(req.out_ids)
-        self.maintain()        # generation was the idle window
+        # the answer's retrieve, generate and maintenance spans open
+        # under this one
+        with self.session.tracer.span("rag.answer"):
+            ans = self.retrieve(query)
+            if self.engine is None:
+                return ans
+            ids = self.tokenizer.encode(ans.prompt, bos=True)
+            req = Request(prompt_ids=ids, max_new_tokens=max_new_tokens)
+            self.engine.serve([req])
+            ans.output_ids = req.out_ids
+            ans.text = self.tokenizer.decode(req.out_ids)
+            self.maintain()        # generation was the idle window
         return ans
 
     # -------------------------------------------------------------- async

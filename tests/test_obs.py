@@ -2,6 +2,7 @@
 recompile sentinel (injected shape-instability + healthy padded churn),
 and the pure-JSON packing_stats contract."""
 import json
+import re
 import threading
 
 import numpy as np
@@ -13,7 +14,7 @@ from repro.core import (CFTDeviceState, MaintenanceEngine,
                         estimate_fpr)
 from repro.core import hashing
 from repro.obs import (HotPathRecompileError, MetricsRegistry,
-                       PeriodicLogger, RecompileSentinel, Tracer,
+                       RecompileSentinel, SpanLog, Tracer, finished_spans,
                        get_registry, state_shapes)
 from repro.serving import AsyncServeEngine, RetrievalSession
 
@@ -86,7 +87,7 @@ def test_disabled_registry_mutates_nothing():
     with sp.stage("x"):
         pass
     sp.end()
-    assert t.recent() == []
+    assert finished_spans("t.span", 10, r) == []
     r.enable()
     c.inc()
     assert c.value() == 1
@@ -135,18 +136,6 @@ def test_snapshot_json_round_trip_and_prometheus_completeness():
         assert name.replace(".", "_") in text
 
 
-def test_periodic_logger_ships_snapshots():
-    r = MetricsRegistry()
-    r.counter("t.c").inc()
-    lines = []
-    log = PeriodicLogger(r, interval=0.01, sink=lines.append)
-    with log:
-        import time
-        time.sleep(0.05)
-    assert lines                               # at least the stop() flush
-    assert json.loads(lines[-1])["counters"]["t.c"] == 1
-
-
 # ---------------------------------------------------------------- tracing
 
 def test_tracer_spans_aggregate_into_histograms():
@@ -156,7 +145,7 @@ def test_tracer_spans_aggregate_into_histograms():
         with sp.stage("dispatch"):
             pass
         sp.add_stage("coalesce", 0.25)
-    spans = t.recent()
+    spans = finished_spans("serve.batch", 10, r)
     assert len(spans) == 1
     assert spans[0]["attrs"] == {"bucket": 32}
     assert [s["stage"] for s in spans[0]["stages"]] == ["dispatch",
@@ -180,7 +169,7 @@ def test_span_exception_path_records_stage_and_propagates():
                 raise KeyError("boom")
     assert r.histogram("trace.serve.batch").summary()["count"] == 1
     assert r.histogram("trace.serve.batch.dispatch").summary()["count"] == 1
-    spans = t.recent()
+    spans = finished_spans("serve.batch", 10, r)
     assert len(spans) == 1
     assert [s["stage"] for s in spans[0]["stages"]] == ["dispatch"]
     # an explicit error attribute (what _launch sets) rides the ring
@@ -191,7 +180,8 @@ def test_span_exception_path_records_stage_and_propagates():
             except ValueError as exc:
                 sp.set(error=type(exc).__name__)
                 raise
-    assert t.recent()[-1]["attrs"] == {"error": "ValueError"}
+    assert finished_spans("serve.batch", 1, r)[-1]["attrs"] == \
+        {"error": "ValueError"}
 
 
 def test_disabled_registry_exception_path_stays_silent():
@@ -205,11 +195,232 @@ def test_disabled_registry_exception_path_stays_silent():
             with sp.stage("dispatch"):
                 c.inc(reason="x")              # the error-path counter
                 raise ValueError("boom")
-    assert t.recent() == []
+    assert finished_spans("serve.batch", 10, r) == []
     assert c.value(reason="x") == 0
     snap = r.snapshot()
     assert snap["counters"] == {} and "trace.serve.batch" \
         not in snap["histograms"]
+
+
+def test_span_parent_is_the_innermost_open_span_on_its_thread():
+    r = MetricsRegistry()
+    t = Tracer(r)
+    seen = {}
+    with t.span("outer") as outer:
+        with t.span("middle") as middle:
+            with t.span("inner") as inner:
+                pass
+            # a span on another thread never sees this thread's spans
+            th = threading.Thread(
+                target=lambda: seen.update(other=t.span("other").end()))
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+        with t.span("sibling") as sibling:
+            pass
+    assert outer.parent is None
+    assert middle.parent == outer.id and sibling.parent == outer.id
+    assert inner.parent == middle.id
+    assert seen["other"].parent is None
+    by_name = {name: finished_spans(name, 1, r)[0]
+               for name in ("outer", "middle", "inner")}
+    assert by_name["inner"]["parent"] == by_name["middle"]["id"]
+    assert by_name["outer"]["t0"] <= by_name["inner"]["t0"] \
+        <= by_name["inner"]["t1"] <= by_name["outer"]["t1"]
+
+
+def test_compile_work_is_attributed_to_the_open_span():
+    """A fresh jitted function compiled inside a span gives the span its
+    tracing, lowering and backend-compile seconds; the process-wide
+    sums move by exactly what the spans got, nested traces counted
+    once."""
+    import jax
+    import jax.numpy as jnp
+
+    reg = get_registry()
+    names = {"trace_s": "xla.trace_s", "lower_s": "xla.lower_s",
+             "compile_s": "xla.compile_s"}
+    t = Tracer()
+    before = {k: reg.histogram(h).summary()["sum"] for k, h in names.items()}
+    got = {k: 0.0 for k in names}
+    for scale in (2.0, 3.0):
+        @jax.jit
+        def inner(x):
+            return jnp.sin(x) * scale
+
+        @jax.jit
+        def outer(x):
+            return inner(x) + inner(x * 2)
+
+        with t.span("t.compile") as sp:
+            outer(jnp.ones(8)).block_until_ready()
+        rec = finished_spans("t.compile", 1)[0]
+        for k in names:
+            assert sp.attrs[k] > 0, k
+            got[k] += sp.attrs[k]
+        assert sum(sp.attrs[k] for k in names) <= rec["t1"] - rec["t0"]
+    for k, h in names.items():
+        moved = reg.histogram(h).summary()["sum"] - before[k]
+        assert moved == pytest.approx(got[k], rel=1e-9), k
+
+
+def test_rag_spans_reach_the_profiler_trace(tmp_path):
+    """Under the profiler, ``repro/rag.retrieve`` and its stages are
+    host events of the trace; the answer's retrieve links to it."""
+    import glob
+    import jax
+    from repro.data import hospital_corpus
+    from repro.serving import RAGPipeline
+
+    rag = RAGPipeline(hospital_corpus(num_trees=6), None, use_bank=True)
+    query = f"Where does {rag.forest.entity_names[5]} report?"
+    rag.retrieve(query)                        # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rag.answer(query)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for p in data.planes for ln in p.lines
+             for e in ln.events if e.name.startswith("repro/")}
+    assert {"repro/rag.answer", "repro/rag.retrieve",
+            "repro/rag.retrieve/recognise", "repro/rag.retrieve/device",
+            "repro/rag.retrieve/harvest", "repro/rag.retrieve/fetch",
+            "repro/rag.retrieve/render"} <= names
+    answer, = finished_spans("rag.answer", 1)
+    retrieve, = finished_spans("rag.retrieve", 1)
+    assert retrieve["parent"] == answer["id"]
+    assert [s["stage"] for s in retrieve["stages"]] == [
+        "recognise", "device", "harvest", "fetch", "render"]
+
+
+class _CountingAnnotation:
+    """Stands in for ``TraceAnnotation`` as if a profiler trace ran."""
+    made = 0
+
+    def __init__(self, name):
+        type(self).made += 1
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+def test_disabled_registry_constructs_no_annotation(monkeypatch):
+    import repro.obs.tracing as tracing_mod
+    monkeypatch.setattr(tracing_mod, "TraceAnnotation", _CountingAnnotation)
+    _CountingAnnotation.made = 0
+    r = MetricsRegistry(enabled=False)
+    t = Tracer(r)
+    with t.span("serve.batch") as sp:
+        with sp.stage("pad"):
+            pass
+    with t.annotate("serve.wait"):
+        pass
+    assert _CountingAnnotation.made == 0
+    r.enable()
+    with t.span("serve.batch") as sp:
+        with sp.stage("pad"):
+            pass
+    with t.annotate("serve.wait"):
+        pass
+    assert _CountingAnnotation.made == 3
+    # the trace-only annotation never enters the span log
+    assert len(finished_spans("serve.batch", 10, r)) == 1
+    assert finished_spans("serve.wait", 10, r) == []
+
+
+def test_no_annotation_while_no_trace_runs(monkeypatch):
+    """Untraced, an enabled registry still records spans but builds no
+    profiler annotation."""
+    import repro.obs.tracing as tracing_mod
+
+    class Untraced(_CountingAnnotation):
+        @staticmethod
+        def is_enabled():
+            return False
+    monkeypatch.setattr(tracing_mod, "TraceAnnotation", Untraced)
+    _CountingAnnotation.made = 0
+    r = MetricsRegistry()
+    t = Tracer(r)
+    with t.span("rag.retrieve") as sp:
+        with sp.stage("device"):
+            pass
+    with t.annotate("serve.wait"):
+        pass
+    assert _CountingAnnotation.made == 0
+    assert len(finished_spans("rag.retrieve", 10, r)) == 1
+    assert finished_spans("serve.wait", 10, r) == []
+
+
+def test_span_log_keeps_the_newest_of_each_name():
+    r = MetricsRegistry()
+    r.spans = SpanLog(capacity=8)
+    t = Tracer(r)
+    for i in range(5):
+        t.span("rag.retrieve", i=i).end()
+    for i in range(100):
+        t.span("serve.batch", i=i).end()
+    kept = finished_spans("rag.retrieve", 5, r)
+    assert [d["attrs"]["i"] for d in kept] == list(range(5))
+    assert [d["attrs"]["i"] for d in finished_spans("rag.retrieve", 2, r)] \
+        == [3, 4]
+    flood = finished_spans("serve.batch", 100, r)
+    assert [d["attrs"]["i"] for d in flood] == list(range(92, 100))
+    assert finished_spans("serve.generate", 3, r) == []
+
+
+def test_lowered_retrieval_step_carries_no_instrumentation(monkeypatch):
+    """Spans live outside what JAX traces: the lowered retrieval step is
+    the same text with the registry on and off, names no ``repro/``
+    annotation, and lowering it builds no profiler annotation even
+    inside an open span."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    import repro.obs.tracing as tracing_mod
+    from repro.core import retrieve_device
+    from repro.kernels.cuckoo_lookup.ops import cuckoo_lookup_arena_auto
+
+    forest, bank, _ = _session(maint=False)
+    state = CFTDeviceState.from_bank(bank, forest)
+    hashes = jnp.asarray(hashing.hash_entities(
+        [forest.entity_names[i] for i in range(1, 9)]))
+    trees = jnp.asarray(np.arange(8, dtype=np.int32) % 4)
+    reg = get_registry()
+    t = Tracer()
+
+    def lowered():
+        step = jax.jit(functools.partial(
+            retrieve_device, lookup_fn=cuckoo_lookup_arena_auto))
+        with t.span("rag.retrieve") as sp:
+            with sp.stage("device"):
+                _CountingAnnotation.made = 0
+                monkeypatch.setattr(tracing_mod, "TraceAnnotation",
+                                    _CountingAnnotation)
+                low = step.lower(state, hashes, trees)
+                monkeypatch.undo()
+                assert _CountingAnnotation.made == 0
+        # named scopes show in the locations, beside source files
+        scopes = re.sub(r'"[^"]*\.py":[^)]*', '"file"',
+                        low.as_text(debug_info=True))
+        assert "repro/" not in scopes
+        return low.as_text()
+
+    on = lowered()
+    reg.disable()
+    try:
+        off = lowered()
+    finally:
+        reg.enable()
+    assert on == off
 
 
 # --------------------------------------------------------------- sentinel
