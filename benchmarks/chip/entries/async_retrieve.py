@@ -39,7 +39,9 @@ class Entry:
                        lookup_fn=cuckoo_lookup_arena_auto)
         session.attach_maintenance(MaintenanceEngine(bank), forest)
         self.session = session
-        self.engine = AsyncServeEngine(session)
+        # the deployment's own engine settings (the admission bound)
+        self.engine = AsyncServeEngine(session,
+                                       **ctx.config.get("serving", {}))
         if ctx.fault:
             from entries.faults import plant_retrieval
             plant_retrieval(session, ctx.fault)
@@ -116,7 +118,8 @@ class Entry:
         notes = [
             f"offered {n} requests ({pairs} pairs) over "
             f"{seconds:.1f} s; generator late p50 {quantile(late_ms, .5):.3f}"
-            f" p99 {quantile(late_ms, .99):.3f} max {late_ms.max():.3f} ms",
+            f" p99 {quantile(late_ms, .99):.3f} max {late_ms.max():.3f} ms;"
+            f" latency p95 {quantile(lat_ms, .95):.3f} ms",
             f"served queries {self.after['serve.queries']:.0f} in "
             f"{self.after['serve.batches']:.0f} batches, pad slots "
             f"{self.after['serve.padded_queries']:.0f}, shed "
@@ -126,9 +129,9 @@ class Entry:
             f"{self.after['xla.compiles']:.0f}"]
         self.win = Window(
             seconds=seconds, attempted=n, failed=failed,
-            e2e={"retrieve_p50_ms": quantile(lat_ms, .5),
-                 "retrieve_p95_ms": quantile(lat_ms, .95)},
-            stats={**self.after, "late_p99_ms": quantile(late_ms, .99)},
+            e2e={"retrieve_p50_ms": quantile(lat_ms, .5)},
+            stats={**self.after, "late_p99_ms": quantile(late_ms, .99),
+                   "retrieve_p95_ms": quantile(lat_ms, .95)},
             notes=notes)
         return self.win
 

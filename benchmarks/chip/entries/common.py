@@ -8,8 +8,8 @@ from typing import Dict, List
 import numpy as np
 
 from harness import Check
-from reference.forest import (Forest, compare, false_positive_bound,
-                              poisson_upper)
+from reference.forest import (Forest, Verdict, compare,
+                              false_positive_bound, merge, poisson_upper)
 
 COUNTERS = ("serve.queries", "serve.padded_queries", "serve.batches",
             "serve.rejected", "serve.prepares", "serve.commits",
@@ -48,7 +48,9 @@ def delta(before: Dict[str, float], after: Dict[str, float]
 
 class RetrievalTap:
     """Records what ``RAGPipeline.retrieve`` hands to the device step and
-    what it gets back, without changing either."""
+    what it gets back, without changing either.  Each call's probes and
+    answers stay on the device, where they were made, until :meth:`take`
+    moves them to the host; :meth:`held_bytes` counts what is held."""
 
     def __init__(self):
         import repro.serving.rag as rag_mod
@@ -56,42 +58,83 @@ class RetrievalTap:
         self._orig = rag_mod.retrieve_device
         self.calls: List[tuple] = []
         self.recording = False
+        self._held = 0
+        self._counted = 0
+        self.held_peak = 0
 
         def tapped(state, hashes, trees, **kw):
             out = self._orig(state, hashes, trees, **kw)
             if self.recording:
-                self.calls.append((hashes, trees, out))
+                self.calls.append((hashes, trees, out.hit, out.locations,
+                                   out.up, out.down))
             return out
         rag_mod.retrieve_device = tapped
+
+    def held_bytes(self) -> int:
+        """Bytes of the calls held now; counted here, between calls, so
+        that a timed call does no more than record."""
+        for call in self.calls[self._counted:]:
+            self._held += sum(int(a.nbytes) for a in call)
+        self._counted = len(self.calls)
+        self.held_peak = max(self.held_peak, self._held)
+        return self._held
+
+    def take(self) -> List[tuple]:
+        """The held calls on the host, in call order, as ``(hashes, trees,
+        hit, locations, up, down)``; the tap holds nothing after it."""
+        import jax
+        self.held_bytes()
+        calls = jax.device_get(self.calls)
+        self.calls, self._held, self._counted = [], 0, 0
+        return calls
 
     def close(self) -> None:
         self._mod.retrieve_device = self._orig
 
 
+class ProbeTally:
+    """The verdict of every probe of a run, added a batch at a time, so
+    that only the distinct pairs asked outlive their batch."""
+
+    def __init__(self, forest: Forest, n: int):
+        self.forest, self.n = forest, n
+        self.parts: List[Verdict] = []
+        self.probes = 0                 # all probes, judged or not
+        self.hits = 0
+
+    def add(self, trees, hashes, hit, locs, up, down) -> None:
+        self.parts.append(compare(self.forest, trees, hashes, hit, locs, up,
+                                  down, n=self.n))
+        self.probes += int(np.asarray(hashes).size)
+        self.hits += int(np.asarray(hit).sum())
+        # fold the batches' distinct pairs together once they outnumber
+        # the pairs already folded: each pair is sorted a few times at most
+        if sum(v.keys.size for v in self.parts[1:]) > \
+                self.parts[0].keys.size:
+            self.parts = [merge(self.parts)]
+
+    def verdict(self) -> Verdict:
+        return merge(self.parts)
+
+
 def probe_checks(forest: Forest, trees, hashes, hit, locs, up, down,
                  config: dict, unanswered: int, ner_wrong: int = None):
     """The retrieval checks of a batch of answers, and the verdict."""
-    bank = config["bank"]
     v = compare(forest, trees, hashes, hit, locs, up, down,
-                n=bank["hierarchy_n"])
+                n=config["bank"]["hierarchy_n"])
+    return verdict_checks(v, config, unanswered, ner_wrong), v
+
+
+def verdict_checks(v: Verdict, config: dict, unanswered: int,
+                   ner_wrong: int = None) -> List[Check]:
+    """The retrieval checks of a verdict, each beside its limit."""
     # the filter's stated false-positive rate, held as a count over the
     # distinct pairs asked: a sound filter exceeds it one run in a million
-    fp_limit = poisson_upper(v.distinct * false_positive_bound(bank["slots"]))
+    fp_limit = poisson_upper(
+        v.distinct * false_positive_bound(config["bank"]["slots"]))
     checks = [Check("unanswered", unanswered, 0),
               Check("wrong_answers", v.wrong, 0),
               Check("false_positives", v.false_pos, fp_limit)]
     if ner_wrong is not None:
         checks.insert(1, Check("wrong_queries", ner_wrong, 0))
-    return checks, v
-
-
-def tapped_arrays(calls):
-    """Concatenated probes and answers of the tapped device calls."""
-    if not calls:
-        empty = np.zeros((0,), np.int64)
-        return empty, empty, empty, np.zeros((0, 1)), np.zeros((0, 1, 1)), \
-            np.zeros((0, 1, 1))
-    cat = lambda xs: np.concatenate([np.asarray(x) for x in xs])  # noqa
-    return (cat([c[1] for c in calls]), cat([c[0] for c in calls]),
-            cat([c[2].hit for c in calls]), cat([c[2].locations for c in calls]),
-            cat([c[2].up for c in calls]), cat([c[2].down for c in calls]))
+    return checks

@@ -9,7 +9,7 @@ also times the pipeline's retrieve, serve and maintain calls inside it.
 
 ``ServeEngine.serve`` compiles a prefill for every new prompt length, so
 set-up answers every pool query once, and once more for each length that
-truncation to the cache gives a scheduled answer.
+truncation to the cache can give a scheduled answer.
 """
 from __future__ import annotations
 
@@ -82,23 +82,28 @@ class Entry(rag_retrieve.Entry):
 
     def warm(self) -> None:
         """One whole answer (retrieval, prefill, decode, maintenance),
-        then a prefill for every other prompt length the schedule gives:
-        each pool query's prompt, cut as ``ServeEngine.serve`` cuts it to
-        leave room for that answer's tokens."""
+        then a prefill for every other prompt length the schedule can
+        give: each of its queries' prompts, cut as ``ServeEngine.serve``
+        cuts it to leave room for each answer length of the mix.
+
+        The schedule's first block holds whole periods of the pool, so
+        every query a later block asks; a later block may pair a query
+        with an answer length that the first block does not, and the cut
+        prompt of every such pair is warmed here too.  (On 200 seeds the
+        first block's own pairs gave every one of these lengths.)"""
         from repro.serving.engine import Request
         sched, rag = self.schedule, self.rag
         cache = self.ctx.config["serving"]["cache_size"]
         rag.answer(sched.queries[0], max_new_tokens=int(sched.max_new[0]))
         seen = {len(self.served[0].prompt_ids)}
-        prompts = {}
-        for q, m in zip(sched.queries, sched.max_new):
-            if q not in prompts:
-                prompts[q] = rag.tokenizer.encode(rag.retrieve(q).prompt,
-                                                  bos=True)
-            ids = prompts[q][-(cache - int(m)):]
-            if len(ids) not in seen:
-                seen.add(len(ids))
-                rag.engine.serve([Request(prompt_ids=ids, max_new_tokens=2)])
+        for q in dict.fromkeys(sched.queries):
+            prompt = rag.tokenizer.encode(rag.retrieve(q).prompt, bos=True)
+            for m in sorted(set(self.ctx.traffic["answer_tokens"])):
+                ids = prompt[-(cache - m):]
+                if len(ids) not in seen:
+                    seen.add(len(ids))
+                    rag.engine.serve([Request(prompt_ids=ids,
+                                              max_new_tokens=2)])
         for log in (self.t_retrieve, self.t_serve, self.t_maintain,
                     self.served):
             log.clear()

@@ -5,6 +5,13 @@ before asking the next.  The pipeline recognises the query's entities
 and fans each out over every tree of the bank; each call is timed on the
 host clock from query text to rendered context (``fanout_p50_ms``,
 ``fanout_p95_ms``).
+
+The window asks queries for as long as it lasts, however fast the calls:
+the schedule grows by a block when the window reaches its end, and the
+tapped probes and answers leave the device and are held to the reference
+a block at a time (``TAP_BYTES``).  Both happen between calls, outside
+every call's timer; a window of fewer calls than a block, holding fewer
+bytes than ``TAP_BYTES``, needs neither.
 """
 from __future__ import annotations
 
@@ -12,9 +19,13 @@ import time
 
 import numpy as np
 
-from entries.common import (RetrievalTap, counters, dataset, delta,
-                            generator, probe_checks, tapped_arrays)
+from entries.common import (ProbeTally, RetrievalTap, counters, dataset,
+                            delta, generator, verdict_checks)
 from harness import Window, quantile
+
+# device bytes of tapped calls that the window lets the tap hold before it
+# checks them: about 185 fan-out calls over 600 trees
+TAP_BYTES = 64 << 20
 
 
 def ner_wrong(ref, calls, entities) -> int:
@@ -23,7 +34,7 @@ def ner_wrong(ref, calls, entities) -> int:
     from reference.forest import fnv1a32_many
     trees_all = int(ref.tree.max()) + 1
     wrong = 0
-    for (hashes, trees, _), ents in zip(calls, entities):
+    for (hashes, trees, *_), ents in zip(calls, entities):
         want_h = np.tile(fnv1a32_many(ents).astype(np.int64), trees_all)
         want_t = np.repeat(np.arange(trees_all), len(ents))
         got_h = np.asarray(hashes).astype(np.int64)
@@ -32,7 +43,7 @@ def ner_wrong(ref, calls, entities) -> int:
                 np.array_equal(got_h, want_h)
                 and np.array_equal(got_t, want_t)):
             wrong += 1
-    return wrong + abs(len(calls) - len(entities))
+    return wrong
 
 
 class Entry:
@@ -53,13 +64,29 @@ class Entry:
             from entries.faults import plant_rag
             plant_rag(self, ctx.fault)
         self.tap = RetrievalTap()
-        self.schedule = generator(ctx.traffic).generate(
-            self.ref, ctx.traffic, ctx.traffic["schedule_length"], ctx.seed)
+        self.tally = ProbeTally(self.ref, ctx.config["bank"]["hierarchy_n"])
+        self.checked, self.wrong_queries, self.tap_checks = 0, 0, 0
+        self.blocks = 1
+        self.schedule = self.block(0)
         self.warm()
+
+    def block(self, b: int):
+        """Block ``b`` of the schedule: ``schedule_length`` queries of
+        their own, drawn from the seed and ``b``."""
+        traffic = self.ctx.traffic
+        n = traffic["schedule_length"]
+        s = generator(traffic).generate(self.ref, traffic, n, self.ctx.seed,
+                                        block=b)
+        if len(s.queries) != n:
+            raise RuntimeError(f"schedule block {b} holds {len(s.queries)} "
+                               f"queries, not {n}")
+        return s
 
     def warm(self) -> None:
         """One query for every entity count the schedule's queries hold:
-        the fan-out batch's shape depends on it alone."""
+        the fan-out batch's shape depends on it alone.  Every query of
+        every block holds ``entities_per_query``, so a later block brings
+        no new count."""
         counts = {}
         for q, e in zip(self.schedule.queries, self.schedule.entities):
             counts.setdefault(len(e), q)
@@ -70,6 +97,18 @@ class Entry:
         with self.ctx.annotate("retrieve"):
             self.rag.retrieve(self.schedule.queries[i])
 
+    def check(self) -> None:
+        """Move the tapped calls to the host and hold them to the
+        reference: each call's batch against its query's entities, each
+        probe's answer against the forest."""
+        calls = self.tap.take()
+        ents = self.schedule.entities[self.checked:self.checked + len(calls)]
+        self.wrong_queries += ner_wrong(self.ref, calls, ents)
+        self.checked += len(calls)
+        if calls:
+            cat = lambda k: np.concatenate([c[k] for c in calls])  # noqa
+            self.tally.add(cat(1), cat(0), cat(2), cat(3), cat(4), cat(5))
+
     def window(self, seconds: float) -> Window:
         lat = []
         before = counters()
@@ -77,12 +116,17 @@ class Entry:
         t0 = time.perf_counter()
         i = 0
         while time.perf_counter() - t0 < seconds:
-            if i >= len(self.schedule.queries):
-                raise RuntimeError("schedule too short for the window")
+            if i == len(self.schedule.queries):
+                self.schedule.extend(self.block(self.blocks))
+                self.blocks += 1
             t = time.perf_counter()
             self.call(i)
             lat.append(time.perf_counter() - t)
             i += 1
+            if self.tap.held_bytes() >= TAP_BYTES:
+                with self.ctx.annotate("check"):
+                    self.check()
+                self.tap_checks += 1
         self.tap.recording = False
         self.asked = i
         after = delta(before, counters())
@@ -95,29 +139,28 @@ class Entry:
             e2e=e2e, stats=dict(after),
             notes=[f"{i} {kind} calls in {seconds:.1f} s, window compiles "
                    f"{after['xla.compiles']:.0f}; "
-                   + ", ".join(f"{k} {v:.3f}" for k, v in e2e.items())])
+                   + ", ".join(f"{k} {v:.3f}" for k, v in e2e.items()),
+                   f"schedule blocks {self.blocks}; the tap held at most "
+                   f"{self.tap.held_peak} device bytes, checked in the "
+                   f"window {self.tap_checks} times"])
         return self.win
 
     def release(self) -> None:
         self.tap.close()
         getattr(self, "unplant", lambda: None)()
-        calls = self.tap.calls
-        self.arrays = tapped_arrays(calls)
-        self.calls = [(np.asarray(h), np.asarray(t), None)
-                      for h, t, _ in calls]
+        self.check()
         del self.rag, self.tap
 
     def verify(self):
-        trees, hashes, hit, locs, up, down = self.arrays
-        ner = ner_wrong(self.ref, self.calls,
-                        self.schedule.entities[:self.asked])
-        checks, v = probe_checks(self.ref, trees, hashes, hit, locs, up,
-                                 down, self.ctx.config,
-                                 unanswered=0, ner_wrong=ner)
-        self.win.stats.update(probes=float(hashes.size),
-                              probe_hits=float(np.asarray(hit).sum()))
+        v = self.tally.verdict()
+        # a query whose call made no device call, or made two, leaves the
+        # tapped calls and the schedule out of step
+        wrong = self.wrong_queries + abs(self.checked - self.asked)
+        checks = verdict_checks(v, self.ctx.config, unanswered=0,
+                                ner_wrong=wrong)
+        self.win.stats.update(probes=float(self.tally.probes),
+                              probe_hits=float(self.tally.hits))
         return checks + self.verify_more()
 
     def verify_more(self):
         return []
-

@@ -23,6 +23,7 @@ import json
 import math
 import os
 import shutil
+import subprocess
 import sys
 import time
 from typing import Callable, Dict, List, Optional
@@ -159,11 +160,10 @@ def configure_jax() -> None:
 @contextlib.contextmanager
 def no_persistent_cache():
     """Neither read nor write the persistent compile cache.  The window
-    runs under it: what the program compiles afresh in every call (as
-    ``RAGPipeline.retrieve`` does) is then compiled in every call, as in
-    a deployment whose cache does not hold it.  Under JAX's 1 s floor a
-    compile that happened to take longer would be written, and every
-    later run of the checkout would load it instead."""
+    runs under it: whatever the program compiles in the window is
+    compiled there, as in a deployment whose cache does not hold it.
+    Under JAX's 1 s floor a compile that happened to take longer would be
+    written, and every later run of the checkout would load it instead."""
     import jax
     from jax._src import compilation_cache
     enabled = jax.config.jax_enable_compilation_cache
@@ -196,11 +196,31 @@ def quantile(values, q: float) -> float:
     return float(np.quantile(v, q)) if v.size else math.nan
 
 
+# a sibling process that only sleeps and notes when it woke late: a
+# pause it sees too stopped the whole machine, not just this process
+PAUSE_WATCH = """
+import signal, sys, time
+gaps, stop = [], []
+signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
+print("ready", flush=True)
+last = time.monotonic()
+while not stop:
+    time.sleep(0.002)
+    now = time.monotonic()
+    if now - last > 0.05:
+        gaps.append(now - last)
+    last = now
+print(len(gaps), max(gaps, default=0.0), flush=True)
+"""
+
+
 class HostWatch:
     """What the host did inside the window besides the work: Python's
-    garbage-collector pauses by generation, and how many of the
-    backend compilations JAX reports were loads from the persistent
-    compile cache.  Read into the run's notes, never into a metric."""
+    garbage-collector pauses by generation, the pauses over 50 ms that a
+    sibling process saw (the whole machine standing still), and how many
+    of the backend compilations JAX reports were loads from the
+    persistent compile cache.  Read into the run's notes, never into a
+    metric."""
 
     _cache_hits = 0
     _listening = False
@@ -229,12 +249,25 @@ class HostWatch:
             HostWatch._listening = True
         self._hits0 = HostWatch._cache_hits
         gc.callbacks.append(self._on_gc)
+        self.machine = None
+        self._sibling = subprocess.Popen(
+            [sys.executable, "-c", PAUSE_WATCH], stdout=subprocess.PIPE,
+            stderr=subprocess.DEVNULL, text=True)
+        self._sibling.stdout.readline()
         return self
 
     def __exit__(self, *exc):
         import gc
         gc.callbacks.remove(self._on_gc)
         self.cache_hits = HostWatch._cache_hits - self._hits0
+        sib = self._sibling
+        sib.terminate()
+        try:
+            count, longest = sib.communicate(timeout=10)[0].split()
+            self.machine = (int(count), float(longest))
+        except (subprocess.TimeoutExpired, ValueError):
+            sib.kill()
+            sib.communicate()
 
     def note(self) -> str:
         parts = []
@@ -243,8 +276,11 @@ class HostWatch:
             parts.append(f"gen{g} {ms.size}" + (
                 f" (longest {ms.max():.1f} ms, total {ms.sum():.1f} ms)"
                 if ms.size else ""))
-        return (f"gc pauses {', '.join(parts)}; persistent-cache loads "
-                f"{self.cache_hits}")
+        machine = "not read" if self.machine is None else (
+            f"{self.machine[0]} (longest {self.machine[1] * 1e3:.1f} ms)")
+        return (f"gc pauses {', '.join(parts)}; machine pauses over 50 ms "
+                f"seen by a sibling process {machine}; persistent-cache "
+                f"loads {self.cache_hits}")
 
 
 def memory_peak(devs) -> int:
@@ -258,17 +294,18 @@ def memory_peak(devs) -> int:
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              t_start: Optional[float] = None, require_tpu: bool = True,
              config_overrides: Optional[dict] = None,
+             traffic_overrides: Optional[dict] = None,
              fault: Optional[str] = None,
              log: Callable[[str], None] = lambda s: None) -> dict:
     """Run one cell and return its result line as a dict.
 
-    ``require_tpu=False``, ``config_overrides`` and ``fault`` are for the
+    ``require_tpu=False``, the overrides and ``fault`` are for the
     harness's own tests: they run the rest of a run on the CPU at a small
     size, with the timed path intact or broken on purpose."""
     t_start = time.perf_counter() if t_start is None else t_start
     cell, config, traffic, e2e_specs, layer_specs = resolve(workload)
-    if config_overrides:
-        config = _merge(config, config_overrides)
+    config = _merge(config, config_overrides or {})
+    traffic = _merge(traffic, traffic_overrides or {})
     configure_jax()
     import jax
     if require_tpu:

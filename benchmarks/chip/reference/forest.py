@@ -180,10 +180,31 @@ class Forest:
 @dataclasses.dataclass
 class Verdict:
     probes: int            # probes compared
-    distinct: int          # distinct (tree, hash) pairs among them
     wrong: int             # probes whose answer no filter semantics explain
-    false_pos: int         # distinct pairs answered with a fingerprint twin
     present: int           # probes for an entity the tree holds
+    keys: np.ndarray       # the distinct ``tree << 32 | hash`` pairs compared
+    fp_keys: np.ndarray    # those answered with a fingerprint twin
+
+    @property
+    def distinct(self) -> int:
+        return int(self.keys.size)
+
+    @property
+    def false_pos(self) -> int:
+        return int(self.fp_keys.size)
+
+
+def merge(verdicts: Sequence[Verdict]) -> Verdict:
+    """The verdict of several batches of probes as one: the probes' counts
+    add up, and a pair asked in several batches is one distinct pair."""
+    empty = np.zeros(0, np.int64)
+    return Verdict(probes=sum(v.probes for v in verdicts),
+                   wrong=sum(v.wrong for v in verdicts),
+                   present=sum(v.present for v in verdicts),
+                   keys=np.unique(np.concatenate(
+                       [empty] + [v.keys for v in verdicts])),
+                   fp_keys=np.unique(np.concatenate(
+                       [empty] + [v.fp_keys for v in verdicts])))
 
 
 def compare(forest: Forest, trees, hashes, hit, locations, up, down,
@@ -241,9 +262,7 @@ def compare(forest: Forest, trees, hashes, hit, locations, up, down,
             & (g_hash != hashes)
             & (fingerprints(g_hash) == fingerprints(hashes)))
     wrong = judged & ~(ok_hit | ok_miss | twin)
-    uniq, inv = np.unique(keys[judged], return_inverse=True)
-    fp_pairs = np.zeros(uniq.size, bool)
-    np.logical_or.at(fp_pairs, inv, twin[judged])
-    return Verdict(probes=int(judged.sum()), distinct=int(uniq.size),
-                   wrong=int(wrong.sum()), false_pos=int(fp_pairs.sum()),
-                   present=int((present & judged).sum()))
+    return Verdict(probes=int(judged.sum()), wrong=int(wrong.sum()),
+                   present=int((present & judged).sum()),
+                   keys=np.unique(keys[judged]),
+                   fp_keys=np.unique(keys[judged & twin]))

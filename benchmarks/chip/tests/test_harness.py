@@ -4,6 +4,8 @@ work counts follow shapes alone, and the command refuses to run without
 a TPU."""
 from __future__ import annotations
 
+import collections
+import hashlib
 import json
 import os
 import re
@@ -16,7 +18,7 @@ import pytest
 
 import counts
 import harness
-from entries.common import generator
+from entries.common import dataset, generator
 from reference.forest import Forest, poisson_upper
 
 BENCH = harness.spec()
@@ -129,6 +131,59 @@ def test_traffic_follows_the_seed(mix):
     a, b, c = (gen.generate(forest, params, size, s) for s in (big, big, 7))
     assert flat(a) == flat(b)
     assert flat(a) != flat(c)
+
+
+# the first 2,048 queries of each closed-loop mix as they were drawn before
+# the schedules grew in blocks: sha256 of their JSON, first 16 digits
+FIRST_BLOCK = {
+    ("retrieve-fanout-600", 7): "ec4da3ec2b69b50b",
+    ("retrieve-fanout-600", 2 ** 31 + 12345): "6d6a30963a39f764",
+    ("retrieve-fanout-600", 3140021900): "825370e8727c768e",
+    ("rag-answer-600", 7): "14af9d241807038d",
+    ("rag-answer-600", 2 ** 31 + 12345): "a3917d386730bd54",
+    ("rag-answer-600", 3140021900): "3cd282b3c89c6e26",
+}
+
+
+def _digest(queries, entities, max_new):
+    body = [queries, entities, None if max_new is None
+            else [int(m) for m in max_new]]
+    return hashlib.sha256(json.dumps(body).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("cell,seed", sorted(FIRST_BLOCK))
+def test_extended_schedule_keeps_its_first_block(cell, seed):
+    """The closed loops' schedules grow a block at a time: the first is
+    the schedule of ``schedule_length`` queries as it always was, and the
+    next follows the same laws with draws of its own."""
+    w, config, traffic, _, _ = harness.resolve(cell)
+    _, ref = dataset(config)
+    entry = harness.load_module(os.path.join(
+        harness.HERE, "entries", traffic["entry"] + ".py")).Entry(
+        harness.Context(cell=w, config=config, traffic=traffic, seed=seed,
+                        seconds=1.0, trace=False))
+    entry.ref = ref
+    n = traffic["schedule_length"]
+    s = entry.block(0)
+    s.extend(entry.block(1))
+    assert len(s.queries) == len(s.entities) == 2 * n
+    head = [s.queries[:n], s.entities[:n],
+            None if s.max_new is None else s.max_new[:n]]
+    assert _digest(*head) == FIRST_BLOCK[cell, seed]
+    base = generator(traffic).generate(ref, traffic, n, seed)
+    assert _digest(*head) == _digest(base.queries, base.entities,
+                                     base.max_new)
+    tail = s.queries[n:]
+    assert tail != s.queries[:n]
+    assert all(len(e) == traffic["entities_per_query"]
+               for e in s.entities[n:])
+    if "pool_size" in traffic:
+        # whole periods of the pool: each query as often as in the first
+        assert collections.Counter(tail) == collections.Counter(
+            s.queries[:n])
+        assert sorted(s.max_new[n:]) == sorted(s.max_new[:n])
+    else:
+        assert not set(tail) & set(s.queries[:n])
 
 
 def test_open_loop_offers_the_same_load_on_every_seed():

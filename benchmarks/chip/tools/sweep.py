@@ -55,7 +55,7 @@ def main(argv=None) -> int:
             "rate_per_s": rate, "attempted": win.attempted,
             "failed": win.failed, "unanswered": entry.unanswered,
             "p50_ms": win.e2e["retrieve_p50_ms"],
-            "p95_ms": win.e2e["retrieve_p95_ms"],
+            "p95_ms": win.stats["retrieve_p95_ms"],
             "first_tenth_p50_ms": float(np.median(lat[:tenth])),
             "last_tenth_p50_ms": float(np.median(lat[-tenth:])),
             "late_p99_ms": win.stats["late_p99_ms"],

@@ -16,6 +16,13 @@ them.  Two mixes share this generator:
 
 ``answer_tokens`` (optional): the answer lengths, cycled in blocks that
 hold each once, in an order drawn from ``--seed``.
+
+A closed loop asks as many queries as its window has room for, so its
+schedule comes in blocks of ``count``: block 0 draws from the stream of
+``--seed`` alone, block ``b`` from a stream of its own made from the seed
+and ``b``.  Every block follows the same laws (whole pool periods when
+``count`` is a multiple of ``pool_size``, fresh Zipf draws otherwise), and
+no block repeats another.
 """
 from __future__ import annotations
 
@@ -40,6 +47,13 @@ class Schedule:
     entities: List[List[str]]            # per query, the names it holds
     max_new: Optional[np.ndarray]        # per query answer length
 
+    def extend(self, more: "Schedule") -> None:
+        """Append the queries of a later block."""
+        self.queries += more.queries
+        self.entities += more.entities
+        if self.max_new is not None:
+            self.max_new = np.concatenate([self.max_new, more.max_new])
+
 
 def _query(rng, names: List[str]) -> str:
     return " ".join(TEMPLATES[int(rng.integers(len(TEMPLATES)))].format(e=e)
@@ -55,8 +69,9 @@ def _distinct_zipf(rng, cdf: np.ndarray, k: int) -> List[int]:
     return got
 
 
-def generate(forest, params: dict, count: int, seed: int) -> Schedule:
-    rng = np.random.default_rng([seed, 2])
+def generate(forest, params: dict, count: int, seed: int,
+             block: int = 0) -> Schedule:
+    rng = np.random.default_rng([seed, 2] + ([block] if block else []))
     k = params["entities_per_query"]
     theta = params["zipf_theta"]
     names = forest.names
