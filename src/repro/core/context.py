@@ -84,53 +84,58 @@ def render_context(forest: EntityForest, ctxs: Sequence[EntityContext]) -> str:
 def gather_hierarchy(parent: jax.Array, entity_id: jax.Array,
                      nodes: jax.Array, n: int) -> jax.Array:
     """Vectorized n-level ancestor gather: for each node index in ``nodes``
-    return (len(nodes), n) ancestor entity ids (NULL-padded).  Runs inside the
-    jitted serving step — parent-pointer chase becomes n dependent gathers."""
-    def step(cur, _):
+    return (len(nodes), n) ancestor entity ids (nearest first, NULL-padded;
+    a NULL node gives a NULL row).  The parent-pointer chase is ``n``
+    static select+gather steps: no loop, so the walk lowers to
+    straight-line gathers over the batch."""
+    cur = nodes.astype(jnp.int32)
+    outs = []
+    for _ in range(n):
         p = jnp.where(cur == NULL, NULL, parent[jnp.maximum(cur, 0)])
         eid = jnp.where(p == NULL, NULL, entity_id[jnp.maximum(p, 0)])
-        return p, eid
-    _, eids = jax.lax.scan(step, nodes.astype(jnp.int32), None, length=n)
-    return jnp.swapaxes(eids, 0, 1)            # (B, n)
+        outs.append(eid)
+        cur = p
+    return jnp.stack(outs, axis=1)             # (B, n)
 
 
 def gather_descendants(child_offsets: jax.Array, child_index: jax.Array,
                        entity_id: jax.Array, nodes: jax.Array,
                        n: int) -> jax.Array:
-    """First-n BFS-down entity ids per node, fully vectorized with a bounded
-    frontier ring buffer of size n (level order, NULL-padded)."""
-    B = nodes.shape[0]
+    """First-n BFS-down entity ids per node (level order, NULL-padded; a
+    NULL node gives a NULL row).
 
-    def per_node(node):
-        buf = jnp.full((n,), NULL, dtype=jnp.int32)   # pending frontier
-        out = jnp.full((n,), NULL, dtype=jnp.int32)
+    The frontier is a ring of ``n`` slots per node with a write cursor;
+    each of the ``n`` steps pops one slot and pushes that node's children.
+    Every step is static select arithmetic over the whole batch: a pop that
+    has nothing to expand pushes from a NULL source, which makes every
+    push lane invalid, so no ``cond`` and no loop is needed, and every
+    gather reads ``(B,)`` elements of the forest's arrays (never a copy of
+    them per node)."""
+    b = nodes.shape[0]
+    ci = child_index.shape[0]
+    nodes = nodes.astype(jnp.int32)
+    buf = jnp.full((b, n), NULL, jnp.int32)      # BFS frontier ring, cap n
+    w = jnp.zeros((b,), jnp.int32)               # frontier write cursor
+    lane = jnp.arange(n, dtype=jnp.int32)[None, :]
 
-        def push_children(state, src):
-            buf, w = state
-            lo = child_offsets[jnp.maximum(src, 0)]
-            hi = child_offsets[jnp.maximum(src, 0) + 1]
-            def body(k, st):
-                buf, w = st
-                idx = lo + k
-                valid = (src != NULL) & (idx < hi) & (w < n)
-                c = jnp.where(valid, child_index[jnp.minimum(idx, child_index.shape[0] - 1)], NULL)
-                buf = jnp.where(valid, buf.at[jnp.minimum(w, n - 1)].set(c), buf)
-                return buf, jnp.where(valid, w + 1, w)
-            return jax.lax.fori_loop(0, n, body, (buf, w))
+    def push(buf, w, src):
+        s = jnp.maximum(src, 0)
+        lo = child_offsets[s]
+        hi = child_offsets[s + 1]
+        for k in range(n):
+            idx = lo + k
+            valid = (src != NULL) & (idx < hi) & (w < n)
+            c = jnp.where(valid, child_index[jnp.minimum(idx, ci - 1)], NULL)
+            oh = (lane == jnp.minimum(w, n - 1)[:, None]) & valid[:, None]
+            buf = jnp.where(oh, c[:, None], buf)
+            w = jnp.where(valid, w + 1, w)
+        return buf, w
 
-        buf, w = push_children((buf, jnp.int32(0)), node)
-
-        def step(i, st):
-            buf, w, out = st
-            cur = buf[jnp.minimum(i, n - 1)]
-            valid = (i < w) & (cur != NULL)
-            out = jnp.where(valid, out.at[i].set(entity_id[jnp.maximum(cur, 0)]), out)
-            buf, w = jax.lax.cond(
-                valid, lambda: push_children((buf, w), cur), lambda: (buf, w))
-            return buf, w, out
-
-        _, _, out = jax.lax.fori_loop(0, n, step, (buf, w, out))
-        return out
-
-    return jax.vmap(per_node)(nodes.astype(jnp.int32)) if B else \
-        jnp.zeros((0, n), dtype=jnp.int32)
+    buf, w = push(buf, w, nodes)
+    outs = []
+    for i in range(n):
+        cur = buf[:, i]
+        valid = (i < w) & (cur != NULL)
+        outs.append(jnp.where(valid, entity_id[jnp.maximum(cur, 0)], NULL))
+        buf, w = push(buf, w, jnp.where(valid, cur, NULL))
+    return jnp.stack(outs, axis=1)               # (B, n)

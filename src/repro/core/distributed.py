@@ -635,8 +635,7 @@ def _sharded_retrieve_jit(state: ShardedBankState,
         hit, locs, temp = _fused_lookup_core(
             state, query_trees, query_hashes, capacity=capacity,
             max_locs=max_locs)
-        return finish_context(state, hit, locs, temp,
-                              max_locs=max_locs, n=n)
+        return finish_context(state, hit, locs, temp, n=n)
     res, temp = _lookup_core(state, query_trees, query_hashes, bump=True,
                              lookup_fn=lookup_fn, capacity=capacity)
     return gather_context(state, res, temp, max_locs=max_locs, n=n)

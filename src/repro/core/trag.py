@@ -11,6 +11,7 @@ inside the jitted serving step (see repro/serving/rag.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, NamedTuple, Optional, Sequence
 
 import jax
@@ -257,8 +258,7 @@ def gather_context(state, res: LookupResult, temperature: jax.Array,
     """
     nodes = csr_window(state.csr_offsets, state.csr_nodes,
                        res.hit, res.head, max_locs)
-    return finish_context(state, res.hit, nodes, temperature,
-                          max_locs=max_locs, n=n)
+    return finish_context(state, res.hit, nodes, temperature, n=n)
 
 
 def csr_window(csr_offsets: jax.Array, csr_nodes: jax.Array,
@@ -286,25 +286,35 @@ def csr_window(csr_offsets: jax.Array, csr_nodes: jax.Array,
     return jnp.where(valid, csr_nodes[safe], NULL)           # (B, max_locs)
 
 
+@functools.partial(jax.jit, static_argnames="n")
+def hierarchy_windows(parent: jax.Array, entity_id: jax.Array,
+                      child_offsets: jax.Array, child_index: jax.Array,
+                      nodes: jax.Array, n: int):
+    """Ancestor and descendant windows ``(B, max_locs, n)`` of a location
+    window ``nodes`` ``(B, max_locs)``; NULL locations give NULL windows.
+
+    One program per shape, jitted here at module level so an eager caller
+    compiles it once per ``(nodes shape, forest shape, n)`` and never
+    again; under an enclosing ``jit`` or ``shard_map`` it is a nested
+    call."""
+    flat = nodes.reshape(-1)
+    up = gather_hierarchy(parent, entity_id, flat, n)
+    down = gather_descendants(child_offsets, child_index, entity_id, flat, n)
+    shape = nodes.shape + (n,)
+    return up.reshape(shape), down.reshape(shape)
+
+
 def finish_context(state, hit: jax.Array, nodes: jax.Array,
-                   temperature: jax.Array, max_locs: int = 4,
-                   n: int = 3) -> DeviceRetrieval:
+                   temperature: jax.Array, n: int = 3) -> DeviceRetrieval:
     """Hierarchy windows for an already-gathered location window — the
     forest-walk tail shared by :func:`gather_context` and the sharded
     owner-fused path (which routes ``(hit, locations)`` back through the
     all-to-all and walks the replicated forest locally)."""
-    flat = nodes.reshape(-1)
-    up = gather_hierarchy(state.parent, state.entity_id,
-                          jnp.maximum(flat, 0), n)
-    up = jnp.where(flat[:, None] == NULL, NULL, up)
-    down = gather_descendants(state.child_offsets, state.child_index,
-                              state.entity_id, jnp.maximum(flat, 0), n)
-    down = jnp.where(flat[:, None] == NULL, NULL, down)
-    B = hit.shape[0]
-    return DeviceRetrieval(
-        hit=hit, locations=nodes,
-        up=up.reshape(B, max_locs, n), down=down.reshape(B, max_locs, n),
-        temperature=temperature)
+    up, down = hierarchy_windows(state.parent, state.entity_id,
+                                 state.child_offsets, state.child_index,
+                                 nodes, n=n)
+    return DeviceRetrieval(hit=hit, locations=nodes, up=up, down=down,
+                           temperature=temperature)
 
 
 def build_retriever(trees, num_buckets: int = 1024, **kw) -> CFTRAG:

@@ -80,7 +80,7 @@ def _as_int(row):
 
 def _up_walk(nodes, pe_ref, n, mxu):
     """Ancestor window: ``n`` (1, TILE) rows — mirrors
-    ``gather_hierarchy_unrolled`` on the packed [parent; entity_id]
+    ``core.context.gather_hierarchy`` on the packed [parent; entity_id]
     table."""
     cur = nodes
     outs = []
@@ -97,7 +97,7 @@ def _up_walk(nodes, pe_ref, n, mxu):
 
 def _down_walk(nodes, child_lc_ref, child_index_ref, pe_ref, n, mxu):
     """Descendant window: ``n`` (1, TILE) rows — mirrors
-    ``gather_descendants_unrolled`` on packed tables: child_lc
+    ``core.context.gather_descendants`` on packed tables: child_lc
     [child_lo; child_count], child_index, entity ids from the
     parent/entity table's second row."""
     null_row = jnp.full((1, TILE), NULL, jnp.int32)

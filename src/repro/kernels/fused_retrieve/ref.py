@@ -3,8 +3,9 @@
 Semantically this is ``lookup_arena`` + temperature bump + the CSR location
 window + hierarchy walks — exactly what ``retrieve_device`` followed by
 ``gather_context`` computes — restated in the *fused* dataflow the Pallas
-kernel implements: select-based unrolled walks (static ``n`` steps, no
-``lax.while``/``lax.cond``) and the sentinel-row miss routing, so every
+kernel implements: the sentinel-row miss routing and the core path's
+select-based walks (static ``n`` steps, no ``lax.while``/``lax.cond``,
+``core.context.gather_hierarchy``/``gather_descendants``), so every
 intermediate stays a register-shaped value.  Tests pin this function
 bit-identical to the unfused core path; the kernel is validated against
 both.
@@ -15,64 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.lookup import bump_temperature_arena, lookup_arena
-from ...core.trag import NULL, DeviceRetrieval
-
-
-def gather_hierarchy_unrolled(parent: jax.Array, entity_id: jax.Array,
-                              nodes: jax.Array, n: int) -> jax.Array:
-    """Ancestor entity-id window — unrolled form of
-    ``context.gather_hierarchy`` (bit-identical; the scan becomes ``n``
-    static select+gather steps)."""
-    cur = nodes.astype(jnp.int32)
-    outs = []
-    for _ in range(n):
-        p = jnp.where(cur == NULL, NULL, parent[jnp.maximum(cur, 0)])
-        eid = jnp.where(p == NULL, NULL, entity_id[jnp.maximum(p, 0)])
-        outs.append(eid)
-        cur = p
-    return jnp.stack(outs, axis=1)
-
-
-def gather_descendants_unrolled(child_offsets: jax.Array,
-                                child_index: jax.Array,
-                                entity_id: jax.Array, nodes: jax.Array,
-                                n: int) -> jax.Array:
-    """Descendant entity-id window — unrolled form of
-    ``context.gather_descendants``.  The per-node BFS (vmapped
-    fori_loop + cond in the reference) becomes static select arithmetic:
-    ``cond(valid, push(cur))`` is replaced by ``push(where(valid, cur,
-    NULL))``, identical because a NULL source makes every inner push lane
-    invalid.  This removes the XLA while-loop overhead that dominates the
-    unfused path on CPU."""
-    b = nodes.shape[0]
-    ci = child_index.shape[0]
-    nodes = nodes.astype(jnp.int32)
-    buf = jnp.full((b, n), NULL, jnp.int32)      # BFS frontier ring, cap n
-    w = jnp.zeros((b,), jnp.int32)               # frontier write cursor
-    lane = jnp.arange(n, dtype=jnp.int32)[None, :]
-
-    def push(buf, w, src):
-        s = jnp.maximum(src, 0)
-        lo = child_offsets[s]
-        hi = child_offsets[s + 1]
-        for k in range(n):
-            idx = lo + k
-            valid = (src != NULL) & (idx < hi) & (w < n)
-            c = jnp.where(valid, child_index[jnp.minimum(idx, ci - 1)], NULL)
-            oh = (lane == jnp.minimum(w, n - 1)[:, None]) & valid[:, None]
-            buf = jnp.where(oh, c[:, None], buf)
-            w = jnp.where(valid, w + 1, w)
-        return buf, w
-
-    buf, w = push(buf, w, nodes)
-    out = jnp.full((b, n), NULL, jnp.int32)
-    for i in range(n):
-        cur = buf[:, i]
-        valid = (i < w) & (cur != NULL)
-        out = out.at[:, i].set(
-            jnp.where(valid, entity_id[jnp.maximum(cur, 0)], out[:, i]))
-        buf, w = push(buf, w, jnp.where(valid, cur, NULL))
-    return out
+from ...core.trag import NULL, DeviceRetrieval, hierarchy_windows
 
 
 def fused_retrieve_ref(fingerprints: jax.Array, temperature: jax.Array,
@@ -105,15 +49,7 @@ def fused_retrieve_ref(fingerprints: jax.Array, temperature: jax.Array,
     safe = jnp.clip(idx, 0, csr_nodes.shape[0] - 1)
     nodes = jnp.where(window, csr_nodes[safe], NULL)       # (B, max_locs)
 
-    flat = nodes.reshape(-1)
-    up = gather_hierarchy_unrolled(parent, entity_id,
-                                   jnp.maximum(flat, 0), n)
-    up = jnp.where(flat[:, None] == NULL, NULL, up)
-    down = gather_descendants_unrolled(child_offsets, child_index,
-                                       entity_id, jnp.maximum(flat, 0), n)
-    down = jnp.where(flat[:, None] == NULL, NULL, down)
-    b = res.hit.shape[0]
-    return DeviceRetrieval(hit=res.hit, locations=nodes,
-                           up=up.reshape(b, max_locs, n),
-                           down=down.reshape(b, max_locs, n),
+    up, down = hierarchy_windows(parent, entity_id, child_offsets,
+                                 child_index, nodes, n=n)
+    return DeviceRetrieval(hit=res.hit, locations=nodes, up=up, down=down,
                            temperature=temp)

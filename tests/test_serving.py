@@ -103,6 +103,26 @@ def test_rag_bank_mode_scoped_and_global():
         assert scoped.entities == a.entities
 
 
+def test_repeated_eager_retrieve_compiles_nothing():
+    """After one warm-up call, an eager retrieve with the same entity
+    count (so the same shapes) compiles no program: the hierarchy walks
+    are one module-level jit, not programs built per call."""
+    from repro.obs import finished_spans, get_registry
+    corpus = hospital_corpus(num_trees=6, num_queries=4)
+    rag = RAGPipeline(corpus, None, tokenizer=HashTokenizer(1024),
+                      use_bank=True)
+    names = rag.forest.entity_names
+    first = rag.retrieve(f"Where do {names[5]} and {names[9]} report?")
+    compiles = get_registry().counter("xla.compiles")
+    before = compiles.value()
+    second = rag.retrieve(f"Where do {names[7]} and {names[12]} report?")
+    assert len(first.entities) == len(second.entities) == 2
+    assert second.context
+    assert compiles.value() == before
+    span, = finished_spans("rag.retrieve", 1)
+    assert span["attrs"].get("compile_s", 0.0) == 0.0
+
+
 def test_kv_cache_sizing():
     cfg = get_arch("yi-34b")
     by = kv_cache_bytes(cfg, batch=128, cache_size=32768)
