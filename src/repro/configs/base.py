@@ -38,7 +38,14 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_head_dim: int = 64
     ssm_expand: int = 2
-    attn_every: int = 0         # zamba2: shared attn every k mamba blocks
+    ssm_groups: int = 1         # mamba2 B/C groups (heads h use h // (H/G))
+    # zamba2: a shared attention block runs before the Mamba2 mixer of each
+    # of these layers, blocks taken in turn; a LoRA of this rank and a
+    # linear of its own at each call
+    hybrid_layers: Tuple[int, ...] = ()
+    shared_blocks: int = 0
+    adapter_rank: int = 0
+    norm_eps: float = 1e-6
     # --- encoder-decoder (whisper) ---
     enc_layers: int = 0
     dec_layers: int = 0
@@ -96,8 +103,11 @@ class ModelConfig:
             kw.update(num_experts=4, top_k=min(self.top_k, 2), d_ff=64)
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=16)
-        if self.attn_every:
-            kw.update(attn_every=2, n_layers=4)
+        if self.hybrid_layers:
+            # blocks A, B, A at layers 2, 4, 7: every adapter its own
+            kw.update(n_layers=8, hybrid_layers=(2, 4, 7), d_model=64,
+                      head_dim=2 * 64 // 4, d_ff=128, ssm_head_dim=8,
+                      adapter_rank=8)
         if self.enc_layers:
             kw.update(enc_layers=2, dec_layers=2)
         if self.num_patches:

@@ -49,7 +49,11 @@ def linear_scan_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     tokens.  This is what the models lower for training/prefill: the
     per-token scan round-trips the (Dk x Dv) state through HBM every step
     (measured 3.2e5 s memory term on zamba2 train_4k); chunking cuts state
-    traffic by the chunk length and turns the work into matmuls."""
+    traffic by the chunk length and turns the work into matmuls.
+
+    ``g`` may hold one decay per head, (B, H, L, 1), as Mamba2's does: the
+    intra-chunk scores are then one (C x C) matmul scaled by the decay
+    between positions, with no (C x C x Dk) decay tensor."""
     b, h, l, dk = q.shape
     dv = v.shape[-1]
     pad = (-l) % chunk
@@ -62,7 +66,7 @@ def linear_scan_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
         return t.reshape(b, h, nc, chunk, d).astype(jnp.float32) \
                 .transpose(2, 0, 1, 3, 4)          # (NC, B, H, C, D)
 
-    qc, kc, gc = prep(q, dk), prep(k, dk), prep(g, dk)
+    qc, kc, gc = prep(q, dk), prep(k, dk), prep(g, g.shape[-1])
     vc = prep(v, dv)
     ii = jnp.arange(chunk)[:, None]
     jj = jnp.arange(chunk)[None, :]
@@ -74,9 +78,13 @@ def linear_scan_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
         cq = c if inclusive else c - g_c
         c_last = c[..., -1:, :]                    # (B, H, 1, Dk)
         out = jnp.einsum("bhck,bhkv->bhcv", q_c * jnp.exp(cq), s)
-        pair = jnp.exp(cq[..., :, None, :] - c[..., None, :, :])
-        scores = jnp.einsum("bhik,bhjk,bhijk->bhij", q_c, k_c, pair)
-        scores = jnp.where(mask, scores, 0.0)
+        if g_c.shape[-1] == 1:          # one decay per head
+            diff = jnp.where(mask, cq - jnp.swapaxes(c, -1, -2), -jnp.inf)
+            scores = jnp.einsum("bhik,bhjk->bhij", q_c, k_c) * jnp.exp(diff)
+        else:
+            pair = jnp.exp(cq[..., :, None, :] - c[..., None, :, :])
+            scores = jnp.einsum("bhik,bhjk,bhijk->bhij", q_c, k_c, pair)
+            scores = jnp.where(mask, scores, 0.0)
         out = out + jnp.einsum("bhij,bhjv->bhiv", scores, v_c)
         ke = k_c * jnp.exp(c_last - c)
         s_new = s * jnp.exp(c_last[..., 0, :])[..., None] + \

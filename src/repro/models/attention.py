@@ -28,13 +28,20 @@ from .layers import Params, apply_rope, dense, dense_init
 NEG_INF = -1e30
 
 
-def init_attention(key, cfg: ModelConfig, dtype) -> Params:
+def init_attention(key, cfg: ModelConfig, dtype,
+                   d_in: Optional[int] = None) -> Params:
+    """q/k/v read ``d_in`` channels (default ``d_model``); o writes
+    ``d_model``."""
     d, hd = cfg.d_model, cfg.resolved_head_dim
+    d_in = d_in or d
     ks = jax.random.split(key, 4)
     return {
-        "wq": dense_init(ks[0], d, cfg.n_heads * hd, dtype, bias=cfg.qkv_bias),
-        "wk": dense_init(ks[1], d, cfg.n_kv_heads * hd, dtype, bias=cfg.qkv_bias),
-        "wv": dense_init(ks[2], d, cfg.n_kv_heads * hd, dtype, bias=cfg.qkv_bias),
+        "wq": dense_init(ks[0], d_in, cfg.n_heads * hd, dtype,
+                         bias=cfg.qkv_bias),
+        "wk": dense_init(ks[1], d_in, cfg.n_kv_heads * hd, dtype,
+                         bias=cfg.qkv_bias),
+        "wv": dense_init(ks[2], d_in, cfg.n_kv_heads * hd, dtype,
+                         bias=cfg.qkv_bias),
         "wo": dense_init(ks[3], cfg.n_heads * hd, d, dtype, bias=False),
     }
 
@@ -112,8 +119,9 @@ def _blocked_attn(q, k, v, causal: bool, q_offset: int, scale: float,
     return out.reshape(b, hq, lq, d).astype(q.dtype)
 
 
-def _inner_attention(cfg: ModelConfig, q, k, v, causal: bool, q_offset: int):
-    scale = cfg.resolved_head_dim ** -0.5
+def _inner_attention(cfg: ModelConfig, q, k, v, causal: bool, q_offset: int,
+                     scale: Optional[float] = None):
+    scale = scale or cfg.resolved_head_dim ** -0.5
     if cfg.attn_impl == "reference":
         return _reference_attn(q, k, v, causal, q_offset, scale)
     if cfg.attn_impl == "flash":
@@ -127,8 +135,9 @@ def _inner_attention(cfg: ModelConfig, q, k, v, causal: bool, q_offset: int):
 def attend(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
            causal: bool = True,
            kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,
-           rope: bool = True) -> jax.Array:
-    """Full-sequence attention (train / prefill). x: (B, L, D)."""
+           rope: bool = True, scale: Optional[float] = None) -> jax.Array:
+    """Full-sequence attention (train / prefill). x: (B, L, D_in).
+    ``scale`` overrides the softmax scale ``head_dim ** -0.5``."""
     hd = cfg.resolved_head_dim
     q = _split_heads(dense(p["wq"], x), cfg.n_heads)
     if kv_override is None:
@@ -140,7 +149,7 @@ def attend(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
         q = apply_rope(q, positions, cfg.rope_theta)
         if kv_override is None:
             k = apply_rope(k, positions, cfg.rope_theta)
-    out = _inner_attention(cfg, q, k, v, causal, q_offset=0)
+    out = _inner_attention(cfg, q, k, v, causal, q_offset=0, scale=scale)
     return dense(p["wo"], _merge_heads(out))
 
 
@@ -188,3 +197,59 @@ def decode_attend(cfg: ModelConfig, p: Params, x: jax.Array,
     out = dense(p["wo"], out.reshape(b, 1, -1))
     new_cache = {"k": k, "v": v} if update_cache else cache
     return out, new_cache
+
+
+# Stacked caches (N, S, B, Hkv, W): one per call of a shared block, the
+# sequence major and the head dim padded to W, a multiple of 128 lanes.
+# That is the layout the TPU keeps them in both at the jit boundary and
+# inside the layer loop, so a decode step writes one position in place
+# and never copies a whole cache to change its layout.
+
+def stacked_width(head_dim: int) -> int:
+    return -(-head_dim // 128) * 128
+
+
+def stacked_kv(k: jax.Array, v: jax.Array, cache_size: int
+               ) -> Dict[str, jax.Array]:
+    """(B, Hkv, L, hd) k and v -> one entry of stacked caches,
+    (cache_size, B, Hkv, W), zero beyond L and hd."""
+    def put(t):
+        t = t.transpose(2, 0, 1, 3)
+        return jnp.pad(t, ((0, cache_size - t.shape[0]), (0, 0), (0, 0),
+                           (0, stacked_width(t.shape[-1]) - t.shape[-1])))
+    return {"k": put(k), "v": put(v)}
+
+
+def decode_attend_stacked(cfg: ModelConfig, p: Params, x: jax.Array,
+                          cache: Dict[str, jax.Array], index: jax.Array,
+                          cache_len: jax.Array, scale: Optional[float] = None
+                          ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token decode step into entry ``index`` of stacked caches: only
+    the new position is written, in place.  x: (B, 1, D_in)."""
+    b, hd = x.shape[0], cfg.resolved_head_dim
+    hkv = cfg.n_kv_heads
+    pos = jnp.full((b, 1), cache_len, jnp.int32)
+
+    def heads(w, n):
+        # split in float32: a bfloat16 split into heads of a width that is
+        # not a multiple of 128 makes the compiler copy the whole weight
+        # into a transposed layout every step
+        return dense(w, x[:, 0]).astype(jnp.float32).reshape(b, n, 1, hd)
+    q = apply_rope(heads(p["wq"], cfg.n_heads), pos,
+                   cfg.rope_theta)[:, :, 0]                  # (B, Hq, hd)
+    k = apply_rope(heads(p["wk"], hkv), pos, cfg.rope_theta)
+    v = heads(p["wv"], hkv)
+    new = stacked_kv(k, v, 1)                                # (1, B, Hkv, W)
+    zero = jnp.int32(0)
+    cache = {n: jax.lax.dynamic_update_slice(
+        cache[n], new[n][None].astype(cache[n].dtype),
+        (index, cache_len, zero, zero, zero)) for n in ("k", "v")}
+    w = cache["k"].shape[-1]
+    qg = jnp.pad(q.reshape(b, hkv, -1, hd), ((0, 0),) * 3 + ((0, w - hd),))
+    s = jnp.einsum("bkgd,sbkd->bkgs", qg, cache["k"][index],
+                   preferred_element_type=jnp.float32) * (scale or hd ** -0.5)
+    s = jnp.where(jnp.arange(s.shape[-1]) <= cache_len, s, NEG_INF)
+    out = jnp.einsum("bkgs,sbkd->bkgd", jax.nn.softmax(s, axis=-1),
+                     cache["v"][index], preferred_element_type=jnp.float32)
+    out = out[..., :hd].reshape(b, 1, -1).astype(x.dtype)
+    return dense(p["wo"], out), cache

@@ -4,8 +4,9 @@ Families:
   dense / vlm     — decoder-only GQA transformer (+ optional early-fusion
                     patch embeddings, frontend STUB)
   moe             — dense attention + (interleaved) MoE FFN
-  mamba_hybrid    — zamba2: mamba2 backbone, weight-SHARED attention block
-                    every ``attn_every`` layers (one param set, many caches)
+  mamba_hybrid    — zamba2: mamba2 backbone, two weight-SHARED attention
+                    blocks called at the listed hybrid layers (one KV cache,
+                    LoRA and output linear per call)
   rwkv            — RWKV6 time-mix + channel-mix
   encdec          — whisper: bidirectional encoder (stub audio frames) +
                     causal decoder with cross-attention
@@ -271,163 +272,196 @@ def decoder_decode(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
 
 # =====================================================================
-# zamba2: mamba backbone + shared attention block
+# zamba2: mamba2 backbone + shared attention blocks
 # =====================================================================
+#
+# Each layer is a Mamba2 layer, ``h <- h + mamba(norm(h + t))``.  At the
+# layers of ``cfg.hybrid_layers``, ``t`` is the output of a shared-block
+# call (zero elsewhere): call j runs block ``j % shared_blocks`` on
+# ``concat[h, e]`` (``e`` the token embedding) with call j's own MLP
+# adapter and output linear, and keeps its own KV cache.  One scan runs
+# every layer; a ``lax.cond`` on the layer's call index makes the call.
 
-def _zamba_layout(cfg: ModelConfig) -> Tuple[int, int, int]:
-    groups = cfg.n_layers // cfg.attn_every
-    tail = cfg.n_layers - groups * cfg.attn_every
-    return groups, cfg.attn_every, tail
+def _zamba_calls(cfg: ModelConfig) -> jax.Array:
+    """Per layer: the index of the shared-block call before its mixer,
+    or -1."""
+    calls = [-1] * cfg.n_layers
+    for j, layer in enumerate(cfg.hybrid_layers):
+        calls[layer] = j
+    return jnp.asarray(calls, jnp.int32)
 
 
 def init_zamba_params(cfg: ModelConfig, key) -> Params:
     dtype = _dtype(cfg)
+    d, ff, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
     ks = jax.random.split(key, 6)
-    groups, per, tail = _zamba_layout(cfg)
-    shared = {"ln1": rmsnorm_init(cfg.d_model, dtype),
-              "attn": attn.init_attention(ks[0], cfg, dtype),
-              "ln2": rmsnorm_init(cfg.d_model, dtype),
-              "mlp": _init_mlp(ks[1], cfg, dtype)}
-    mamba_init = lambda k: {"ln": rmsnorm_init(cfg.d_model, dtype),
-                            "mamba": m2.init_mamba2(k, cfg, dtype)}
-    p = {"embed": embed_init(ks[2], cfg.padded_vocab, cfg.d_model, dtype),
-         "final_norm": rmsnorm_init(cfg.d_model, dtype),
-         "lm_head": dense_init(ks[3], cfg.d_model, cfg.padded_vocab, dtype),
-         "shared": shared,
-         "groups": _stack_init(ks[4], groups,
-                               lambda k: _stack_init(k, per, mamba_init))}
-    if tail:
-        p["tail"] = _stack_init(ks[5], tail, mamba_init)
+    block = lambda k: {
+        "ln_in": rmsnorm_init(2 * d, dtype),
+        "attn": attn.init_attention(jax.random.fold_in(k, 0), cfg, dtype,
+                                    d_in=2 * d),
+        "ln_ff": rmsnorm_init(d, dtype),
+        "gate_up": dense_init(jax.random.fold_in(k, 1), d, 2 * ff, dtype),
+        "down": dense_init(jax.random.fold_in(k, 2), ff, d, dtype)}
+    call = lambda k: {
+        "adapter_in": dense_init(jax.random.fold_in(k, 0), d, r, dtype),
+        "adapter_out": dense_init(jax.random.fold_in(k, 1), r, 2 * ff,
+                                  dtype),
+        "linear": dense_init(jax.random.fold_in(k, 2), d, d, dtype)}
+    layer = lambda k: {"ln": rmsnorm_init(d, dtype),
+                       "mamba": m2.init_mamba2(k, cfg, dtype)}
+    p = {"embed": embed_init(ks[0], cfg.padded_vocab, d, dtype),
+         "final_norm": rmsnorm_init(d, dtype),
+         "layers": _stack_init(ks[1], cfg.n_layers, layer),
+         "shared": _stack_init(ks[2], cfg.shared_blocks, block),
+         "calls": _stack_init(ks[3], len(cfg.hybrid_layers), call)}
+    if not cfg.tie_embeddings:
+        p["lm_head"] = dense_init(ks[4], d, cfg.padded_vocab, dtype)
     return p
 
 
-def _mamba_block(cfg, lp, x):
-    return x + m2.mamba2_forward(cfg, lp["mamba"], rmsnorm(lp["ln"], x))
+def _zamba_call_weights(cfg: ModelConfig, params: Params, j):
+    """Call ``j``'s block (taken in turn) and its own adapter and linear."""
+    sp = jax.tree.map(lambda w: w[j % cfg.shared_blocks], params["shared"])
+    cp = jax.tree.map(lambda w: w[j], params["calls"])
+    return sp, cp
 
 
-def _shared_attn_block(cfg, sp, x, positions):
-    x = x + attn.attend(cfg, sp["attn"], rmsnorm(sp["ln1"], x), positions)
-    return x + _mlp(sp["mlp"], rmsnorm(sp["ln2"], x))
+def _zamba_block_in(cfg: ModelConfig, sp: Params, h, e):
+    return rmsnorm(sp["ln_in"], jnp.concatenate([h, e], axis=-1),
+                   cfg.norm_eps)
+
+
+def _zamba_block_out(cfg: ModelConfig, sp: Params, cp: Params, a):
+    """From the attention output: RMSNorm, the GELU MLP whose gate/up
+    projection adds the call's LoRA, then the call's linear."""
+    x = rmsnorm(sp["ln_ff"], a, cfg.norm_eps)
+    gu = dense(sp["gate_up"], x) + dense(cp["adapter_out"],
+                                         dense(cp["adapter_in"], x))
+    g, u = jnp.split(gu, 2, axis=-1)
+    g = jax.nn.gelu(g.astype(jnp.float32), approximate=False)
+    return dense(cp["linear"], dense(sp["down"], g.astype(u.dtype) * u))
+
+
+def _zamba_scale(cfg: ModelConfig) -> float:
+    return (cfg.resolved_head_dim / 2) ** -0.5
+
+
+def _zamba_mixer_in(cfg: ModelConfig, lp: Params, h, t):
+    return rmsnorm(lp["ln"], h + t, cfg.norm_eps)
 
 
 def zamba_forward(cfg: ModelConfig, params: Params, tokens: jax.Array
                   ) -> jax.Array:
-    x = params["embed"][tokens]
-    b, l, _ = x.shape
+    e = params["embed"][tokens]
+    b, l, _ = e.shape
     positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None], (b, l))
-    groups, per, tail = _zamba_layout(cfg)
-    shared = params["shared"]
 
-    def group_body(xc, gp):
-        xc = runtime.constrain_batch(xc)
-        def mamba_body(xi, lp):
-            return _mamba_block(cfg, lp, runtime.constrain_batch(xi)), None
-        xc, _ = jax.lax.scan(mamba_body, xc, gp)
-        xc = _shared_attn_block(cfg, shared, xc, positions)
-        return xc, None
+    def call(h, j):
+        with jax.named_scope("zamba2.shared"):
+            sp, cp = _zamba_call_weights(cfg, params, j)
+            a = attn.attend(cfg, sp["attn"], _zamba_block_in(cfg, sp, h, e),
+                            positions, scale=_zamba_scale(cfg))
+            return _zamba_block_out(cfg, sp, cp, a)
 
-    x, _ = jax.lax.scan(_remat(cfg, group_body), x, params["groups"])
-    if tail:
-        def mamba_body(xi, lp):
-            return _mamba_block(cfg, lp, runtime.constrain_batch(xi)), None
-        x, _ = jax.lax.scan(_remat(cfg, mamba_body), x, params["tail"])
-    x = rmsnorm(params["final_norm"], x)
-    return _logits(cfg, params, x)
+    def body(h, inp):
+        lp, j = inp
+        h = runtime.constrain_batch(h)
+        t = jax.lax.cond(j >= 0, call, lambda h, j: jnp.zeros_like(h), h, j)
+        with jax.named_scope("zamba2.ssm"):
+            y = m2.mamba2_forward(cfg, lp["mamba"],
+                                  _zamba_mixer_in(cfg, lp, h, t))
+        return h + y, None
+
+    h, _ = jax.lax.scan(_remat(cfg, body), e,
+                        (params["layers"], _zamba_calls(cfg)))
+    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return _logits(cfg, params, h)
 
 
 def zamba_init_state(cfg: ModelConfig, batch: int, cache_size: int,
                      dtype) -> Dict[str, Any]:
-    groups, per, tail = _zamba_layout(cfg)
-    hd = cfg.resolved_head_dim
-    mk_mamba = lambda n: jax.tree.map(
-        lambda t: jnp.broadcast_to(t, (n,) + t.shape),
+    """KV caches per shared-block call (``attention.stacked_kv``'s
+    layout), conv and SSM state per layer."""
+    kv = (len(cfg.hybrid_layers), cache_size, batch, cfg.n_kv_heads,
+          attn.stacked_width(cfg.resolved_head_dim))
+    mamba = jax.tree.map(
+        lambda t: jnp.broadcast_to(t, (cfg.n_layers,) + t.shape),
         m2.mamba2_init_cache(cfg, batch, dtype))
-    attn_cache = {
-        "k": jnp.zeros((groups, batch, cfg.n_kv_heads, cache_size, hd), dtype),
-        "v": jnp.zeros((groups, batch, cfg.n_kv_heads, cache_size, hd), dtype),
-    }
-    st = {"groups_mamba": jax.tree.map(
-              lambda t: jnp.broadcast_to(t, (groups,) + t.shape),
-              mk_mamba(per)),
-          "attn": attn_cache, "len": jnp.int32(0)}
-    if tail:
-        st["tail_mamba"] = mk_mamba(tail)
-    return st
+    return {"kv": {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)},
+            "mamba": mamba, "len": jnp.int32(0)}
 
 
 def zamba_prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   cache_size: int) -> Tuple[jax.Array, Dict[str, Any]]:
-    """Full-sequence hybrid prefill: chunked-scan mamba blocks with state
-    collection + shared-attention kv capture (replaces the sequential
-    token-by-token fallback, which cost 32768 serial steps)."""
-    x = params["embed"][tokens]
-    b, l, _ = x.shape
+    """Full-sequence hybrid prefill: chunked-scan mamba layers that keep
+    their final state, and each call's KV written into its cache."""
+    e = params["embed"][tokens]
+    b, l, _ = e.shape
     positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None], (b, l))
-    groups, per, tail = _zamba_layout(cfg)
-    shared = params["shared"]
+    kv0 = zamba_init_state(cfg, b, cache_size, e.dtype)["kv"]
 
-    def mamba_step(xi, lp):
-        h, cache = m2.mamba2_forward(cfg, lp["mamba"],
-                                     rmsnorm(lp["ln"], xi), collect=True)
-        return xi + h, cache
+    def call(h, j, kv):
+        with jax.named_scope("zamba2.shared"):
+            sp, cp = _zamba_call_weights(cfg, params, j)
+            xin = _zamba_block_in(cfg, sp, h, e)
+            k, v = (attn.prefill_kv(cfg, sp["attn"], xin, positions, l)[n]
+                    for n in ("k", "v"))
+            new = attn.stacked_kv(k, v, cache_size)
+            kv = {n: jax.lax.dynamic_update_index_in_dim(kv[n], new[n], j, 0)
+                  for n in ("k", "v")}
+            a = attn.attend(cfg, sp["attn"], xin, positions,
+                            kv_override=(k, v), scale=_zamba_scale(cfg))
+            return _zamba_block_out(cfg, sp, cp, a), kv
 
-    def group_body(xc, gp):
-        xc = runtime.constrain_batch(xc)
-        xc, mcaches = jax.lax.scan(mamba_step, xc, gp)
-        xin = rmsnorm(shared["ln1"], xc)
-        kv = attn.prefill_kv(cfg, shared["attn"], xin, positions, cache_size)
-        xc = xc + attn.attend(cfg, shared["attn"], xin, positions)
-        xc = xc + _mlp(shared["mlp"], rmsnorm(shared["ln2"], xc))
-        return xc, (mcaches, kv)
+    def body(carry, inp):
+        h, kv = carry
+        lp, j = inp
+        t, kv = jax.lax.cond(j >= 0, call,
+                             lambda h, j, kv: (jnp.zeros_like(h), kv),
+                             h, j, kv)
+        with jax.named_scope("zamba2.ssm"):
+            y, mc = m2.mamba2_forward(cfg, lp["mamba"],
+                                      _zamba_mixer_in(cfg, lp, h, t),
+                                      collect=True)
+        return (h + y, kv), mc
 
-    x, (gm, attn_c) = jax.lax.scan(_remat(cfg, group_body), x,
-                                   params["groups"])
-    state = {"groups_mamba": gm, "attn": attn_c, "len": jnp.int32(l)}
-    if tail:
-        x, tm = jax.lax.scan(mamba_step, x, params["tail"])
-        state["tail_mamba"] = tm
-    x = rmsnorm(params["final_norm"], x[:, -1:])
-    return _logits(cfg, params, x), state
+    (h, kv), mamba = jax.lax.scan(_remat(cfg, body), (e, kv0),
+                                  (params["layers"], _zamba_calls(cfg)))
+    h = rmsnorm(params["final_norm"], h[:, -1:], cfg.norm_eps)
+    state = {"kv": kv, "mamba": mamba, "len": jnp.int32(l)}
+    return _logits(cfg, params, h), state
 
 
 def zamba_decode(cfg: ModelConfig, params: Params, tokens: jax.Array,
                  state: Dict[str, Any]) -> Tuple[jax.Array, Dict[str, Any]]:
-    x = params["embed"][tokens]
+    e = params["embed"][tokens]
     cache_len = state["len"]
-    groups, per, tail = _zamba_layout(cfg)
-    shared = params["shared"]
 
-    def group_body(xc, inp):
-        gp, mcache, acache = inp
+    def call(h, j, kv):
+        with jax.named_scope("zamba2.shared"):
+            sp, cp = _zamba_call_weights(cfg, params, j)
+            a, kv = attn.decode_attend_stacked(
+                cfg, sp["attn"], _zamba_block_in(cfg, sp, h, e), kv, j,
+                cache_len, scale=_zamba_scale(cfg))
+            return _zamba_block_out(cfg, sp, cp, a), kv
 
-        def mamba_step(xi, minp):
-            lp, mc = minp
-            h, nc = m2.mamba2_decode(cfg, lp["mamba"],
-                                     rmsnorm(lp["ln"], xi), mc)
-            return xi + h, nc
-        xc, new_mcache = jax.lax.scan(mamba_step, xc, (gp, mcache))
-        h, new_acache = attn.decode_attend(
-            cfg, shared["attn"], rmsnorm(shared["ln1"], xc), acache, cache_len)
-        xc = xc + h
-        xc = xc + _mlp(shared["mlp"], rmsnorm(shared["ln2"], xc))
-        return xc, (new_mcache, new_acache)
+    def body(carry, inp):
+        h, kv = carry
+        lp, j, mc = inp
+        t, kv = jax.lax.cond(j >= 0, call,
+                             lambda h, j, kv: (jnp.zeros_like(h), kv),
+                             h, j, kv)
+        with jax.named_scope("zamba2.ssm"):
+            y, mc = m2.mamba2_decode(cfg, lp["mamba"],
+                                     _zamba_mixer_in(cfg, lp, h, t), mc)
+        return (h + y, kv), mc
 
-    x, (new_gm, new_attn) = jax.lax.scan(
-        group_body, x,
-        (params["groups"], state["groups_mamba"], state["attn"]))
-    new_state = {"groups_mamba": new_gm, "attn": new_attn,
-                 "len": cache_len + 1}
-    if tail:
-        def mamba_step(xi, minp):
-            lp, mc = minp
-            h, nc = m2.mamba2_decode(cfg, lp["mamba"],
-                                     rmsnorm(lp["ln"], xi), mc)
-            return xi + h, nc
-        x, new_tail = jax.lax.scan(mamba_step, x,
-                                   (params["tail"], state["tail_mamba"]))
-        new_state["tail_mamba"] = new_tail
-    x = rmsnorm(params["final_norm"], x)
-    return _logits(cfg, params, x), new_state
+    (h, kv), mamba = jax.lax.scan(
+        body, (e, state["kv"]),
+        (params["layers"], _zamba_calls(cfg), state["mamba"]))
+    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return _logits(cfg, params, h), {"kv": kv, "mamba": mamba,
+                                     "len": cache_len + 1}
 
 
 # =====================================================================

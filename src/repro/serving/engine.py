@@ -24,6 +24,7 @@ from ..core import (CFTDeviceState, DeviceRetrieval, MaintenanceEngine,
 from ..core.maintenance import RestageCoordinator
 from ..data.tokenizer import HashTokenizer
 from ..models import lm
+from ..models.attention import stacked_width
 from ..obs import RecompileSentinel, Tracer, get_registry, state_shapes
 
 
@@ -446,6 +447,10 @@ class ServeEngine:
             functools.partial(lm.decode_step, cfg), donate_argnums=(2,))
         self.retrieval = RetrievalSession()
         self.tracer = Tracer()
+        gauge = get_registry().gauge(
+            "serve.state_bytes", "one sequence's decode state at the cache")
+        for kind, n in decode_state_bytes(cfg, cache_size).items():
+            gauge.set(n, kind=kind)
 
     # engine-internal views of the session (kept for callers that poke
     # the state directly, e.g. the benches' equivalence gates)
@@ -553,11 +558,41 @@ class ServeEngine:
 
 
 def kv_cache_bytes(cfg: ModelConfig, batch: int, cache_size: int) -> int:
-    """Sizing helper (used by roofline + admission control)."""
+    """Sizing helper (used by roofline + admission control): the decode
+    state's bytes.  The hybrid holds a KV cache per shared-block call,
+    its heads padded to whole 128-lane rows as the TPU holds them
+    (``attention.stacked_width``), and conv and SSM state per layer."""
     hd = cfg.resolved_head_dim
     bpe = 2 if cfg.dtype == "bfloat16" else 4
     if cfg.family == "rwkv":
         return cfg.n_layers * batch * cfg.n_heads * hd * hd * 4
-    layers = cfg.n_layers if cfg.family != "mamba_hybrid" \
-        else cfg.n_layers // max(cfg.attn_every, 1)
-    return 2 * layers * batch * cfg.n_kv_heads * cache_size * hd * bpe
+    if cfg.family == "mamba_hybrid":
+        conv = (cfg.ssm_conv - 1) * (cfg.d_inner + 2 * cfg.ssm_groups
+                                     * cfg.ssm_state) * bpe
+        ssm = cfg.ssm_heads * cfg.ssm_state * cfg.ssm_head_dim * 4
+        kv = 2 * len(cfg.hybrid_layers) * cfg.n_kv_heads * cache_size \
+            * stacked_width(hd) * bpe
+        return batch * (kv + cfg.n_layers * (conv + ssm))
+    return 2 * cfg.n_layers * batch * cfg.n_kv_heads * cache_size * hd * bpe
+
+
+# decode-state leaves by kind: attention caches, recurrent state, and the
+# short input windows of convolutions and token shifts
+STATE_KINDS = {"k": "kv", "v": "kv", "ssm": "ssm", "wkv": "ssm",
+               "conv": "conv", "time_x": "conv", "chan_x": "conv"}
+
+
+def decode_state_bytes(cfg: ModelConfig, cache_size: int) -> Dict[str, int]:
+    """Bytes of one sequence's decode state by kind (``kv``, ``ssm``,
+    ``conv``), from the shapes ``lm.init_decode_state`` gives: nothing
+    is allocated."""
+    if cfg.family == "encdec":                 # its state needs the audio
+        return {}
+    shapes = jax.eval_shape(
+        lambda: lm.init_decode_state(cfg, None, 1, cache_size))
+    out: Dict[str, int] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        kind = STATE_KINDS.get(getattr(path[-1], "key", None))
+        if kind:
+            out[kind] = out.get(kind, 0) + leaf.size * leaf.dtype.itemsize
+    return out

@@ -12,6 +12,7 @@ from repro.kernels.decode_attention import (combine_partial_attention,
                                             decode_attention_ref)
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.linear_scan import linear_scan, linear_scan_ref
+from repro.kernels.linear_scan.ref import linear_scan_chunked
 
 RNG = np.random.default_rng(42)
 
@@ -135,6 +136,27 @@ def test_linear_scan_sweep(b, h, l, dk, dv, inclusive, decay_scale):
     out_r, s_r = linear_scan_ref(q, k, v, g, s0, inclusive=inclusive)
     np.testing.assert_allclose(out_k, out_r, atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(s_k, s_r, atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("inclusive", [True, False])
+@pytest.mark.parametrize("decay_scale", [0.05, 8.0])
+def test_linear_scan_chunked_per_head_decay(inclusive, decay_scale):
+    """One decay per head, (B, H, L, 1) as Mamba2's: the chunked form's
+    matmul path equals the sequential reference with the decay broadcast
+    over the key width."""
+    b, h, l, dk, dv = 2, 3, 150, 16, 8
+    q = jnp.asarray(RNG.normal(size=(b, h, l, dk)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(b, h, l, dk)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(b, h, l, dv)), jnp.float32)
+    g = jnp.asarray(-np.abs(RNG.normal(size=(b, h, l, 1))) * decay_scale,
+                    jnp.float32)
+    s0 = jnp.asarray(RNG.normal(size=(b, h, dk, dv)), jnp.float32)
+    out_c, s_c = linear_scan_chunked(q, k, v, g, s0, inclusive=inclusive,
+                                     chunk=64)
+    out_r, s_r = linear_scan_ref(q, k, v, jnp.broadcast_to(g, q.shape), s0,
+                                 inclusive=inclusive)
+    np.testing.assert_allclose(out_c, out_r, atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(s_c, s_r, atol=2e-4, rtol=2e-4)
 
 
 def test_linear_scan_bf16():

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_arch
 from repro.data import HashTokenizer, hospital_corpus
@@ -128,3 +129,32 @@ def test_kv_cache_sizing():
     by = kv_cache_bytes(cfg, batch=128, cache_size=32768)
     # 2 * 60L * 128B * 8kv * 32768 * 128hd * 2bytes
     assert by == 2 * 60 * 128 * 8 * 32768 * 128 * 2
+
+
+@pytest.mark.parametrize("arch", ["zamba2-7b", "qwen2-0.5b"])
+def test_kv_cache_bytes_match_the_decode_state(arch):
+    """The sizing helper counts what ``init_decode_state`` holds: for the
+    hybrid, a KV cache per shared-block call (224-wide heads) and conv
+    and SSM state per layer; the engine's gauge holds one sequence's."""
+    from repro.models import lm
+    from repro.obs import get_registry
+    from repro.serving.engine import decode_state_bytes
+    cfg = get_arch(arch).replace(n_layers=24, hybrid_layers=(6, 11, 17, 23)) \
+        if arch == "zamba2-7b" else get_arch(arch)
+    shapes = jax.eval_shape(lambda: lm.init_decode_state(cfg, None, 4, 2048))
+    held = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(shapes)
+               if l.ndim)
+    assert kv_cache_bytes(cfg, batch=4, cache_size=2048) == held
+    kinds = decode_state_bytes(cfg, 2048)
+    assert sum(kinds.values()) * 4 == held
+    if arch == "zamba2-7b":
+        assert set(kinds) == {"kv", "ssm", "conv"}
+        # 4 calls x (k, v) x 32 heads x 2048 x 224 in bf16, a sequence,
+        # each head's 224 lanes held in two whole rows of 128
+        assert kinds["kv"] == 4 * 2 * 32 * 2048 * 256 * 2
+        # 24 layers x 112 heads x 64 x 64 in fp32
+        assert kinds["ssm"] == 24 * 112 * 64 * 64 * 4
+        ServeEngine(cfg.smoke(), None, cache_size=64)
+        gauge = get_registry().gauge("serve.state_bytes")
+        small = decode_state_bytes(cfg.smoke(), 64)
+        assert {k: gauge.value(kind=k) for k in small} == small
