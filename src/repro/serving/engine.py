@@ -1,11 +1,16 @@
 """Serving engine: jitted prefill + decode loop with a continuous-lite
 batch scheduler.
 
-The decode step donates the cache/state buffers (no double-buffered KV), and
-greedy sampling runs on device.  The scheduler packs pending requests into
-fixed-size batches (padding short prompts) — the "continuous-lite" policy:
-new requests join at the next batch boundary rather than mid-flight, which
-keeps the step function shape-stable (one compilation per batch geometry).
+An answer is two device programs: the prefill, which ends in the first
+greedy token, and the decode loop, which runs every further step of the
+answer on the device (``lax.fori_loop`` over ``lm.decode_step`` and the
+argmax) and writes each token into a device buffer.  The loop donates
+the cache/state buffers (no double-buffered KV) and its trip count is a
+traced scalar, so it compiles once per batch geometry whatever the
+answer length.  The scheduler packs pending requests into fixed-size
+batches (padding short prompts) — the "continuous-lite" policy: new
+requests join at the next batch boundary rather than mid-flight, which
+keeps both programs shape-stable.
 """
 from __future__ import annotations
 
@@ -442,11 +447,15 @@ class ServeEngine:
         self.batch_size = batch_size
 
         self._prefill = jax.jit(
-            functools.partial(lm.prefill, cfg, cache_size=cache_size))
+            functools.partial(_prefill_token, cfg, cache_size))
         self._decode = jax.jit(
-            functools.partial(lm.decode_step, cfg), donate_argnums=(2,))
+            functools.partial(_decode_loop, cfg, cache_size),
+            donate_argnums=(2,))
         self.retrieval = RetrievalSession()
         self.tracer = Tracer()
+        self._c_dispatches = self.tracer.registry.counter(
+            "serve.decode_dispatches",
+            "decode programs launched: one an answer of two or more tokens")
         gauge = get_registry().gauge(
             "serve.state_bytes", "one sequence's decode state at the cache")
         for kind, n in decode_state_bytes(cfg, cache_size).items():
@@ -515,17 +524,27 @@ class ServeEngine:
     # ----------------------------------------------------------- generate
     def generate(self, batch: Dict[str, jax.Array], max_new_tokens: int
                  ) -> np.ndarray:
-        """Greedy generation. batch['tokens']: (B, S) prompt ids."""
-        with self.tracer.span("serve.generate", steps=max_new_tokens) as sp:
+        """Greedy generation. batch['tokens']: (B, S) prompt ids.
+
+        Two dispatches an answer: the prefill program returns the first
+        token, which the host copies; then, for ``max_new_tokens > 1``,
+        one decode program runs the other ``max_new_tokens - 1`` steps
+        on the device and the host copies their tokens once."""
+        n_steps = max_new_tokens - 1
+        if n_steps > self.cache_size:
+            raise ValueError(f"{max_new_tokens} new tokens do not fit a "
+                             f"cache of {self.cache_size}")
+        with self.tracer.span("serve.generate", steps=max_new_tokens,
+                              dispatches=int(n_steps > 0)) as sp:
             with sp.stage("prefill"):          # to the first token on host
-                logits, state = self._prefill(self.params, batch)
-                tok = lm.greedy_token(logits)
+                tok, state = self._prefill(self.params, batch)
                 out = [np.asarray(tok)]
-            with sp.stage("decode"):           # each step syncs its token
-                for _ in range(max_new_tokens - 1):
-                    logits, state = self._decode(self.params, tok, state)
-                    tok = lm.greedy_token(logits)
-                    out.append(np.asarray(tok))
+            with sp.stage("decode"):           # one program, one copy
+                if n_steps > 0:
+                    toks, _ = self._decode(self.params, tok, state,
+                                           np.int32(n_steps))
+                    out.append(np.asarray(toks)[:, :n_steps])
+                    self._c_dispatches.inc()
         return np.concatenate(out, axis=1)            # (B, new)
 
     # ---------------------------------------------------------- scheduler
@@ -555,6 +574,31 @@ class ServeEngine:
                 self.maintain()    # idle window between batches: apply
                 #                    pending deltas, resort, restage
         return done
+
+
+def _prefill_token(cfg: ModelConfig, cache_size: int, params,
+                   batch: Dict[str, jax.Array]):
+    """The prompt's forward pass to its first greedy token and the
+    decode state."""
+    logits, state = lm.prefill(cfg, params, batch, cache_size)
+    return lm.greedy_token(logits), state
+
+
+def _decode_loop(cfg: ModelConfig, cache_size: int, params, tok: jax.Array,
+                 state, n_steps: jax.Array):
+    """``n_steps`` greedy decode steps from ``tok`` (B, 1), on the device:
+    step ``i``'s token lands in column ``i`` of a (B, cache_size) int32
+    buffer.  Returns the buffer and the final state (which aliases the
+    donated one)."""
+    def step(i, carry):
+        tok, state, toks = carry
+        logits, state = lm.decode_step(cfg, params, tok, state)
+        tok = lm.greedy_token(logits)
+        return tok, state, jax.lax.dynamic_update_slice(toks, tok, (0, i))
+
+    toks = jnp.zeros((tok.shape[0], cache_size), jnp.int32)
+    _, state, toks = jax.lax.fori_loop(0, n_steps, step, (tok, state, toks))
+    return toks, state
 
 
 def kv_cache_bytes(cfg: ModelConfig, batch: int, cache_size: int) -> int:

@@ -158,3 +158,62 @@ def test_kv_cache_bytes_match_the_decode_state(arch):
         gauge = get_registry().gauge("serve.state_bytes")
         small = decode_state_bytes(cfg.smoke(), 64)
         assert {k: gauge.value(kind=k) for k in small} == small
+
+
+def _stepwise(cfg, params, toks, cache, max_new):
+    """Greedy tokens one step at a time: the prefill, then a dispatch of
+    ``decode_step`` and ``greedy_token`` per step, each token on the
+    host before the next step."""
+    from repro.models import lm
+    logits, state = jax.jit(lambda p, b: lm.prefill(cfg, p, b, cache))(
+        params, {"tokens": toks})
+    step = jax.jit(lambda p, t, s: lm.decode_step(cfg, p, t, s))
+    tok = lm.greedy_token(logits)
+    out = [np.asarray(tok)]
+    for _ in range(max_new - 1):
+        logits, state = step(params, tok, state)
+        tok = lm.greedy_token(logits)
+        out.append(np.asarray(tok))
+    return np.concatenate(out, axis=1)
+
+
+@pytest.mark.parametrize("max_new", [1, 2, 7])
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "zamba2-7b", "rwkv6-1.6b"])
+def test_generate_matches_stepwise_decode(arch, max_new):
+    """The on-device decode loop serves, token for token, what the
+    step-by-step loop serves: the same greedy argmax over the same
+    ``decode_step``, the same number of steps."""
+    cfg = get_arch(arch).smoke()
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    toks = jnp.asarray(np.random.default_rng(2).integers(
+        4, cfg.vocab, (2, 12)), jnp.int32)
+    eng = ServeEngine(cfg, params, cache_size=32, batch_size=2)
+    out = eng.generate({"tokens": toks}, max_new_tokens=max_new)
+    assert out.shape == (2, max_new) and out.dtype == np.int32
+    np.testing.assert_array_equal(
+        out, _stepwise(cfg, params, toks, 32, max_new))
+
+
+def test_decode_loop_compiles_once_and_dispatches_once_an_answer():
+    """After the first answer, answers of other lengths at the same
+    shapes compile nothing (the trip count is traced), and each answer
+    of two or more tokens launches one decode program."""
+    from repro.obs import finished_spans, get_registry
+    cfg, eng = _engine(cache=32)
+    toks = jnp.asarray(np.random.default_rng(3).integers(
+        4, cfg.vocab, (2, 10)), jnp.int32)
+    eng.generate({"tokens": toks}, max_new_tokens=3)
+    reg = get_registry()
+    compiles = reg.counter("xla.compiles")
+    dispatches = reg.counter("serve.decode_dispatches")
+    for n in (9, 1, 2, 17):
+        c0, d0 = compiles.value(), dispatches.value()
+        assert eng.generate({"tokens": toks}, n).shape == (2, n)
+        assert compiles.value() == c0
+        assert dispatches.value() == d0 + (n > 1)
+        span, = finished_spans("serve.generate", 1)
+        assert span["attrs"]["steps"] == n
+        assert span["attrs"]["dispatches"] == int(n > 1)
+        assert [s["stage"] for s in span["stages"]] == ["prefill", "decode"]
+    with pytest.raises(ValueError):
+        eng.generate({"tokens": toks}, 34)

@@ -121,3 +121,67 @@ def test_paper_generator_decode_step_compiles(one_chip):
         params, tok, state).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16 * 1024 ** 3      # one v5e's HBM
+
+
+def _loop_copy_bytes(hlo: str):
+    """Bytes of each ``copy`` op inside the entry's while loops, in the
+    loop bodies and every computation they call."""
+    import re
+    import numpy as np
+    comps, entry, name = {}, None, None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            head = line.split()
+            name = (head[1] if head[0] == "ENTRY" else head[0]).lstrip("%")
+            entry = name if head[0] == "ENTRY" else entry
+            comps[name] = []
+        elif name and line.startswith(" "):
+            comps[name].append(line)
+    seen = set()
+    todo = [m for ins in comps[entry] if " while(" in ins
+            for m in re.findall(r"body=%?([\w.\-]+)", ins)]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for ins in comps[c]:
+            todo += re.findall(
+                r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)", ins)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", ins):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    out = []
+    for c in seen:
+        for ins in comps[c]:
+            m = re.search(r"= [a-z]+(\d*)\[([\d,]*)\]\S* copy(?:-done)?\(",
+                          ins)
+            if m:                                # bf16 -> 2 bytes, pred 1
+                dims = [int(d) for d in m.group(2).split(",") if d]
+                out.append(int(np.prod(dims)) * max(int(m.group(1) or 8) // 8,
+                                                    1))
+    return seen, out
+
+
+def test_zamba2_decode_loop_keeps_state_and_weights_in_place(one_chip):
+    """The serving engine's decode loop for Zamba2-7B's first pipeline
+    stage at published widths (24 layers, bf16, batch 4, a 2,048-slot
+    cache): every step runs inside one while loop, and nothing in it
+    copies a KV cache, the SSM or conv state, or a weight; what copies
+    remain are a step's activations and small vector prefetches."""
+    from repro.configs import get_arch
+    from repro.models import abstract_params, init_decode_state
+    from repro.serving.engine import _decode_loop
+    cfg = get_arch("zamba2-7b").replace(n_layers=24,
+                                        hybrid_layers=(6, 11, 17, 23))
+    place = lambda t: jax.tree.map(                         # noqa: E731
+        lambda x: _spec(one_chip, x.shape, x.dtype), t)
+    params = place(abstract_params(cfg))
+    state = place(jax.eval_shape(
+        lambda: init_decode_state(cfg, None, 4, 2048)))
+    hlo = jax.jit(functools.partial(_decode_loop, cfg, 2048),
+                  donate_argnums=(2,)).lower(
+        params, _spec(one_chip, (4, 1), jnp.int32), state,
+        _spec(one_chip, (), jnp.int32)).compile().as_text()
+    body, copies = _loop_copy_bytes(hlo)
+    assert body                                      # the loop is there
+    assert max(copies, default=0) <= 64 * 1024, sorted(copies)[-4:]
