@@ -1,7 +1,8 @@
 """Replicated vs bank-axis-sharded lookup — the scaling claim, measured.
 
 The replicated path keeps the whole ragged bucket arena on one device and
-probes it with ``lookup_batch_ragged``; the sharded path partitions tree
+probes it with ``lookup_arena``, each query routed to its tree's segment;
+the sharded path partitions tree
 ranges over the mesh (``FilterBank.shard`` + ``stage_sharded_bank``) and
 routes each query batch through the ``shard_map`` all-to-all
 (``sharded_lookup_bank``).  For every T the sweep records wall-clock for
@@ -25,7 +26,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from repro.core import build_bank, lookup_batch_ragged
+from repro.core import build_bank, lookup_arena
 from repro.core import hashing
 
 from .common import (best_time, parse_bench_args, synthetic_forest,
@@ -72,7 +73,11 @@ def run(tree_counts: Sequence[int] = (16, 64, 256),
         fps_r, heads_r = jnp.asarray(mf), jnp.asarray(mh)
         off_r = jnp.asarray(moff.astype(np.int32))
         nb_r = jnp.asarray(mnb)
-        rep_fn = jax.jit(lookup_batch_ragged)
+
+        @jax.jit
+        def rep_fn(fps, heads, offsets, tree_nb, qt, qh):
+            return lookup_arena(fps, heads, offsets[qt],
+                                (tree_nb[qt] - 1).astype(jnp.uint32), qh)
 
         # ---- equivalence gate before timing
         ref = rep_fn(fps_r, heads_r, off_r, nb_r, qt_j, qh_j)

@@ -7,10 +7,10 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import CuckooFilter, build_forest, build_index, lookup_batch
+from repro.core import CuckooFilter, build_forest, build_index, lookup_arena
 from repro.core import hashing
 from repro.data import hospital_corpus
-from repro.kernels.cuckoo_lookup import cuckoo_lookup
+from repro.kernels.cuckoo_lookup import cuckoo_lookup_arena
 
 
 def error_rate(num_entities: int = 3148, num_buckets: int = 1024,
@@ -36,7 +36,10 @@ def batched_vs_sequential(num_trees: int = 300, batch: int = 512,
     names = [forest.entity_names[i % forest.num_entities]
              for i in range(batch)]
     hs = hashing.hash_entities(names)
-    hj = jnp.asarray(hs)
+    # the single filter is an arena of one segment
+    args = (fps, heads, jnp.zeros((batch,), jnp.int32),
+            jnp.full((batch,), idx.filter.num_buckets - 1, jnp.uint32),
+            jnp.asarray(hs))
 
     t0 = time.perf_counter()
     for _ in range(repeats):
@@ -44,16 +47,16 @@ def batched_vs_sequential(num_trees: int = 300, batch: int = 512,
             idx.filter.lookup(int(h), bump=False)
     t_seq = (time.perf_counter() - t0) / repeats
 
-    lookup_batch(fps, heads, hj).hit.block_until_ready()   # warm
+    lookup_arena(*args).hit.block_until_ready()   # warm
     t0 = time.perf_counter()
     for _ in range(repeats):
-        lookup_batch(fps, heads, hj).hit.block_until_ready()
+        lookup_arena(*args).hit.block_until_ready()
     t_vec = (time.perf_counter() - t0) / repeats
 
-    out = cuckoo_lookup(fps, heads, hj, interpret=True)
+    out = cuckoo_lookup_arena(*args, interpret=True)
     out.hit.block_until_ready()
     t0 = time.perf_counter()
-    cuckoo_lookup(fps, heads, hj, interpret=True).hit.block_until_ready()
+    cuckoo_lookup_arena(*args, interpret=True).hit.block_until_ready()
     t_kernel_interp = time.perf_counter() - t0
 
     return {"batch": batch, "sequential_s": t_seq, "vectorized_s": t_vec,

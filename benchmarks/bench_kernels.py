@@ -1,122 +1,19 @@
-"""Kernel benchmarks: the gated fused-vs-unfused retrieval sweep plus the
-per-kernel oracle-error microbenchmarks.
+"""Kernel microbenchmarks: per-kernel error against its jnp oracle.
 
-The headline metric is ``fused_speedup`` — wall time of the unfused jitted
-``retrieve_device`` chain (arena probe -> bump -> CSR gather -> hierarchy
-walks, each materializing its (B,)-shaped intermediates) divided by the
-single-pass :mod:`repro.kernels.fused_retrieve` launch, on skewed deep
-forests at T in {16, 64, 256} and hit rates {0.1, 0.9}.  Dimensionless and
-measured within one process, so the committed baseline gates CI runners
-(``benchmarks/check_regression.py``); every timed pair is preceded by a
-bit-identity assert, so a fast-but-wrong kernel can never post a win.
+Wall time on the CPU is interpret-mode and says nothing about the TPU
+target, so each row's ``derived`` column reports max |err| against the
+oracle (1 = exact for the probe); ``work`` is a size figure of the case.
 
-Raw per-batch times ride along unngated; the oracle-error micro rows
-(``micro``) keep the numerical columns the old print-only bench reported.
+``python -m benchmarks.bench_kernels [--json BENCH_kernels.json]``
 """
 from __future__ import annotations
 
 import sys
 
-import time
-
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from benchmarks.common import parse_bench_args, write_json
-
-BATCH = 512
-SWEEP_TREES = (16, 64, 256)
-HIT_RATES = (0.1, 0.9)
-
-
-def skewed_forest(num_trees: int, seed: int = 0):
-    """Skewed deep forest: most trees are small and flat, every 7th is a
-    hub with a deep random-parent tail — the adversarial layout for the
-    fused kernel's ragged routing + hierarchy walks."""
-    from repro.core import build_forest
-    rng = np.random.default_rng(seed)
-    trees = []
-    for t in range(num_trees):
-        names = [f"e{t}_{i}" for i in range(4)]
-        edges = [(f"r{t}", n) for n in names]
-        if t % 7 == 0:                      # hub tree: deep + skewed
-            for j in range(40):
-                parent = names[int(rng.integers(len(names)))]
-                child = f"e{t}_h{j}"
-                edges.append((parent, child))
-                names.append(child)
-        trees.append(edges)
-    return build_forest(trees), trees
-
-
-def _queries(forest, trees, num_trees: int, hit_rate: float, seed: int):
-    from repro.core import hashing
-    rng = np.random.default_rng(seed)
-    per_tree = [[c for _, c in edges] for edges in trees]
-    qt = rng.integers(num_trees, size=BATCH).astype(np.int32)
-    qh = np.empty(BATCH, np.uint32)
-    hit = rng.random(BATCH) < hit_rate
-    for i in range(BATCH):
-        if hit[i]:
-            ents = per_tree[qt[i]]
-            qh[i] = hashing.entity_hash(ents[int(rng.integers(len(ents)))])
-        else:
-            qh[i] = rng.integers(1, 2 ** 32)
-    return jnp.asarray(qh), jnp.asarray(qt)
-
-
-def fused_rows(iters: int, seed: int = 0):
-    """The gated sweep: assert bit-identity, then time both paths."""
-    from repro.core import CFTDeviceState, build_index, retrieve_device
-    from repro.kernels.fused_retrieve import fused_retrieve_state_auto
-
-    rows = []
-    for num_trees in SWEEP_TREES:
-        forest, trees = skewed_forest(num_trees, seed=seed)
-        # size for a realistic ~0.7 load over 4-slot buckets: an
-        # arena padded to the next power of two past E/3 rows
-        idx = build_index(forest, num_buckets=1 << int(np.ceil(
-            np.log2(max(64, forest.num_entities // 3)))))
-        state = CFTDeviceState.from_index(idx)
-        unfused = jax.jit(retrieve_device, static_argnames=("max_locs", "n"))
-        for hr in HIT_RATES:
-            qh, qt = _queries(forest, trees, num_trees, hr, seed + 1)
-            ref = jax.block_until_ready(unfused(state, qh, qt))
-            got = fused_retrieve_state_auto(state, qh, qt)
-            assert got is not None, "fused path unavailable on this host"
-            jax.block_until_ready(got)
-            for f in ("hit", "locations", "up", "down", "temperature"):
-                np.testing.assert_array_equal(
-                    np.asarray(getattr(ref, f)),
-                    np.asarray(getattr(got, f)),
-                    err_msg=f"fused != unfused on {f} "
-                            f"(T={num_trees}, hit_rate={hr})")
-            t_un, t_fu = _interleaved_best(
-                lambda: jax.block_until_ready(unfused(state, qh, qt)),
-                lambda: jax.block_until_ready(
-                    fused_retrieve_state_auto(state, qh, qt)),
-                iters)
-            rows.append(dict(trees=num_trees, batch=BATCH, hit_rate=hr,
-                             unfused_ms=t_un * 1e3, fused_ms=t_fu * 1e3,
-                             fused_speedup=t_un / t_fu))
-    return rows
-
-
-def _interleaved_best(fn_a, fn_b, rounds: int):
-    """Best-of-N with A/B interleaved per round, so a noisy scheduling
-    window on a shared host degrades both sides instead of biasing the
-    ratio toward whichever ran in the quiet window."""
-    fn_a(), fn_b()                                 # absorb compiles
-    best_a = best_b = float("inf")
-    for _ in range(max(1, rounds)):
-        t0 = time.perf_counter()
-        fn_a()
-        best_a = min(best_a, time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        fn_b()
-        best_b = min(best_b, time.perf_counter() - t0)
-    return best_a, best_b
 
 
 def micro_rows():
@@ -127,9 +24,9 @@ def micro_rows():
     rows = []
 
     # cuckoo lookup: exactness + table footprint
-    from repro.core import build_forest, build_index
+    from repro.core import build_forest, build_index, lookup_arena
     from repro.core import hashing
-    from repro.kernels.cuckoo_lookup import cuckoo_lookup, cuckoo_lookup_ref
+    from repro.kernels.cuckoo_lookup import cuckoo_lookup_arena
     forest = build_forest([[(f"r{t}", f"e{t}_{i}") for i in range(8)]
                            for t in range(80)])
     idx = build_index(forest, num_buckets=1024)
@@ -137,8 +34,11 @@ def micro_rows():
     fps, heads = jnp.asarray(t.fingerprints), jnp.asarray(t.heads)
     h = jnp.asarray(hashing.hash_entities(
         [forest.entity_names[i % forest.num_entities] for i in range(256)]))
-    ref = cuckoo_lookup_ref(fps, heads, h)
-    ker = cuckoo_lookup(fps, heads, h, interpret=True)
+    # the single filter is an arena of one segment
+    args = (fps, heads, jnp.zeros((256,), jnp.int32),
+            jnp.full((256,), 1023, jnp.uint32), h)
+    ref = lookup_arena(*args)
+    ker = cuckoo_lookup_arena(*args, interpret=True)
     exact = int(np.array_equal(np.asarray(ref.head), np.asarray(ker.head)))
     vmem_kib = t.fingerprints.size * 4 * 2 / 1024
     rows.append(dict(name="cuckoo_lookup/exact", work=vmem_kib,
@@ -187,27 +87,15 @@ def micro_rows():
 
 def main(argv=None) -> int:
     from repro.obs import get_registry
-    flags, json_path = parse_bench_args(
-        sys.argv[1:] if argv is None else argv, "bench_kernels")
-    iters = 4 if "--fast" in flags else (12 if "--smoke" in flags else 24)
-
-    rows = fused_rows(iters)
-    print("fused retrieval sweep (skewed forests, B=512, bit-identity "
-          "asserted before timing)")
-    print(f"  {'T':>4s} {'hit':>4s} {'unfused_ms':>11s} "
-          f"{'fused_ms':>9s} {'speedup':>8s}")
-    for r in rows:
-        print(f"  {r['trees']:4d} {r['hit_rate']:4.1f} "
-              f"{r['unfused_ms']:11.3f} {r['fused_ms']:9.3f} "
-              f"{r['fused_speedup']:7.2f}x")
-
+    _, json_path = parse_bench_args(
+        sys.argv[1:] if argv is None else argv, "bench_kernels", flags=())
     micro = micro_rows()
     print("kernel microbenchmarks (derived = max|err| vs oracle, 1=exact)")
     for r in micro:
         print(f"  {r['name']:34s} work~{r['work']:10.1f}  "
               f"derived {r['derived']:.3e}")
 
-    write_json(json_path, {"rows": rows, "micro": micro,
+    write_json(json_path, {"micro": micro,
                            "obs": get_registry().snapshot()})
     return 0
 

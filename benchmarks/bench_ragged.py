@@ -26,7 +26,7 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 from repro.core import (MaintenanceEngine, build_bank, build_forest,
-                        lookup_batch_ragged)
+                        lookup_arena)
 from repro.core import hashing
 
 from .common import best_time, parse_bench_args, write_json
@@ -47,7 +47,7 @@ def _equivalence(bank, forest) -> bool:
     """Host vs jnp vs Pallas kernel, bit-identical (hits and misses on
     hit/head; bucket/slot on hits, as everywhere else in the suite)."""
     import jax.numpy as jnp
-    from repro.kernels.cuckoo_lookup import cuckoo_lookup_ragged
+    from repro.kernels.cuckoo_lookup import cuckoo_lookup_arena
 
     hashes = hashing.hash_entities(forest.entity_names)
     tid = np.concatenate([bank.row_tree,
@@ -55,11 +55,13 @@ def _equivalence(bank, forest) -> bool:
     hh = np.concatenate([hashes[bank.row_entity],
                          hashing.hash_entities([f"missing {i}"
                                                 for i in range(32)])])
+    # each query's segment start and bucket mask, as retrieve_device routes
     args = (jnp.asarray(bank.fingerprints), jnp.asarray(bank.heads),
-            jnp.asarray(bank.bucket_offsets.astype(np.int32)),
-            jnp.asarray(bank.tree_nb), jnp.asarray(tid), jnp.asarray(hh))
-    ref = lookup_batch_ragged(*args)
-    ker = cuckoo_lookup_ragged(*args, interpret=True)
+            jnp.asarray(bank.bucket_offsets[tid].astype(np.int32)),
+            jnp.asarray((bank.tree_nb[tid] - 1).astype(np.uint32)),
+            jnp.asarray(hh))
+    ref = lookup_arena(*args)
+    ker = cuckoo_lookup_arena(*args, interpret=True)
     m = np.asarray(ref.hit)
     ok = (np.array_equal(np.asarray(ker.hit), m)
           and np.array_equal(np.asarray(ker.head), np.asarray(ref.head))

@@ -42,7 +42,6 @@ GATED_METRICS: Dict[str, str] = {
     "healthy_goodput_ratio": "down",   # bench_tenant (healthy / clean)
     "victim_goodput_ratio": "down",    # bench_tenant (victim / clean)
     "bytes_fraction": "up",       # bench_ragged / bench_distributed
-    "fused_speedup": "down",      # bench_kernels (fused vs unfused)
 }
 
 # keys that identify a row's scenario — a mismatch means the bench's

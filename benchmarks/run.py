@@ -86,17 +86,17 @@ def main() -> None:
                           entities_per_tree=8 if smoke else 48,
                           batch_per_tree=16 if smoke else 64,
                           repeats=1 if smoke else 3)
-    print("\n== Filter bank: bulk build + vmapped lookup vs #trees ==")
+    print("\n== Filter bank: bulk build + batched lookup vs #trees ==")
     print(f"{'trees':>6s} {'items':>7s} {'build_x':>8s} {'lookup_x':>9s} "
           f"{'exact':>6s}")
     for r in rows:
-        assert r["vmap_exact"], "bank lookup diverged from reference"
+        assert r["exact"], "bank lookup diverged from reference"
         print(f"{r['trees']:6d} {r['items']:7d} {r['build_speedup']:8.1f} "
-              f"{r['lookup_speedup']:9.1f} {str(r['vmap_exact']):>6s}")
+              f"{r['lookup_speedup']:9.1f} {str(r['exact']):>6s}")
         csv.append((f"bank/trees{r['trees']}/build",
                     r["build_bulk_s"] * 1e6, r["build_speedup"]))
         csv.append((f"bank/trees{r['trees']}/lookup",
-                    r["lookup_vmap_s"] * 1e6, r["lookup_speedup"]))
+                    r["lookup_batch_s"] * 1e6, r["lookup_speedup"]))
 
     churn_kw = (dict(tree_counts=(16,), entities_per_tree=24, ops=128,
                      batch=32) if smoke else

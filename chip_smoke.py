@@ -6,11 +6,11 @@
 One chip: the ``paper-cftrag`` generator at full width (random weights
 from ``--seed``) behind ``RAGPipeline`` over the 600-tree hospital corpus
 with the filter bank on device.  It answers queries end to end, serves
-one ``(tree_id, hash)`` batch through the retrieval session unfused and
-fused, applies a live insert and splice commit, and checks every result:
-device retrieval against the host BFS oracle and the jnp reference, fused
-against unfused bit for bit, the inserted entity found, greedy decoding
-deterministic, and prefill logits against a float32 reference.
+one ``(tree_id, hash)`` batch through the retrieval session, applies a
+live insert and splice commit, and checks every result: device retrieval
+against the host BFS oracle and, bit for bit, the jnp reference, the
+inserted entity found, greedy decoding deterministic, and prefill logits
+against a float32 reference.
 
 ``--four-chips`` runs only the bank-axis sharded pipeline on a 4-chip
 mesh and the one-chip replicated pipeline on the same queries, and checks
@@ -147,7 +147,6 @@ def smoke_one_chip(seed: int) -> dict:
     from repro.data import HashTokenizer, hospital_corpus
     from repro.data.ner import recognize_entities
     from repro.kernels.cuckoo_lookup.ops import cuckoo_lookup_arena_auto
-    from repro.kernels.fused_retrieve import ops as fops
     from repro.models import init_params, prefill
     from repro.obs import get_registry
     from repro.serving import RAGPipeline, ServeEngine
@@ -222,8 +221,8 @@ def smoke_one_chip(seed: int) -> dict:
         f"filter false positives: {false_pos} of {probes} (tree, entity) "
         "probes")
 
-    # --- one (tree_id, hash) batch through the session: unfused, fused,
-    # and the jnp reference, each from a fresh copy of the bank's state
+    # --- one (tree_id, hash) batch through the session and the jnp
+    # reference, each from a fresh copy of the bank's state
     rng = np.random.default_rng(seed)
     trees, hashes, expect = _query_batch(rag.forest, rng, 192, 64)
     fresh = lambda: CFTDeviceState.from_bank(rag.bank, rag.forest)  # noqa
@@ -231,40 +230,20 @@ def smoke_one_chip(seed: int) -> dict:
     hh, tid, _ = engine.retrieval.pad_queries(trees, hashes)
     check(_lowers_to_mosaic(engine.retrieval._step, engine.retrieval.state,
                             hh, tid),
-          "unfused step did not compile to a Mosaic kernel")
+          "session step did not compile to a Mosaic kernel")
     t = time.perf_counter()
-    unfused = engine.retrieve(trees, hashes)
-    jax.block_until_ready(unfused)
-    unfused_s = time.perf_counter() - t
-    reg = get_registry()
-    fused_before = reg.counter("serve.fused_batches").value()
-    engine.attach_retrieval(fresh(), fused=True)
-    st = engine.retrieval.state
-    plan = fops.launch_plan(arena_rows, int(st.fingerprints.shape[1]),
-                            int(st.csr_offsets.shape[0]) - 1,
-                            int(st.csr_nodes.shape[0]),
-                            int(st.parent.shape[0]),
-                            int(st.child_index.shape[0]))
-    check(plan[0] is False and plan[1] is True,
-          f"fused launch plan {plan} is not a compiled MXU kernel")
-    t = time.perf_counter()
-    fused = engine.retrieve(trees, hashes)
-    jax.block_until_ready(fused)
-    fused_s = time.perf_counter() - t
-    fused_ran = reg.counter("serve.fused_batches").value() - fused_before
-    check(fused_ran == 1, f"serve.fused_batches ticked {fused_ran} times")
+    got = engine.retrieve(trees, hashes)
+    jax.block_until_ready(got)
+    step_s = time.perf_counter() - t
     ref = retrieve_device(fresh(), hh, tid)
     b = len(hashes)
     ref = DeviceRetrieval(ref.hit[:b], ref.locations[:b], ref.up[:b],
                           ref.down[:b], ref.temperature)
-    _same(unfused, fused, fields, "fused vs unfused")
-    _same(unfused, ref, fields, "kernel vs jnp reference")
-    n_checked = _check_against_forest(unfused, expect, 4)
+    _same(got, ref, fields, "kernel vs jnp reference")
+    n_checked = _check_against_forest(got, expect, 4)
     log(f"session batch: {b} queries ({n_checked} forest-checked hits), "
-        f"fused == unfused == jnp reference on {', '.join(fields)}; "
-        f"fused plan (interpret, mxu, row_tile, vmem_limit)={plan}; "
-        f"seconds unfused {unfused_s:.3f} fused {fused_s:.3f} "
-        "(first calls, compiles included)")
+        f"kernel == jnp reference on {', '.join(fields)}; "
+        f"seconds {step_s:.3f} (first call, compiles included)")
 
     # --- live insert + splice commit, then the device finds it
     tree = 7
@@ -305,11 +284,12 @@ def smoke_one_chip(seed: int) -> dict:
     log(f"prefill logits vs float32 reference: max_abs_diff={diff:.6f} "
         f"bound={bound:.6f}")
     check(diff <= bound, f"logits differ by {diff} > {bound}")
-    compiles = reg.snapshot()["histograms"].get("xla.compile_s", {})
+    compiles = get_registry().snapshot()["histograms"].get("xla.compile_s",
+                                                           {})
     log(f"compile seconds (backend compiles after set-up): "
         f"{compiles.get('sum', 0.0):.3f} over {compiles.get('count', 0)} "
         "compiles")
-    return {"arena_rows": arena_rows, "fused_ran": fused_ran}
+    return {"arena_rows": arena_rows}
 
 
 # ------------------------------------------------------------ four chips
@@ -317,10 +297,8 @@ def smoke_one_chip(seed: int) -> dict:
 def smoke_four_chips(seed: int) -> None:
     import jax
     import numpy as np
-    from repro.core import stage_sharded_bank
     from repro.data import hospital_corpus
     from repro.serving import RAGPipeline
-    from repro.serving.engine import RetrievalSession
 
     fields = ("hit", "locations", "up", "down")
     corpus = hospital_corpus(num_trees=NUM_TREES)
@@ -364,13 +342,9 @@ def smoke_four_chips(seed: int) -> None:
     s = shd.session.retrieve(trees, hashes)
     jax.block_until_ready((r, s))
     _same(r, s, fields, "sharded vs replicated")
-    fused = RetrievalSession()
-    fused.attach(stage_sharded_bank(shd.bank, shd.forest, mesh), fused=True)
-    f = fused.retrieve(trees, hashes)
-    _same(r, f, fields, "sharded fused vs replicated")
     n_checked = _check_against_forest(r, expect, 4)
     log(f"session batch: {len(hashes)} queries ({n_checked} forest-checked "
-        f"hits); sharded (probe and fused) == replicated on "
+        f"hits); sharded == replicated on "
         f"{', '.join(fields)}; {time.perf_counter() - t:.3f} s with compiles")
 
 
@@ -391,7 +365,7 @@ def main(argv=None) -> int:
         else:
             res = smoke_one_chip(args.seed)
             log(f"summary: kind={dev.device_kind} arena_rows="
-                f"{res['arena_rows']} fused_plan_ran={res['fused_ran'] > 0}")
+                f"{res['arena_rows']}")
     except Exception as e:                       # any failed phase
         import traceback
         traceback.print_exc()

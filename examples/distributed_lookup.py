@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import build_forest, build_index, lookup_batch
+from repro.core import build_forest, build_index, lookup_arena
 from repro.core import hashing
 from repro.core.distributed import shard_filter_tables, sharded_lookup
 from repro.data import hospital_corpus
@@ -34,7 +34,11 @@ def main():
     names = forest.entity_names[:96] + ["Missing Unit X"]
     h = jnp.asarray(hashing.hash_entities(names))
     got = sharded_lookup(mesh, "model", fps, heads, h)
-    ref = lookup_batch(jnp.asarray(t.fingerprints), jnp.asarray(t.heads), h)
+    # the replicated filter is an arena of one segment
+    ref = lookup_arena(jnp.asarray(t.fingerprints), jnp.asarray(t.heads),
+                       jnp.zeros(h.shape, jnp.int32),
+                       jnp.full(h.shape, index.filter.num_buckets - 1,
+                                jnp.uint32), h)
     assert np.array_equal(np.asarray(got.hit), np.asarray(ref.hit))
     assert np.array_equal(np.asarray(got.head), np.asarray(ref.head))
     print(f"sharded lookup == replicated lookup on {len(names)} queries "

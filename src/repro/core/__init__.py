@@ -8,11 +8,8 @@ from .context import (EntityContext, context_from_arena, context_from_csr,
                       generate_context, render_context)
 from .cuckoo import (CFTIndex, CuckooFilter, CuckooTables, build_index,
                      bulk_place)
-from .lookup import (LookupResult, bump_temperature, bump_temperature_arena,
-                     bump_temperature_bank, lookup_arena, lookup_batch,
-                     lookup_batch_bank, lookup_batch_ragged,
-                     lookup_batch_trees, sort_buckets, sort_buckets_arena,
-                     sort_buckets_bank)
+from .lookup import (LookupResult, bump_temperature_arena, lookup_arena,
+                     sort_buckets_arena)
 from .maintenance import (BankDelta, MaintenanceBreaker, MaintenanceEngine,
                           MaintenanceReport, PendingRestage,
                           PendingShardedRestage, ShardedMaintenanceEngine,
@@ -56,10 +53,8 @@ __all__ = [
     "EntityContext", "context_from_arena", "context_from_csr",
     "generate_context", "render_context",
     "CFTIndex", "CuckooFilter", "CuckooTables", "build_index", "bulk_place",
-    "LookupResult", "bump_temperature", "bump_temperature_arena",
-    "bump_temperature_bank", "lookup_arena", "lookup_batch",
-    "lookup_batch_bank", "lookup_batch_ragged", "lookup_batch_trees",
-    "sort_buckets", "sort_buckets_arena", "sort_buckets_bank",
+    "LookupResult", "bump_temperature_arena", "lookup_arena",
+    "sort_buckets_arena",
     "CFTRAG", "CFTDeviceState", "DeviceRetrieval", "build_retriever",
     "retrieve_device",
     "EntityForest", "build_forest",

@@ -693,10 +693,9 @@ class ShardedBank:
 
     def merged_tables(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Replicated-reference arena ``(A, S)`` tables in global tree
-        order with merged-row head payloads — the tables
-        ``lookup_batch_ragged`` probes (with :meth:`merged_layout`) to
-        produce the sharded path's exact results.  Well-defined for any
-        per-tree nb (the dense uniform-NB restriction is gone)."""
+        order with merged-row head payloads — the tables ``lookup_arena``
+        probes (routed through :meth:`merged_layout`) to produce the
+        sharded path's exact results.  Well-defined for any per-tree nb."""
         base = self.shard_row_base()
         fps = np.concatenate([b.fingerprints for b in self.banks], axis=0)
         temp = np.concatenate([b.temperature for b in self.banks], axis=0)

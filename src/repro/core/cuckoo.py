@@ -7,8 +7,9 @@ construction outside the query hot loop.
 
 Device side: the tables are dense arrays (fingerprints / temperature / head
 pointers per bucket slot) shipped to the accelerator; batched lookup lives in
-``lookup_batch`` (pure jnp reference) and ``repro.kernels.cuckoo_lookup``
-(Pallas TPU kernel with identical semantics).
+``repro.core.lookup.lookup_arena`` (pure jnp reference; one filter is an
+arena of one segment) and ``repro.kernels.cuckoo_lookup`` (Pallas TPU
+kernel with identical semantics).
 
 Each bucket slot stores, per the paper (§3.1): the entity's 12-bit
 fingerprint, its temperature, and the head pointer of its block linked list.

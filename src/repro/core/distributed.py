@@ -51,8 +51,7 @@ from . import hashing
 from .bank import FilterBank, ShardedBank, pad_csr
 from .lookup import LookupResult, lookup_arena, sort_buckets_arena
 from .tree import EntityForest
-from .trag import (CFTDeviceState, DeviceRetrieval, finish_context,
-                   gather_context)
+from .trag import CFTDeviceState, DeviceRetrieval, gather_context
 
 NULL = -1
 
@@ -113,14 +112,6 @@ def _route_back(x: jax.Array, dest: jax.Array, rank: jax.Array,
                 axis: str, num_shards: int) -> jax.Array:
     """Send per-slot probe results home and unscatter to query order."""
     recv = _exchange(x.reshape(num_shards, -1), axis)
-    return recv[dest, rank]
-
-
-def _route_back_wide(x: jax.Array, dest: jax.Array, rank: jax.Array,
-                     axis: str, num_shards: int) -> jax.Array:
-    """Route-back for per-query *row* payloads ``(D*C, W)`` — the fused
-    owner probe sends whole CSR location windows home, not scalars."""
-    recv = _exchange(x.reshape(num_shards, -1, x.shape[-1]), axis)
     return recv[dest, rank]
 
 
@@ -407,77 +398,6 @@ def _bank_local_fn(axis: str, num_shards: int, num_trees: int, slots: int,
     return local
 
 
-def _bank_local_fused_fn(axis: str, num_shards: int, num_trees: int,
-                         capacity: int, max_locs: int):
-    """Shard-local body for the *fused* owner probe: route -> one Pallas
-    launch (probe + temperature bump + CSR location window, from the
-    replicated CSR tables) on the owning shard -> route ``(hit,
-    locations)`` home.  The hierarchy walk stays on the source shard
-    (``finish_context`` over the replicated forest), so the route-back
-    payload grows only by ``max_locs`` ints per query."""
-    from ..kernels.fused_retrieve.ops import fused_probe_locs, launch_plan
-
-    def local(fps_b, temp_b, heads_b, tree_shard, tree_off, tree_nb,
-              csr_offsets, csr_nodes, tid, h):
-        tq = jnp.clip(tid, 0, num_trees - 1)
-        valid = (tid >= 0) & (tid < num_trees)
-        dest = jnp.where(valid, tree_shard[tq], 0).astype(jnp.int32)
-        aoff = jnp.where(valid, tree_off[tq], 0).astype(jnp.int32)
-        msk = jnp.where(valid, (tree_nb[tq] - 1).astype(jnp.uint32),
-                        jnp.uint32(0))
-        rank, (bh, bo, bm, bv) = _bucket_queries(
-            dest, num_shards, capacity,
-            ((h.astype(jnp.uint32), jnp.uint32(0)),
-             (aoff, jnp.int32(0)), (msk, jnp.uint32(0)), (valid, False)))
-        qh = _exchange(bh, axis).reshape(-1)
-        qo = _exchange(bo, axis).reshape(-1)
-        qm = _exchange(bm, axis).reshape(-1)
-        qv = _exchange(bv, axis).reshape(-1)
-        a, s = fps_b.shape
-        interpret, mxu, rt, limit = launch_plan(
-            a, s, csr_offsets.shape[0] - 1, csr_nodes.shape[0], 0, 0)
-        hit, locs, temp_b = fused_probe_locs(
-            fps_b, temp_b, heads_b, qo, qm, qv, qh, csr_offsets,
-            csr_nodes, max_locs=max_locs, interpret=interpret, row_tile=rt,
-            mxu=mxu, vmem_limit=limit)
-        back = functools.partial(_route_back, dest=dest, rank=rank,
-                                 axis=axis, num_shards=num_shards)
-        locs_home = _route_back_wide(locs, dest, rank, axis, num_shards)
-        return back(hit), locs_home, temp_b
-
-    return local
-
-
-def _fused_lookup_core(state: ShardedBankState, tree_ids: jax.Array,
-                       h: jax.Array, capacity: Optional[int],
-                       max_locs: int
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Sharded fused probe core: returns ``(hit, locations, temperature)``
-    with the CSR window already gathered on the owner shards."""
-    mesh, axis = state.mesh, state.axis
-    d = state.num_shards
-    b = h.shape[0]
-    pad = (-b) % d
-    bl = (b + pad) // d
-    cap = bl if capacity is None else min(capacity, bl)
-    tid = jnp.pad(tree_ids.astype(jnp.int32), (0, pad),
-                  constant_values=NULL)            # pad queries always miss
-    hp = jnp.pad(h.astype(jnp.uint32), (0, pad))
-    local = _bank_local_fused_fn(axis, d, state.num_trees, cap, max_locs)
-    spec_b = P(axis, None)
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(spec_b, spec_b, spec_b, P(), P(), P(), P(), P(),
-                  P(axis), P(axis)),
-        out_specs=(P(axis), P(axis, None), spec_b),
-        check_vma=False)                   # pallas_call: no replication rule
-    hit, locs, temp = fn(state.fingerprints, state.temperature,
-                         state.heads, state.tree_shard, state.tree_offset,
-                         state.tree_nb, state.csr_offsets, state.csr_nodes,
-                         tid, hp)
-    return hit[:b], locs[:b], temp
-
-
 def _lookup_core(state: ShardedBankState, tree_ids: jax.Array,
                  h: jax.Array, bump: bool, lookup_fn,
                  capacity: Optional[int]
@@ -601,7 +521,8 @@ def sharded_lookup_bank(state: ShardedBankState, tree_ids: jax.Array,
                         capacity_factor: Optional[float] = None
                         ) -> LookupResult:
     """All-to-all routed bank lookup; bit-identical to
-    ``lookup_batch_ragged`` over the merged replicated arena.
+    ``lookup_arena`` over the merged replicated arena, with each query
+    routed to its tree's segment as ``retrieve_device`` routes it.
 
     ``lookup_fn(fps, heads, row_offsets, masks, h)`` swaps in a different
     shard-local arena probe (e.g. the row-tiled Pallas kernel
@@ -622,20 +543,14 @@ def sharded_lookup_bank(state: ShardedBankState, tree_ids: jax.Array,
 
 @functools.partial(jax.jit,
                    static_argnames=("max_locs", "n", "lookup_fn",
-                                    "capacity", "fused"))
+                                    "capacity"))
 def _sharded_retrieve_jit(state: ShardedBankState,
                           query_hashes: jax.Array,
                           query_trees: jax.Array,
                           max_locs: int = 4, n: int = 3,
                           lookup_fn=None,
-                          capacity: Optional[int] = None,
-                          fused: bool = False
+                          capacity: Optional[int] = None
                           ) -> DeviceRetrieval:
-    if fused:
-        hit, locs, temp = _fused_lookup_core(
-            state, query_trees, query_hashes, capacity=capacity,
-            max_locs=max_locs)
-        return finish_context(state, hit, locs, temp, n=n)
     res, temp = _lookup_core(state, query_trees, query_hashes, bump=True,
                              lookup_fn=lookup_fn, capacity=capacity)
     return gather_context(state, res, temp, max_locs=max_locs, n=n)
@@ -646,8 +561,8 @@ def sharded_retrieve_device(state: ShardedBankState,
                             query_trees: Optional[jax.Array] = None,
                             max_locs: int = 4, n: int = 3,
                             lookup_fn=None,
-                            capacity_factor: Optional[float] = None,
-                            fused: bool = False) -> DeviceRetrieval:
+                            capacity_factor: Optional[float] = None
+                            ) -> DeviceRetrieval:
     """Bank-axis sharded analogue of ``repro.core.retrieve_device``.
 
     The lookup routes through the all-to-all; temperature bumps land in
@@ -655,24 +570,13 @@ def sharded_retrieve_device(state: ShardedBankState,
     ``temperature`` keeps the sharded layout — thread it forward with
     ``state.with_temperature``); the CSR location gather and hierarchy
     windows run on the replicated arrays exactly as the replicated path.
-
-    ``fused=True`` fuses probe + temperature bump + CSR location gather
-    into one Pallas launch *on the owner shard* before the route-back
-    all-to-all (the replicated CSR tables make the owner-side gather
-    free of extra communication); only ``(hit, locations)`` travel home,
-    and the hierarchy walk finishes on the source shard.  Bit-identical
-    to the unfused path; mutually exclusive with ``lookup_fn``.
     """
-    if fused and lookup_fn is not None:
-        raise ValueError("fused=True embeds the probe; lookup_fn "
-                         "cannot be combined with it")
     if query_trees is None:
         query_trees = jnp.zeros(query_hashes.shape, jnp.int32)
     capacity = _pick_capacity(state, query_trees, capacity_factor)
     return _sharded_retrieve_jit(state, query_hashes, query_trees,
                                  max_locs=max_locs, n=n,
-                                 lookup_fn=lookup_fn, capacity=capacity,
-                                 fused=fused)
+                                 lookup_fn=lookup_fn, capacity=capacity)
 
 
 # ------------------------------------------- legacy single-filter wrappers
@@ -725,9 +629,9 @@ def sharded_lookup(mesh: Mesh, axis: str, fingerprints: jax.Array,
 
     Thin wrapper over the bank-axis router: the D bucket stripes act as a
     degenerate D-tree bank (one "tree" per shard), each query routes to its
-    two candidate stripes, and no replica combine exists — the old
-    replicated-query pmax path is gone.  Bit-identical to
-    ``lookup_batch``.
+    two candidate stripes, and no replica combine exists.  Bit-identical to
+    ``lookup_arena`` over the unsharded filter as an arena of one segment
+    (zero offsets, masks ``nb - 1``).
     """
     nb_global, slots = fingerprints.shape
     mesh = auto_axes(mesh)

@@ -31,8 +31,8 @@ def match_rows(fp: jax.Array, i1: jax.Array, i2: jax.Array,
                heads1: jax.Array, heads2: jax.Array,
                s: int) -> LookupResult:
     """Shared slot-priority match over two gathered bucket rows — the one
-    place lookup semantics live; the batch/bank/sharded entry points all
-    gather their candidate rows and defer to this."""
+    place lookup semantics live; the arena probe gathers its candidate
+    rows and defers to this."""
     match = jnp.concatenate([rows1 == fp[:, None],
                              rows2 == fp[:, None]], axis=1)   # (B, 2S)
     hit = jnp.any(match, axis=1)
@@ -47,32 +47,6 @@ def match_rows(fp: jax.Array, i1: jax.Array, i2: jax.Array,
                         bucket=bucket, slot=slot)
 
 
-def lookup_batch(fingerprints: jax.Array, heads: jax.Array,
-                 h: jax.Array) -> LookupResult:
-    """fingerprints/heads: (NB, S); h: (B,) uint32 entity hashes."""
-    nb, s = fingerprints.shape
-    fp, i1, i2 = hashing.candidate_buckets(h.astype(jnp.uint32), nb, jnp)
-    return match_rows(fp, i1, i2, fingerprints[i1], fingerprints[i2],
-                      heads[i1], heads[i2], s)
-
-
-def lookup_batch_bank(fingerprints: jax.Array, heads: jax.Array,
-                      tree_ids: jax.Array, h: jax.Array) -> LookupResult:
-    """Per-query tree routing over a *dense uniform* filter bank.
-
-    fingerprints/heads: (T, NB, S); tree_ids/h: (B,).  Each query probes
-    only its own tree's filter; ``bucket`` is the tree-local bucket index.
-    Kept as the dense-equivalence reference for the ragged arena path
-    (:func:`lookup_batch_ragged` with uniform tree_nb must agree
-    bit-for-bit).
-    """
-    _, nb, s = fingerprints.shape
-    fp, i1, i2 = hashing.candidate_buckets(h.astype(jnp.uint32), nb, jnp)
-    t = tree_ids.astype(jnp.int32)
-    return match_rows(fp, i1, i2, fingerprints[t, i1], fingerprints[t, i2],
-                      heads[t, i1], heads[t, i2], s)
-
-
 def lookup_arena(fingerprints: jax.Array, heads: jax.Array,
                  row_offsets: jax.Array, masks: jax.Array,
                  h: jax.Array) -> LookupResult:
@@ -82,9 +56,11 @@ def lookup_arena(fingerprints: jax.Array, heads: jax.Array,
     (B,) per-query segment start and bucket mask ``nb_t - 1``.  This is
     the layer the sharded all-to-all hands exchanged queries to (the
     receiving shard knows each query's segment, not its global tree id);
-    :func:`lookup_batch_ragged` derives the per-query routing from the
-    per-tree offsets table.  ``bucket`` is the tree-local bucket index, so
-    results are bit-identical to probing that tree's standalone filter.
+    ``retrieve_device`` derives the per-query routing from the per-tree
+    offsets table.  A single filter ``(NB, S)`` is an arena of one
+    segment: zero offsets and masks ``NB - 1``.  ``bucket`` is the
+    tree-local bucket index, so results are bit-identical to probing that
+    tree's standalone filter.
     """
     s = fingerprints.shape[-1]
     fp, i1, i2 = hashing.candidate_buckets_masked(
@@ -96,50 +72,11 @@ def lookup_arena(fingerprints: jax.Array, heads: jax.Array,
                       heads[r1], heads[r2], s)
 
 
-def lookup_batch_ragged(fingerprints: jax.Array, heads: jax.Array,
-                        bucket_offsets: jax.Array, tree_nb: jax.Array,
-                        tree_ids: jax.Array, h: jax.Array) -> LookupResult:
-    """Per-query tree routing over the ragged bucket arena.
-
-    fingerprints/heads: (A, S); ``bucket_offsets``: (T + 1,) segment
-    starts; ``tree_nb``: (T,) per-tree bucket counts; tree_ids/h: (B,).
-    The probe computes ``bucket_offsets[t] + (i & (tree_nb[t] - 1))`` —
-    with uniform tree_nb this is bit-identical to :func:`lookup_batch_bank`
-    over the dense reshape of the same arena.
-    """
-    t = tree_ids.astype(jnp.int32)
-    return lookup_arena(fingerprints, heads, bucket_offsets[t],
-                        (tree_nb[t] - 1).astype(jnp.uint32), h)
-
-
-def lookup_batch_trees(fingerprints: jax.Array, heads: jax.Array,
-                       h: jax.Array) -> LookupResult:
-    """Vmapped-over-trees entry point: one dense query batch per tree.
-
-    fingerprints/heads: (T, NB, S); h: (T, B) — result fields are (T, B).
-    """
-    return jax.vmap(lookup_batch)(fingerprints, heads, h)
-
-
-def bump_temperature(temperature: jax.Array, res: LookupResult) -> jax.Array:
-    """Algorithm 3: temperature += 1 for every hit slot (scatter-add)."""
-    return temperature.at[res.bucket, res.slot].add(
-        res.hit.astype(temperature.dtype))
-
-
-def bump_temperature_bank(temperature: jax.Array, tree_ids: jax.Array,
-                          res: LookupResult) -> jax.Array:
-    """Dense bank-axis variant: temperature (T, NB, S), scatter per tree."""
-    return temperature.at[tree_ids.astype(jnp.int32),
-                          res.bucket, res.slot].add(
-        res.hit.astype(temperature.dtype))
-
-
 def bump_temperature_arena(temperature: jax.Array, row_offsets: jax.Array,
                            res: LookupResult) -> jax.Array:
-    """Arena variant: temperature (A, S); ``row_offsets`` (B,) per-query
-    segment starts — the hit slot lives at arena row
-    ``row_offsets + bucket``."""
+    """Algorithm 3: temperature += 1 for every hit slot (scatter-add).
+    temperature (A, S); ``row_offsets`` (B,) per-query segment starts —
+    the hit slot lives at arena row ``row_offsets + bucket``."""
     return temperature.at[row_offsets.astype(jnp.int32) + res.bucket,
                           res.slot].add(res.hit.astype(temperature.dtype))
 
@@ -158,26 +95,11 @@ def _sort_slots(fingerprints: jax.Array, temperature: jax.Array,
         take(t) for t in tables)
 
 
-def sort_buckets(fingerprints: jax.Array, temperature: jax.Array,
-                 heads: jax.Array, entity_ids: jax.Array):
-    """Reorder slots of every bucket by descending temperature (device-side
-    analogue of the paper's idle-time adaptive sort); empties sink last."""
-    return _sort_slots(fingerprints, temperature, heads, entity_ids)
-
-
-def sort_buckets_bank(fingerprints: jax.Array, temperature: jax.Array,
-                      *tables: jax.Array):
-    """Dense bank-axis idle-time sort: vmap of :func:`sort_buckets` over
-    the tree axis.  Tables are ``(T, NB, S)``."""
-    return jax.vmap(_sort_slots)(fingerprints, temperature, *tables)
-
-
 def sort_buckets_arena(fingerprints: jax.Array, temperature: jax.Array,
                        *tables: jax.Array):
     """Ragged-arena idle-time sort: one flat per-bucket slot reorder over
-    the whole ``(A, S)`` arena — the segmented replacement for the vmapped
-    ``sort_buckets_bank`` (a bucket sort never crosses rows, so the tree
-    segmentation needs no special handling).  Hot fingerprints float to
+    the whole ``(A, S)`` arena (a bucket sort never crosses rows, so the
+    tree segmentation needs no special handling).  Hot fingerprints float to
     slot 0 of their bucket within every tree's filter at once.  Payload
     tables (heads, entity ids, ...) are variadic so both the 3-table
     device state and the 5-table host bank restage through the same

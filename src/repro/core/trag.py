@@ -203,7 +203,7 @@ class CFTDeviceState:
 def retrieve_device(state: CFTDeviceState, query_hashes: jax.Array,
                     query_trees: Optional[jax.Array] = None,
                     max_locs: int = 4, n: int = 3,
-                    lookup_fn=None, fused: bool = False) -> DeviceRetrieval:
+                    lookup_fn=None) -> DeviceRetrieval:
     """Batched CFT-RAG retrieval, jit-compatible end to end.
 
     Queries are ``(tree_id, hash)`` pairs; ``query_trees`` defaults to all
@@ -214,20 +214,7 @@ def retrieve_device(state: CFTDeviceState, query_hashes: jax.Array,
     arena — defaults to the pure-jnp :func:`repro.core.lookup.
     lookup_arena`; the serving engine passes the Pallas arena kernel
     wrapper (identical signature/semantics).
-
-    ``fused=True`` routes the whole step (probe + bump + CSR window +
-    hierarchy walks) through the single-pass
-    :mod:`repro.kernels.fused_retrieve` kernel instead — bit-identical
-    outputs, one launch.  Mutually exclusive with ``lookup_fn`` (the fused
-    kernel *is* the probe).
     """
-    if fused:
-        if lookup_fn is not None:
-            raise ValueError("fused=True embeds the probe; lookup_fn "
-                             "cannot be combined with it")
-        from ..kernels.fused_retrieve import fused_retrieve_state_auto
-        return fused_retrieve_state_auto(state, query_hashes, query_trees,
-                                         max_locs=max_locs, n=n)
     if lookup_fn is None:
         lookup_fn = lookup_arena
     if query_trees is None:
@@ -307,9 +294,7 @@ def hierarchy_windows(parent: jax.Array, entity_id: jax.Array,
 def finish_context(state, hit: jax.Array, nodes: jax.Array,
                    temperature: jax.Array, n: int = 3) -> DeviceRetrieval:
     """Hierarchy windows for an already-gathered location window — the
-    forest-walk tail shared by :func:`gather_context` and the sharded
-    owner-fused path (which routes ``(hit, locations)`` back through the
-    all-to-all and walks the replicated forest locally)."""
+    forest-walk tail of :func:`gather_context`."""
     up, down = hierarchy_windows(state.parent, state.entity_id,
                                  state.child_offsets, state.child_index,
                                  nodes, n=n)

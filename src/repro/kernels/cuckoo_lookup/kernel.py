@@ -16,9 +16,8 @@ pointer dereference per probe:
      outputs hit/head/bucket/slot — the semantics of
      ``repro.core.lookup.lookup_arena``.
 
-Every probe entry (single filter, dense bank, ragged arena, fused
-retrieval) routes through :func:`_arena_probe`: a single filter is an
-arena of one segment, a dense bank an arena of equal segments.
+Queries arrive pre-routed to their segment; a single filter is an arena
+of one segment.
 """
 from __future__ import annotations
 
@@ -35,24 +34,10 @@ TILE = 128          # queries per grid step (one vector lane row)
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def compiler_params(vmem_limit: int):
-    """Mosaic parameters for a launch: ``vmem_limit`` > 0 raises the
-    kernel's scoped VMEM limit to what the tile budget was derived for
-    (0 keeps the compiler default)."""
-    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit or None)
-
-
-def _fp_f32(fp):
-    """Query fingerprints as the f32 the tables are staged in.  Mosaic has
-    no uint32 -> f32 cast; fingerprints are FP_BITS wide, so the hop
-    through int32 is exact."""
-    return fp.astype(jnp.int32).astype(jnp.float32)
-
-
-def _arena_probe(h, qoff, qmask, ti, tab_ref, hit_ref, head_ref,
-                 bucket_ref, slot_ref, prio_ref, *, slots: int,
-                 row_tile: int):
-    """Probe body shared by the arena and fused kernels.
+def _arena_kernel(h_ref, off_ref, mask_ref, tab_ref, hit_ref, head_ref,
+                  bucket_ref, slot_ref, prio_ref, *, slots: int,
+                  row_tile: int):
+    """Probe one (query tile, arena tile) grid step.
 
     ``h``/``qoff``/``qmask``: (1, TILE) hash, segment start and bucket
     mask ``nb_t - 1``.  Grid axis 1 walks tiles of ``row_tile`` arena
@@ -63,6 +48,8 @@ def _arena_probe(h, qoff, qmask, ti, tab_ref, hit_ref, head_ref,
     miss defaults (head -1, bucket i2, slot S-1); since every candidate
     row lives in exactly one tile, the min-priority merge reproduces the
     single-block match order exactly."""
+    h, qoff, qmask = h_ref[...], off_ref[...], mask_ref[...]
+    ti = pl.program_id(1)
     fp, i1u, i2u = hashing.candidate_buckets_masked(h, qmask, jnp)
     i1 = i1u.astype(jnp.int32)
     i2 = i2u.astype(jnp.int32)
@@ -90,7 +77,10 @@ def _arena_probe(h, qoff, qmask, ti, tab_ref, hit_ref, head_ref,
     rows1 = jax.lax.dot(tab, oh1, precision=_HIGHEST)   # (2S, TILE)
     rows2 = jax.lax.dot(tab, oh2, precision=_HIGHEST)
 
-    fpq = _fp_f32(fp)
+    # query fingerprints as the f32 the tables are staged in: Mosaic has no
+    # uint32 -> f32 cast, and FP_BITS-wide fingerprints hop through int32
+    # exactly
+    fpq = fp.astype(jnp.int32).astype(jnp.float32)
     pos = jax.lax.broadcasted_iota(jnp.int32, (slots, TILE), 0)
     none = 2 * slots
     first = jnp.minimum(
@@ -113,14 +103,6 @@ def _arena_probe(h, qoff, qmask, ti, tab_ref, hit_ref, head_ref,
                               jnp.where(first < slots, first, first - slots),
                               slot_ref[...])
     prio_ref[...] = jnp.where(better, first, prio_ref[...])
-
-
-def _arena_kernel(h_ref, off_ref, mask_ref, tab_ref, hit_ref, head_ref,
-                  bucket_ref, slot_ref, prio_ref, *, slots: int,
-                  row_tile: int):
-    _arena_probe(h_ref[...], off_ref[...], mask_ref[...], pl.program_id(1),
-                 tab_ref, hit_ref, head_ref, bucket_ref, slot_ref, prio_ref,
-                 slots=slots, row_tile=row_tile)
 
 
 def cuckoo_lookup_arena_pallas(h: jax.Array, row_offsets: jax.Array,
@@ -151,7 +133,10 @@ def cuckoo_lookup_arena_pallas(h: jax.Array, row_offsets: jax.Array,
         out_specs=[qspec] * 5,
         out_shape=[jax.ShapeDtypeStruct((1, b), jnp.int32)
                    for _ in range(5)],
-        compiler_params=compiler_params(vmem_limit),
+        # vmem_limit > 0 raises the scoped VMEM limit to what the tile
+        # budget was derived for; 0 keeps the compiler default
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
         name="cuckoo_probe",
     )(h, row_offsets, masks, table_f32)

@@ -1,8 +1,8 @@
-"""Shared VMEM budget for the retrieval kernels.
+"""VMEM budget for the retrieval probe kernel.
 
-The arena kernels (``cuckoo_lookup`` and ``fused_retrieve``) stream arena
-tiles through VMEM and must cap the rows per tile so that the tile working
-set fits on chip.  The capacity comes from the attached TPU (Pallas' TPU
+The arena probe kernel (``cuckoo_lookup``) streams arena tiles through
+VMEM and must cap the rows per tile so that the tile working set fits on
+chip.  The capacity comes from the attached TPU (Pallas' TPU
 hardware table, ``pltpu.get_tpu_info``, keyed by ``device_kind``; a kind
 the table does not know is an error).  Only a TPU has VMEM: off the chip
 the kernels run in interpret mode and nothing here is consulted.
@@ -15,7 +15,7 @@ Per arena row streamed through a probe tile (closed form, f32 staging):
     per_row = 4 * (4 * slots + 2 * TILE)
 
 Budget = half of the core's VMEM for the streamed tiles; the other half is
-headroom for the residents and Mosaic's own scratch.  The kernels are
+headroom for the query blocks and Mosaic's own scratch.  The kernel is
 launched with a scoped VMEM limit of the whole capacity, so the compiler
 holds them to the physical memory the budget was derived from.
 
@@ -77,13 +77,9 @@ def device_budget(slots: int = 4, tile: int = 128) -> VmemBudget:
     return b
 
 
-def max_rows_for_vmem(budget: VmemBudget, tile: int = 128,
-                      resident_bytes: int = 0) -> int:
-    """Largest arena row count whose tile working set fits the budget after
-    subtracting ``resident_bytes`` (tables pinned for the whole launch,
-    e.g. the fused kernel's CSR/forest/temperature blocks)."""
-    avail = max(budget.budget_bytes - resident_bytes, 0)
-    rows = avail // budget.per_row_bytes
+def max_rows_for_vmem(budget: VmemBudget, tile: int = 128) -> int:
+    """Largest arena row count whose tile working set fits the budget."""
+    rows = budget.budget_bytes // budget.per_row_bytes
     return max(tile, rows // tile * tile)
 
 

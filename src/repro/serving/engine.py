@@ -68,10 +68,8 @@ class RetrievalSession:
         self.snapshots = None                  # Optional[SnapshotWriter]
         self.tenants = None                    # Optional[TenantRegistry]
         self.batch_pad = 64
-        self.fused = False
         self._step = None
         self._watched_step = None
-        self._attach_args = (None, 4, 3)
         # observability: process-wide registry, per-session tracer and
         # recompile sentinel (the PR 6 shape-instability tripwire)
         self.metrics = get_registry()
@@ -80,7 +78,7 @@ class RetrievalSession:
 
     # ------------------------------------------------------------ attach
     def attach(self, state, lookup_fn=None, max_locs: int = 4, n: int = 3,
-               batch_pad: int = 64, fused: bool = False) -> None:
+               batch_pad: int = 64) -> None:
         """Point the session at a device state: one jitted step over the
         bank-axis layout, shape-stable via the padding policy.
 
@@ -89,65 +87,25 @@ class RetrievalSession:
         query batch to the owning shards with an all-to-all instead of
         probing a replicated bank; everything downstream (padding policy,
         temperature threading, maintenance harvest) is identical.
-
-        ``fused=True`` serves through the single-pass
-        :mod:`repro.kernels.fused_retrieve` kernel (probe + bump + CSR
-        window + hierarchy walks in one launch; owner-shard fusion on the
-        sharded layout).  Mutually exclusive with ``lookup_fn`` — the
-        fused kernel *is* the probe.  Flip at runtime with
-        :meth:`set_fused`.
         """
-        if fused and lookup_fn is not None:
-            raise ValueError("fused=True embeds the probe; lookup_fn "
-                             "cannot be combined with it")
         self.state = state
         self.batch_pad = batch_pad
-        self.fused = bool(fused)
-        self._attach_args = (lookup_fn, max_locs, n)
-        self._build_step()
+        self._build_step(lookup_fn, max_locs, n)
 
-    def _build_step(self) -> None:
-        lookup_fn, max_locs, n = self._attach_args
+    def _build_step(self, lookup_fn, max_locs: int, n: int) -> None:
         if isinstance(self.state, ShardedBankState):
             # already jitted; mesh/axis ride in the state's static aux
             self._step = functools.partial(
                 sharded_retrieve_device, max_locs=max_locs, n=n,
-                lookup_fn=lookup_fn, fused=self.fused)
+                lookup_fn=lookup_fn)
             from ..core.distributed import _sharded_retrieve_jit
             self._watched_step = _sharded_retrieve_jit
-        elif self.fused:
-            # the fused entry picks its launch plan outside any trace, so
-            # the jit boundary is the kernel ops wrapper
-            from ..kernels.fused_retrieve import (fused_retrieve_state_auto,
-                                                  ops as _fops)
-            self._step = functools.partial(fused_retrieve_state_auto,
-                                           max_locs=max_locs, n=n)
-            self._watched_step = _fops.fused_retrieve_ragged
         else:
             self._step = jax.jit(functools.partial(
                 retrieve_device, max_locs=max_locs, n=n,
                 lookup_fn=lookup_fn))
             self._watched_step = self._step
         self.sentinel.watch("serve.step", self._watched_step)
-
-    def set_fused(self, on: bool) -> None:
-        """Flip the attached step between the fused single-pass kernel
-        and the unfused oracle path at runtime.  The new step compiles
-        its geometries once — an expected, intentional event — so the
-        recompile sentinel forgives exactly one cache growth
-        (:meth:`RecompileSentinel.allow_next`), keeping armed tripwires
-        quiet for the flip itself but live for anything after it."""
-        if self.state is None:
-            raise RuntimeError("attach a retrieval state first")
-        if bool(on) == self.fused:
-            return
-        lookup_fn, _, _ = self._attach_args
-        if on and lookup_fn is not None:
-            raise ValueError("fused=True embeds the probe; lookup_fn "
-                             "cannot be combined with it")
-        self.fused = bool(on)
-        self._build_step()
-        self.sentinel.allow_next()
 
     def attach_maintenance(self, maint, forest, breaker=None,
                            registry=None) -> None:
@@ -478,12 +436,11 @@ class ServeEngine:
     # ---------------------------------------------------------- retrieval
     def attach_retrieval(self, state, lookup_fn=None,
                          max_locs: int = 4, n: int = 3,
-                         batch_pad: int = 64, fused: bool = False) -> None:
+                         batch_pad: int = 64) -> None:
         """Fuse CFT retrieval into the engine — see
         :meth:`RetrievalSession.attach`."""
         self.retrieval.attach(state, lookup_fn=lookup_fn,
-                              max_locs=max_locs, n=n, batch_pad=batch_pad,
-                              fused=fused)
+                              max_locs=max_locs, n=n, batch_pad=batch_pad)
 
     def retrieve(self, tree_ids: Sequence[int],
                  hashes: Sequence[int]) -> DeviceRetrieval:

@@ -1,15 +1,13 @@
-"""FilterBank: bulk build, per-tree routing, vmapped + Pallas lookups."""
+"""FilterBank: bulk build, per-tree routing, jnp + Pallas arena lookups."""
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (CFTDeviceState, build_bank, build_forest,
-                        lookup_batch, lookup_batch_bank,
-                        lookup_batch_ragged, lookup_batch_trees,
-                        retrieve_device)
+                        lookup_arena, retrieve_device)
 from repro.core import hashing
 from repro.data import hospital_corpus
-from repro.kernels.cuckoo_lookup import (cuckoo_lookup_bank,
-                                         cuckoo_lookup_trees)
+from repro.kernels.cuckoo_lookup import cuckoo_lookup_arena
+from repro.kernels.cuckoo_lookup.ops import padded_rows
 
 
 def _forest(num_trees=16, shared=True):
@@ -19,6 +17,25 @@ def _forest(num_trees=16, shared=True):
         for t in range(num_trees):          # one entity spanning all trees
             trees[t].append((f"root {t}", "shared entity"))
     return build_forest(trees)
+
+
+def _routed(bank, tid):
+    """Per-query segment start and bucket mask of in-range tree ids, as
+    ``retrieve_device`` gathers them."""
+    tid = np.asarray(tid)
+    return (jnp.asarray(bank.bucket_offsets[tid].astype(np.int32)),
+            jnp.asarray((bank.tree_nb[tid] - 1).astype(np.uint32)))
+
+
+def _dense_arena(bank, tid):
+    """A uniform bank's dense ``(T, NB, S)`` view as an arena of T equal
+    segments, queries routed to ``tree * NB``."""
+    df, _, dh = bank.dense_tables()
+    t, nb, s = df.shape
+    tid = jnp.asarray(tid, jnp.int32)
+    return (jnp.asarray(df.reshape(t * nb, s)),
+            jnp.asarray(dh.reshape(t * nb, s)),
+            tid * nb, jnp.full(tid.shape, nb - 1, jnp.uint32))
 
 
 def test_round_trip_every_row():
@@ -95,12 +112,9 @@ def test_routed_lookup_matches_host():
     hh = np.concatenate([hashes[bank.row_entity],
                          hashing.hash_entities([f"missing {i}"
                                                 for i in range(16)])])
-    res = lookup_batch_ragged(jnp.asarray(bank.fingerprints),
-                              jnp.asarray(bank.heads),
-                              jnp.asarray(
-                                  bank.bucket_offsets.astype(np.int32)),
-                              jnp.asarray(bank.tree_nb),
-                              jnp.asarray(tid), jnp.asarray(hh))
+    res = lookup_arena(jnp.asarray(bank.fingerprints),
+                       jnp.asarray(bank.heads), *_routed(bank, tid),
+                       jnp.asarray(hh))
     for i in range(tid.shape[0]):
         hit, row, _ = bank.lookup(int(tid[i]), int(hh[i]))
         assert bool(res.hit[i]) == hit
@@ -109,30 +123,40 @@ def test_routed_lookup_matches_host():
 
 
 def test_vmapped_lookup_matches_per_tree_reference():
-    """lookup_batch_trees == looping lookup_batch over each tree's table."""
+    """Each tree's dense filter probed alone, as an arena of one segment,
+    answers as the whole bank probed with per-tree routing — through the
+    jnp probe and the kernel."""
     forest = _forest()
     bank = build_bank(forest)
     names = [[f"entity {t}_{i}" for i in range(12)] + ["missing x", "shared entity"]
              for t in range(bank.num_trees)]
-    hb = jnp.stack([jnp.asarray(hashing.hash_entities(ns)) for ns in names])
+    hb = np.stack([hashing.hash_entities(ns) for ns in names])
+    t_n, b = hb.shape
+    tid = np.repeat(np.arange(t_n, dtype=np.int32), b)
+    args = _dense_arena(bank, tid) + (jnp.asarray(hb.reshape(-1)),)
+    got = lookup_arena(*args)
+    ker = cuckoo_lookup_arena(*args, interpret=True)
     df, _, dh = bank.dense_tables()         # uniform forest -> dense view
-    fps, heads = jnp.asarray(df), jnp.asarray(dh)
-    got = lookup_batch_trees(fps, heads, hb)
-    ker = cuckoo_lookup_trees(fps, heads, hb, interpret=True)
+    nb = df.shape[1]
     for t in range(bank.num_trees):
-        ref = lookup_batch(fps[t], heads[t], hb[t])
+        ref = lookup_arena(jnp.asarray(df[t]), jnp.asarray(dh[t]),
+                           jnp.zeros((b,), jnp.int32),
+                           jnp.full((b,), nb - 1, jnp.uint32),
+                           jnp.asarray(hb[t]))
         m = np.asarray(ref.hit)
+        rows = slice(t * b, (t + 1) * b)
         for field in ("hit", "head"):
-            np.testing.assert_array_equal(np.asarray(getattr(got, field)[t]),
-                                          np.asarray(getattr(ref, field)))
-            np.testing.assert_array_equal(np.asarray(getattr(ker, field)[t]),
-                                          np.asarray(getattr(ref, field)))
+            want = np.asarray(getattr(ref, field))
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, field))[rows], want)
+            np.testing.assert_array_equal(
+                np.asarray(getattr(ker, field))[rows], want)
         for field in ("bucket", "slot"):      # defined only on hits
             np.testing.assert_array_equal(
-                np.asarray(getattr(got, field)[t])[m],
+                np.asarray(getattr(got, field))[rows][m],
                 np.asarray(getattr(ref, field))[m])
             np.testing.assert_array_equal(
-                np.asarray(getattr(ker, field)[t])[m],
+                np.asarray(getattr(ker, field))[rows][m],
                 np.asarray(getattr(ref, field))[m])
 
 
@@ -140,21 +164,19 @@ def test_pallas_bank_kernel_matches_reference():
     forest = _forest()
     bank = build_bank(forest)
     hashes = hashing.hash_entities(forest.entity_names)
-    tid = jnp.asarray(bank.row_tree.astype(np.int32))
-    hh = jnp.asarray(hashes[bank.row_entity])
-    df, _, dh = bank.dense_tables()
-    fps, heads = jnp.asarray(df), jnp.asarray(dh)
-    ref = lookup_batch_bank(fps, heads, tid, hh)
-    ker = cuckoo_lookup_bank(fps, heads, tid, hh, interpret=True)
+    tid = bank.row_tree.astype(np.int32)
+    args = _dense_arena(bank, tid) + (jnp.asarray(hashes[bank.row_entity]),)
+    ref = lookup_arena(*args)
+    ker = cuckoo_lookup_arena(*args, interpret=True)
     for field in ("hit", "head", "bucket", "slot"):
         np.testing.assert_array_equal(np.asarray(getattr(ref, field)),
                                       np.asarray(getattr(ker, field)))
 
 
 def test_pallas_bank_kernel_tree_tiled_matches_single_block():
-    """The tree-axis-tiled grid must be bit-identical to the single-VMEM-
-    block kernel on every lane (hits AND misses), for tile sizes that do
-    and do not divide T."""
+    """A grid tiled by whole trees' worth of arena rows must be
+    bit-identical to the single-VMEM-block kernel on every lane (hits AND
+    misses), for tile sizes that do and do not divide T."""
     forest = _forest(num_trees=12)
     bank = build_bank(forest)
     hashes = hashing.hash_entities(forest.entity_names)
@@ -163,16 +185,14 @@ def test_pallas_bank_kernel_tree_tiled_matches_single_block():
     hh = np.concatenate([hashes[bank.row_entity],
                          hashing.hash_entities([f"missing {i}"
                                                 for i in range(24)])])
-    df, _, dh = bank.dense_tables()
-    fps, heads = jnp.asarray(df), jnp.asarray(dh)
-    tid_j, hh_j = jnp.asarray(tid), jnp.asarray(hh)
-    ref = lookup_batch_bank(fps, heads, tid_j, hh_j)
+    args = _dense_arena(bank, tid) + (jnp.asarray(hh),)
+    nb = bank.dense_tables()[0].shape[1]
+    ref = lookup_arena(*args)
     m = np.asarray(ref.hit)
-    base = cuckoo_lookup_bank(fps, heads, tid_j, hh_j, interpret=True,
-                              tree_tile=0)
+    base = cuckoo_lookup_arena(*args, interpret=True, row_tile=0)
     for tt in (1, 4, 5, 12, -1):   # 5 does not divide T=12 -> pad path
-        ker = cuckoo_lookup_bank(fps, heads, tid_j, hh_j, interpret=True,
-                                 tree_tile=tt)
+        rt = -1 if tt < 0 else padded_rows(tt * nb, 0)
+        ker = cuckoo_lookup_arena(*args, interpret=True, row_tile=rt)
         for field in ("hit", "head", "bucket", "slot"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(ker, field)),

@@ -9,8 +9,8 @@ import numpy as np
 import pytest
 
 from repro.core import (CFTDeviceState, build_bank, build_forest,
-                        build_index, bump_temperature, lookup_batch,
-                        retrieve_device, sort_buckets)
+                        build_index, bump_temperature_arena, lookup_arena,
+                        retrieve_device, sort_buckets_arena)
 from repro.core import hashing
 from repro.core.context import gather_descendants, gather_hierarchy
 from repro.core.trag import NULL
@@ -24,13 +24,21 @@ def _setup(trees=20):
     return c, forest, idx
 
 
+def _one_segment(num_buckets, h):
+    """A single filter is an arena of one segment: zero offsets, masks
+    ``nb - 1``."""
+    return (jnp.zeros(h.shape, jnp.int32),
+            jnp.full(h.shape, num_buckets - 1, jnp.uint32))
+
+
 def test_lookup_batch_matches_host():
     _, forest, idx = _setup()
     t = idx.filter.tables()
     names = forest.entity_names[:100] + [f"missing {i}" for i in range(20)]
     hs = hashing.hash_entities(names)
-    res = lookup_batch(jnp.asarray(t.fingerprints), jnp.asarray(t.heads),
-                       jnp.asarray(hs))
+    h = jnp.asarray(hs)
+    res = lookup_arena(jnp.asarray(t.fingerprints), jnp.asarray(t.heads),
+                       *_one_segment(idx.filter.num_buckets, h), h)
     for i, nm in enumerate(names):
         hit, head = idx.filter.lookup(int(hs[i]), bump=False)
         assert bool(res.hit[i]) == hit, nm
@@ -46,12 +54,14 @@ def test_bump_and_sort_device():
     heads = jnp.asarray(t.heads)
     eids = jnp.asarray(t.entity_ids)
     h = jnp.asarray(hashing.hash_entities([forest.entity_names[3]] * 4))
-    res = lookup_batch(fps, heads, h)
-    temps2 = bump_temperature(temps, res)
+    off, masks = _one_segment(idx.filter.num_buckets, h)
+    res = lookup_arena(fps, heads, off, masks, h)
+    temps2 = bump_temperature_arena(temps, off, res)
     assert int(temps2.sum()) == int(temps.sum()) + 4
-    fps2, temps3, heads2, eids2 = sort_buckets(fps, temps2, heads, eids)
+    fps2, temps3, heads2, eids2 = sort_buckets_arena(fps, temps2, heads,
+                                                     eids)
     # hot entity now at slot 0 of its bucket; membership preserved
-    res2 = lookup_batch(fps2, heads2, h)
+    res2 = lookup_arena(fps2, heads2, off, masks, h)
     assert bool(res2.hit[0]) and int(res2.slot[0]) == 0
     assert int((fps2 != 0).sum()) == int((fps != 0).sum())
 

@@ -30,11 +30,12 @@ def _run(code: str, devices: int = 8) -> str:
 
 
 def test_sharded_filter_lookup():
-    """Legacy bucket-striped single filter — now a wrapper over the
-    bank-axis all-to-all router; bit-identical to lookup_batch."""
+    """Bucket-striped single filter — a wrapper over the bank-axis
+    all-to-all router; bit-identical to the filter probed as an arena of
+    one segment."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.core import build_forest, build_index, lookup_batch
+    from repro.core import build_forest, build_index, lookup_arena
     from repro.core import hashing
     from repro.core.distributed import shard_filter_tables, sharded_lookup
     from repro.data import hospital_corpus
@@ -49,7 +50,9 @@ def test_sharded_filter_lookup():
                                      jnp.asarray(t.heads))
     names = forest.entity_names[:64] + ["missing A", "missing B"]
     h = jnp.asarray(hashing.hash_entities(names))
-    ref = lookup_batch(jnp.asarray(t.fingerprints), jnp.asarray(t.heads), h)
+    ref = lookup_arena(jnp.asarray(t.fingerprints), jnp.asarray(t.heads),
+                       jnp.zeros(h.shape, jnp.int32),
+                       jnp.full(h.shape, 255, jnp.uint32), h)
     got = sharded_lookup(mesh, "model", fps, heads, h)
     for f in ("hit", "head", "bucket", "slot"):
         np.testing.assert_array_equal(np.asarray(getattr(ref, f)),
@@ -61,13 +64,20 @@ def test_sharded_filter_lookup():
 
 def test_bank_axis_sharded_lookup_equivalence():
     """Bank-axis sharding: all-to-all routed lookup is bit-identical to
-    lookup_batch_ragged on the merged replicated arena — queries hitting
+    the routed jnp probe on the merged replicated arena — queries hitting
     trees on every shard, a ragged batch size, and an all-miss batch."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.core import (build_forest, build_bank, lookup_batch_ragged,
+    from repro.core import (build_forest, build_bank, lookup_arena,
                             sharded_lookup_bank, stage_sharded_bank)
     from repro.core import hashing
+
+    def lookup_routed(fps, heads, offsets, tree_nb, qt, qh):
+        # each query's segment start and bucket mask, as retrieve_device
+        qt = jnp.asarray(qt)
+        return lookup_arena(fps, heads, jnp.asarray(offsets)[qt],
+                            (jnp.asarray(tree_nb)[qt] - 1).astype(jnp.uint32),
+                            qh)
 
     T, D = 32, 8
     trees = [[(f"r{t}", f"e{t}_{i}") for i in range(4 + (t % 5) * 3)]
@@ -83,7 +93,7 @@ def test_bank_axis_sharded_lookup_equivalence():
     mnb_j = jnp.asarray(mnb)
 
     def check(qt, qh):
-        ref = lookup_batch_ragged(jnp.asarray(mf), jnp.asarray(mh),
+        ref = lookup_routed(jnp.asarray(mf), jnp.asarray(mh),
                                   moff_j, mnb_j,
                                   jnp.asarray(qt), jnp.asarray(qh))
         got = sharded_lookup_bank(state, jnp.asarray(qt), jnp.asarray(qh))
@@ -109,7 +119,7 @@ def test_bank_axis_sharded_lookup_equivalence():
 
     # semantic equivalence vs the original unsharded bank: same hits,
     # identical node lists through the merged row numbering
-    ref0 = lookup_batch_ragged(
+    ref0 = lookup_routed(
         jnp.asarray(bank.fingerprints), jnp.asarray(bank.heads),
         jnp.asarray(bank.bucket_offsets.astype(np.int32)),
         jnp.asarray(bank.tree_nb), jnp.asarray(qt), jnp.asarray(qh))
@@ -186,9 +196,16 @@ def test_sharded_maintenance_shard_local_churn():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import (build_forest, build_bank, build_bank_from_rows,
-                            lookup_batch_ragged, ShardedMaintenanceEngine,
+                            lookup_arena, ShardedMaintenanceEngine,
                             sharded_lookup_bank, stage_sharded_bank)
     from repro.core import hashing
+
+    def lookup_routed(fps, heads, offsets, tree_nb, qt, qh):
+        # each query's segment start and bucket mask, as retrieve_device
+        qt = jnp.asarray(qt)
+        return lookup_arena(fps, heads, jnp.asarray(offsets)[qt],
+                            (jnp.asarray(tree_nb)[qt] - 1).astype(jnp.uint32),
+                            qh)
 
     T, D = 16, 4
     trees = [[(f"r{t}", f"e{t}_{i}") for i in range(12)] for t in range(T)]
@@ -281,7 +298,7 @@ def test_sharded_maintenance_shard_local_churn():
         b = sbank.banks[d]
         occ = b.fingerprints != hashing.EMPTY_FP
         heads_m = np.where(occ, b.heads + np.int32(base[d]), -1)
-        ref = lookup_batch_ragged(
+        ref = lookup_routed(
             jnp.asarray(b.fingerprints), jnp.asarray(heads_m),
             jnp.asarray(b.bucket_offsets.astype(np.int32)),
             jnp.asarray(b.tree_nb),
@@ -740,14 +757,15 @@ def test_sharded_tenant_evict_reload_bit_exact():
     """, devices=4)
 
 
-def test_sharded_fused_owner_probe_byte_equality():
-    """The fused owner-shard probe (probe + bump + CSR window in one
-    Pallas launch before the route-back) is byte-identical to the unfused
-    sharded path — hit/locations/hierarchy and the *sharded-layout*
-    temperature, across rounds and both capacity modes."""
+def test_sharded_step_out_of_range_trees_miss():
+    """Negative and too-large tree ids miss in the sharded step: no
+    clamping to the last tree, no aliasing to tree 0, no bump on any
+    shard — across rounds and both capacity modes — while every in-range
+    query answers as the replicated step does."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.core import build_forest, build_bank, stage_sharded_bank
+    from repro.core import (CFTDeviceState, build_forest, build_bank,
+                            retrieve_device, stage_sharded_bank)
     from repro.core.distributed import sharded_retrieve_device
     from repro.core import hashing
 
@@ -761,30 +779,38 @@ def test_sharded_fused_owner_probe_byte_equality():
     sbank = bank.shard(D)
     mesh = jax.make_mesh((D,), ("model",))
     rng = np.random.default_rng(1)
+    # stored entities of the first and last trees under foreign ids
+    oob = [-3, T + 9, -1, T, T + 1000]
     qt = [t for t in range(T) for _ in range(3)] + \\
-         [int(rng.integers(T)) for _ in range(15)] + [-3, T + 9]
+         [int(rng.integers(T)) for _ in range(15)] + oob
     qh = [int(hashing.entity_hash(f"e{t}_{k}"))
           for t in range(T) for k in (0, 1, 2)] + \\
-         [int(rng.integers(1, 2 ** 32)) for _ in range(17)]
+         [int(rng.integers(1, 2 ** 32)) for _ in range(15)] + \\
+         [int(hashing.entity_hash(n)) for n in
+          ("e31_0", "e0_0", "e31_1", "e31_2", "e0_1")]
     qt = jnp.asarray(np.asarray(qt, np.int32))
     qh = jnp.asarray(np.asarray(qh, np.uint32))
+    k = len(oob)
 
+    rep = CFTDeviceState.from_bank(bank, forest)
+    ref = retrieve_device(rep, qh, qt)
     for cf in (None, 0.5):
-        s_ref = stage_sharded_bank(sbank, forest, mesh, "model")
-        s_fus = stage_sharded_bank(sbank, forest, mesh, "model")
+        state = stage_sharded_bank(sbank, forest, mesh, "model")
         for rnd in range(3):
-            ref = sharded_retrieve_device(s_ref, qh, qt,
-                                          capacity_factor=cf)
-            got = sharded_retrieve_device(s_fus, qh, qt,
-                                          capacity_factor=cf, fused=True)
-            for f in ("hit", "locations", "up", "down", "temperature"):
+            before = np.asarray(state.temperature).copy()
+            got = sharded_retrieve_device(state, qh, qt, capacity_factor=cf)
+            hit = np.asarray(got.hit)
+            assert not hit[-k:].any(), (cf, rnd, hit[-k:])
+            assert (np.asarray(got.locations)[-k:] == -1).all()
+            assert hit[:3 * T].all()
+            for f in ("hit", "locations", "up", "down"):
                 np.testing.assert_array_equal(
                     np.asarray(getattr(ref, f)),
                     np.asarray(getattr(got, f)),
                     err_msg=f"{f} cf={cf} round={rnd}")
-            s_ref = s_ref.with_temperature(ref.temperature)
-            s_fus = s_fus.with_temperature(got.temperature)
-    assert np.asarray(ref.hit)[:3 * T].all()
-    assert not np.asarray(ref.hit)[-2:].any()      # out-of-range ids miss
-    print("sharded fused owner probe OK")
+            # exactly the in-range hits bumped, on their owning shards
+            bumped = int(np.asarray(got.temperature).sum() - before.sum())
+            assert bumped == int(hit.sum()), (bumped, int(hit.sum()))
+            state = state.with_temperature(got.temperature)
+    print("sharded out-of-range ids OK")
     """)

@@ -4,9 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import build_forest, build_index
+from repro.core import build_bank, build_forest, build_index, lookup_arena
 from repro.core import hashing
-from repro.kernels.cuckoo_lookup import cuckoo_lookup, cuckoo_lookup_ref
+from repro.kernels import vmem
+from repro.kernels.cuckoo_lookup import cuckoo_lookup_arena
+from repro.kernels.cuckoo_lookup.ops import launch_vmem_limit, pick_row_tile
 from repro.kernels.decode_attention import (combine_partial_attention,
                                             decode_attention,
                                             decode_attention_ref)
@@ -19,28 +21,83 @@ RNG = np.random.default_rng(42)
 
 # ------------------------------------------------------------ cuckoo lookup
 
+def _one_segment(forest, num_buckets, names):
+    """The single filter as an arena of one segment: zero offsets, masks
+    ``nb - 1``."""
+    t = build_index(forest, num_buckets=num_buckets).filter.tables()
+    b = len(names)
+    return (jnp.asarray(t.fingerprints), jnp.asarray(t.heads),
+            jnp.zeros((b,), jnp.int32),
+            jnp.full((b,), num_buckets - 1, jnp.uint32))
+
+
+def _ragged(forest, _num_buckets, names):
+    """The same rows as a ragged bank, each query routed to the tree that
+    holds its entity (misses to tree 0)."""
+    bank = build_bank(forest)
+    tree = np.zeros(len(names), np.int32)
+    for i, nm in enumerate(names):
+        eid = forest.name_to_id.get(nm)
+        if eid is not None:
+            tree[i] = forest.entity_locations[eid][0][0]
+    return (jnp.asarray(bank.fingerprints), jnp.asarray(bank.heads),
+            jnp.asarray(bank.bucket_offsets[tree].astype(np.int32)),
+            jnp.asarray((bank.tree_nb[tree] - 1).astype(np.uint32)))
+
+
+@pytest.mark.parametrize("layout", [_one_segment, _ragged],
+                         ids=["one_segment", "ragged"])
 @pytest.mark.parametrize("num_buckets,n_entities,batch",
                          [(64, 100, 16), (256, 500, 130), (1024, 3000, 256),
                           (2048, 5000, 97)])
-def test_cuckoo_lookup_sweep(num_buckets, n_entities, batch):
+def test_cuckoo_lookup_sweep(num_buckets, n_entities, batch, layout):
     trees = [[(f"r{t}", f"e{t}_{i}") for i in range(n_entities // 40)]
              for t in range(40)]
     forest = build_forest(trees)
-    idx = build_index(forest, num_buckets=num_buckets)
-    t = idx.filter.tables()
-    fps, heads = jnp.asarray(t.fingerprints), jnp.asarray(t.heads)
     names = ([forest.entity_names[i % forest.num_entities]
               for i in range(batch - 10)] + [f"miss{i}" for i in range(10)])
     h = jnp.asarray(hashing.hash_entities(names))
-    ref = cuckoo_lookup_ref(fps, heads, h)
-    ker = cuckoo_lookup(fps, heads, h, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref.hit), np.asarray(ker.hit))
-    np.testing.assert_array_equal(np.asarray(ref.head), np.asarray(ker.head))
+    fps, heads, off, masks = layout(forest, num_buckets, names)
+    ref = lookup_arena(fps, heads, off, masks, h)
+    ker = cuckoo_lookup_arena(fps, heads, off, masks, h, interpret=True)
     m = np.asarray(ref.hit)
+    assert m[:batch - 10].all() and not m[batch - 10:].any()
+    np.testing.assert_array_equal(m, np.asarray(ker.hit))
+    np.testing.assert_array_equal(np.asarray(ref.head), np.asarray(ker.head))
     np.testing.assert_array_equal(np.asarray(ref.bucket)[m],
                                   np.asarray(ker.bucket)[m])
     np.testing.assert_array_equal(np.asarray(ref.slot)[m],
                                   np.asarray(ker.slot)[m])
+
+
+# --------------------------------------------------------- VMEM derivation
+
+def test_vmem_budget_derivation():
+    """The tile budget of a core is half its VMEM at the closed-form
+    per-row cost, launched under a scoped limit of that capacity."""
+    for capacity in (16 << 20, 128 << 20):
+        b = vmem.budget_for(capacity)
+        assert b.budget_bytes == capacity * vmem.BUDGET_FRACTION
+        assert b.per_row_bytes == vmem.closed_form_row_bytes(4, 128) > 0
+        assert b.limit_bytes == capacity
+
+
+def test_vmem_budget_measured_on_cpu():
+    """The CPU has no VMEM: the capacity lookup raises rather than assume
+    a size, and the probe launches interpreted, untiled, under the
+    compiler's default limit."""
+    with pytest.raises(RuntimeError, match="no VMEM"):
+        vmem.vmem_capacity_bytes()
+    assert pick_row_tile(4608, interpret=True) == 0
+    assert launch_vmem_limit(interpret=True) == 0
+
+
+def test_max_rows_monotone():
+    b = vmem.budget_for(16 << 20)
+    free = vmem.max_rows_for_vmem(b, 128)
+    assert free % 128 == 0 and free >= 128
+    # a larger core never holds fewer rows
+    assert vmem.max_rows_for_vmem(vmem.budget_for(128 << 20), 128) >= free
 
 
 # ---------------------------------------------------------- flash attention

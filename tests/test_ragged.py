@@ -10,13 +10,11 @@ except ImportError:                                  # offline container
     from _hypothesis_stub import given, settings, st
 
 from repro.core import (CFTDeviceState, MaintenanceEngine,
-                        bump_temperature_arena, bump_temperature_bank,
-                        build_bank, build_forest, lookup_batch,
-                        lookup_batch_bank, lookup_batch_ragged,
-                        retrieve_device)
+                        bump_temperature_arena, build_bank, build_forest,
+                        lookup_arena, retrieve_device)
 from repro.core import hashing
 from repro.core.bank import EMPTY_TREE_NB
-from repro.kernels.cuckoo_lookup import cuckoo_lookup_ragged
+from repro.kernels.cuckoo_lookup import cuckoo_lookup_arena
 
 
 def _skewed_forest(rng, num_trees):
@@ -40,10 +38,12 @@ def _query_batch(bank, hashes, rng, misses=24):
     return tid, hh
 
 
-def _ragged_args(bank):
+def _ragged_args(bank, tid):
+    """Arena tables plus each query's segment start and bucket mask, as
+    ``retrieve_device`` routes in-range tree ids."""
     return (jnp.asarray(bank.fingerprints), jnp.asarray(bank.heads),
-            jnp.asarray(bank.bucket_offsets.astype(np.int32)),
-            jnp.asarray(bank.tree_nb))
+            jnp.asarray(bank.bucket_offsets[tid].astype(np.int32)),
+            jnp.asarray((bank.tree_nb[tid] - 1).astype(np.uint32)))
 
 
 # --------------------------------------------------- dense equivalence
@@ -65,18 +65,23 @@ def test_ragged_bit_identical_to_dense_equivalent(seed):
     tid_j, hh_j = jnp.asarray(tid), jnp.asarray(hh)
 
     df, dt, dh = bank.dense_tables()
-    ref = lookup_batch_bank(jnp.asarray(df), jnp.asarray(dh), tid_j, hh_j)
-    got = lookup_batch_ragged(*_ragged_args(bank), tid_j, hh_j)
+    t, nb, s = df.shape
+    dense_off = tid_j * nb                           # the dense (T, NB) index
+    ref = lookup_arena(jnp.asarray(df.reshape(t * nb, s)),
+                       jnp.asarray(dh.reshape(t * nb, s)), dense_off,
+                       jnp.full(tid.shape, nb - 1, jnp.uint32), hh_j)
+    args = _ragged_args(bank, tid)
+    got = lookup_arena(*args, hh_j)
     for f in ("hit", "head", "bucket", "slot"):
         np.testing.assert_array_equal(np.asarray(getattr(ref, f)),
                                       np.asarray(getattr(got, f)),
                                       err_msg=f"dense-equivalence {f}")
 
     # temperature bumps land on the same slots through both layouts
-    temp_d = bump_temperature_bank(jnp.asarray(dt), tid_j, ref)
-    row_off = jnp.asarray(bank.bucket_offsets.astype(np.int32))[tid_j]
+    temp_d = bump_temperature_arena(jnp.asarray(dt.reshape(t * nb, s)),
+                                    dense_off, ref)
     temp_r = bump_temperature_arena(jnp.asarray(bank.temperature),
-                                    row_off, got)
+                                    args[2], got)
     np.testing.assert_array_equal(
         np.asarray(temp_d).reshape(np.asarray(temp_r).shape),
         np.asarray(temp_r))
@@ -93,11 +98,10 @@ def test_ragged_lookup_matches_per_tree_standalone(seed):
     bank = build_bank(forest)
     hashes = hashing.hash_entities(forest.entity_names)
     tid, hh = _query_batch(bank, hashes, rng)
-    tid_j, hh_j = jnp.asarray(tid), jnp.asarray(hh)
+    args = _ragged_args(bank, tid) + (jnp.asarray(hh),)
 
-    got = lookup_batch_ragged(*_ragged_args(bank), tid_j, hh_j)
-    ker = cuckoo_lookup_ragged(*_ragged_args(bank), tid_j, hh_j,
-                               interpret=True)
+    got = lookup_arena(*args)
+    ker = cuckoo_lookup_arena(*args, interpret=True)
     m = np.asarray(got.hit)
     np.testing.assert_array_equal(m, np.asarray(ker.hit))
     np.testing.assert_array_equal(np.asarray(got.head),
@@ -111,8 +115,11 @@ def test_ragged_lookup_matches_per_tree_standalone(seed):
         if not sel.any():
             continue
         lo, hi = bank.segment(t)
-        ref = lookup_batch(jnp.asarray(bank.fingerprints[lo:hi]),
+        b = int(sel.sum())                           # one-segment arena
+        ref = lookup_arena(jnp.asarray(bank.fingerprints[lo:hi]),
                            jnp.asarray(bank.heads[lo:hi]),
+                           jnp.zeros((b,), jnp.int32),
+                           jnp.full((b,), hi - lo - 1, jnp.uint32),
                            jnp.asarray(hh[sel]))
         for f in ("hit", "head", "bucket", "slot"):
             np.testing.assert_array_equal(

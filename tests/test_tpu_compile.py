@@ -8,6 +8,7 @@ The topology is described inside a module fixture, never while a module is
 imported: only one process may load the TPU library at a time.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,41 +69,50 @@ def test_arena_probe_compiles(one_chip, rows, row_tile):
 
 
 def test_tree_routed_probe_compiles(one_chip):
-    from repro.kernels.cuckoo_lookup.ops import cuckoo_lookup_ragged
+    """The probe behind per-query routing gathered from the per-tree
+    offsets table, out-of-range ids masked to misses, as
+    ``retrieve_device`` routes."""
+    from repro.kernels.cuckoo_lookup.ops import cuckoo_lookup_arena
+
+    def routed(fps, heads, bucket_offsets, tree_nb, tid, h):
+        num_trees = bucket_offsets.shape[0] - 1
+        in_range = (tid >= 0) & (tid < num_trees)
+        t = jnp.where(in_range, tid, 0).astype(jnp.int32)
+        res = cuckoo_lookup_arena(
+            fps, heads, bucket_offsets[t], (tree_nb[t] - 1).astype(jnp.uint32),
+            h, interpret=False, row_tile=0, vmem_limit=V5E_VMEM)
+        return res._replace(hit=res.hit & in_range)
+
     fps, heads = _arena(one_chip)
     tid, h = _queries(one_chip)
-    fn = functools.partial(cuckoo_lookup_ragged, interpret=False,
-                           row_tile=0, vmem_limit=V5E_VMEM)
     assert "tpu_custom_call" in _compile(
-        fn, fps, heads, _spec(one_chip, (TREES + 1,), jnp.int32),
+        routed, fps, heads, _spec(one_chip, (TREES + 1,), jnp.int32),
         _spec(one_chip, (TREES,), jnp.int32), tid, h)
 
 
-@pytest.mark.parametrize("row_tile", [0, 1024], ids=["single_block", "tiled"])
-def test_fused_kernel_compiles(one_chip, row_tile):
-    from repro.kernels.fused_retrieve.ops import fused_retrieve_ragged
-    fps, heads = _arena(one_chip)
+@pytest.mark.parametrize("rows,row_tile", [(ARENA, 0), (65536, 8192)],
+                         ids=["single_block", "tiled"])
+def test_retrieval_step_compiles(one_chip, rows, row_tile):
+    """The whole device step (routing, probe, bump, CSR window, hierarchy
+    walks) at the 600-tree geometry is one program with exactly one
+    kernel launch: the probe."""
+    from repro.core import CFTDeviceState, retrieve_device
+    from repro.kernels.cuckoo_lookup.ops import cuckoo_lookup_arena
+    fps, heads = _arena(one_chip, rows)
+    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
+    state = CFTDeviceState(
+        fingerprints=fps, temperature=i32((rows, SLOTS)), heads=heads,
+        bucket_offsets=i32((TREES + 1,)), tree_nb=i32((TREES,)),
+        csr_offsets=i32((CSR_ROWS + 1,)), csr_nodes=i32((CSR_NODES,)),
+        parent=i32((NODES,)), entity_id=i32((NODES,)),
+        child_offsets=i32((NODES + 1,)), child_index=i32((CHILDREN,)))
     tid, h = _queries(one_chip)
-    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
-    fn = functools.partial(fused_retrieve_ragged, interpret=False, mxu=True,
-                           row_tile=row_tile, vmem_limit=V5E_VMEM)
-    assert "tpu_custom_call" in _compile(
-        fn, fps, i32((ARENA, SLOTS)), heads, i32((TREES + 1,)),
-        i32((TREES,)), tid, h, i32((CSR_ROWS + 1,)), i32((CSR_NODES,)),
-        i32((NODES,)), i32((NODES,)), i32((NODES + 1,)), i32((CHILDREN,)))
-
-
-def test_fused_probe_locs_compiles(one_chip):
-    from repro.kernels.fused_retrieve.ops import fused_probe_locs
-    fps, heads = _arena(one_chip)
-    off, h = _queries(one_chip)
-    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
-    fn = functools.partial(fused_probe_locs, interpret=False, mxu=True,
-                           vmem_limit=V5E_VMEM)
-    assert "tpu_custom_call" in _compile(
-        fn, fps, i32((ARENA, SLOTS)), heads, off,
-        _spec(one_chip, (BATCH,), jnp.uint32), i32((BATCH,)), h,
-        i32((CSR_ROWS + 1,)), i32((CSR_NODES,)))
+    probe = functools.partial(cuckoo_lookup_arena, interpret=False,
+                              row_tile=row_tile, vmem_limit=V5E_VMEM)
+    step = functools.partial(retrieve_device, max_locs=4, n=3,
+                             lookup_fn=probe)
+    hlo = _compile(step, state, h, tid)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', hlo)) == 1
 
 
 def test_paper_generator_decode_step_compiles(one_chip):
@@ -126,7 +136,6 @@ def test_paper_generator_decode_step_compiles(one_chip):
 def _loop_copy_bytes(hlo: str):
     """Bytes of each ``copy`` op inside the entry's while loops, in the
     loop bodies and every computation they call."""
-    import re
     import numpy as np
     comps, entry, name = {}, None, None
     for line in hlo.splitlines():
